@@ -50,7 +50,6 @@ def make_sharded_rows_update(mesh, *, nc: int, vocab: int, shards: int,
     from jax.sharding import PartitionSpec as P
 
     from ..ops.pallas_embedding import fused_rows_update
-    from ..utils.jaxcompat import shard_map
 
     if vocab % shards != 0:
         raise ValueError(f"vocab {vocab} not divisible by {shards} shards")
@@ -74,13 +73,14 @@ def make_sharded_rows_update(mesh, *, nc: int, vocab: int, shards: int,
         return fused_rows_update(table_l, slots_l, g_rows, local_ids,
                                  rule, lr, use_pallas)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(tspec, slots_spec, tspec, P(), P()),
-                   out_specs=(tspec, slots_spec),
-                   # axis_index + replicated-by-construction outputs: the
-                   # per-device results agree across unmentioned axes, but
-                   # the static replication checker can't see it
-                   check_vma=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(tspec, slots_spec, tspec, P(), P()),
+                       out_specs=(tspec, slots_spec),
+                       # axis_index + replicated-by-construction outputs:
+                       # the per-device results agree across unmentioned
+                       # axes, but the static replication checker can't
+                       # see it
+                       check_vma=False)
 
     def update(table, slots, g, ids, lr):
         return fn(table, slots, g, ids, jnp.asarray(lr, jnp.float32))

@@ -2,7 +2,7 @@
 a host memmap.
 
 A 10M x 16 f32 stacked table is ~640 MB per field before moment slots —
-past what a single device (or the CPU CI tunnel) wants resident — but
+past what a single device wants resident — but
 tabular id traffic is zipf-skewed: a small hot set serves almost every
 lookup.  `TieredTable` keeps the hot rows in memory (HBM once placed) and
 serves the cold tail from a disk-backed memmap in the cache-v2 wire

@@ -302,6 +302,7 @@ def try_load_aot(export_dir: str):
         if bad:
             return fallback("fingerprint mismatch: " + "; ".join(bad))
 
+        import jax
         from jax.experimental.serialize_executable import \
             deserialize_and_load
 
@@ -315,6 +316,10 @@ def try_load_aot(export_dir: str):
                             "lowering order")
         leaves = [flat[k] for k in keys]
 
+        # the pack was compiled for ONE device; left to its default,
+        # deserialize_and_load would spread it over every device of the
+        # backend and the first call would fail on a multi-chip host
+        device = jax.devices()[:1]
         loaded: dict[int, Any] = {}
         bucket_ms: dict[str, float] = {}
         t0 = time.perf_counter()
@@ -332,7 +337,8 @@ def try_load_aot(export_dir: str):
             t_b = time.perf_counter()
             rec = pickle.loads(blob)
             loaded[b] = deserialize_and_load(
-                rec["payload"], rec["in_tree"], rec["out_tree"])
+                rec["payload"], rec["in_tree"], rec["out_tree"],
+                execution_devices=device)
             bucket_ms[str(b)] = round(
                 (time.perf_counter() - t_b) * 1e3, 3)
         scorer = AotScorer(export_dir, manifest, loaded, leaves)

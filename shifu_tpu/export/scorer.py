@@ -349,11 +349,12 @@ class Scorer(BatchScorer):
 
 
 class JaxScorer(BatchScorer):
-    """Fallback scorer for non-chain models (wide_deep/deepfm/multitask/
-    ft_transformer): rebuilds the Flax model from the artifact's stored spec
-    and scores on the CPU backend.  Still satisfies the eval contract — no TF
-    runtime, commodity CPU — at the cost of a jax dependency; the native
-    C++ op-list path covers these model types as their ops are lowered."""
+    """Rebuilds the Flax model from the artifact's stored spec and scores
+    through a jitted forward on the default JAX backend — the TPU where
+    there is one (`serve --engine jax` is the device serving path), the CPU
+    otherwise.  Serves every model family, including the non-chain ones
+    (wide_deep/deepfm/multitask/ft_transformer) the op-list engines only
+    cover as their ops are lowered, at the cost of a jax dependency."""
 
     engine = "jax"
     static_shapes = True  # jit compiles per batch shape — daemon pads

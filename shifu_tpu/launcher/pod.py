@@ -55,6 +55,31 @@ DEFAULT_COORDINATOR_PORT = 8476
 SSH_CONNECT_RETRIES = 3
 
 
+class ChipOwnershipError(RuntimeError):
+    """More than one process on a host would open the accelerator."""
+
+
+def require_one_chip_owner(n_on_host: int, what: str, *,
+                           local: bool) -> None:
+    """Refuse to start `n_on_host` > 1 processes that each open the
+    default JAX backend on one host.  A TPU chip belongs to one process
+    at a time: the second one dies at start-up on libtpu's lock file
+    ("Unable to initialize backend 'tpu': ABORTED"; chip_smoke.py's serve
+    phase records it on every run).  Local children inherit this
+    environment, so with `JAX_PLATFORMS=cpu` they are a CPU simulation
+    and may be as many as asked; pinning one chip per child is not
+    implemented."""
+    if n_on_host <= 1:
+        return
+    if local and os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return
+    raise ChipOwnershipError(
+        f"{what}: {n_on_host} processes on one host would each open the "
+        "accelerator, and a TPU chip belongs to one process at a time (the "
+        "others fail at start-up). Run one such process per host, or set "
+        "JAX_PLATFORMS=cpu for a CPU simulation")
+
+
 @dataclass(frozen=True)
 class PodSpec:
     hosts: tuple[str, ...]           # rank i runs on hosts[i]
@@ -182,6 +207,13 @@ def launch_gang(spec: PodSpec, child_args: Sequence[str], out_dir: str,
     treats that as terminal)."""
     from .supervisor import EXIT_TIMEOUT
     n = len(spec.hosts)
+    if list(child_args[:1]) == ["train"]:
+        # `train` ranks open the default backend; a `data-dryrun` gang is
+        # host-side work and may share a host freely
+        local = spec.transport == "local"
+        require_one_chip_owner(
+            n if local else max(spec.hosts.count(h) for h in spec.hosts),
+            f"training gang of {n} ({spec.transport})", local=local)
     try:
         from ..data import fsio
         remote_out = fsio.is_remote(out_dir)

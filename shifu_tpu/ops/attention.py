@@ -19,8 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..utils.jaxcompat import shard_map as shard_map_compat
-
 
 def mha(q: jax.Array, k: jax.Array, v: jax.Array,
         scale: Optional[float] = None) -> jax.Array:
@@ -80,7 +78,7 @@ def ring_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     spec = _sp_spec(mesh, seq_axis)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_local, axis_name=seq_axis, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,
@@ -136,7 +134,7 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
     spec = _sp_spec(mesh, seq_axis)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(_ulysses_local, axis_name=seq_axis, scale=scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False,

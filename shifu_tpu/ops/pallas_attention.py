@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from .attention import mha
-from .pallas_common import pallas_opt_in, pltpu
+from .pallas_common import on_tpu, pallas_opt_in, pltpu
 
 _NEG_BIG = -1e30  # -inf would make fully-masked rows produce NaN (exp(inf-inf))
 
@@ -304,20 +304,12 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     use_pallas: None = auto (SHIFU_TPU_PALLAS=1 opt-in, like
     ops/pallas_embedding.py); True forces the kernels (interpret mode
-    off-TPU; raises if the pallas tpu extension is absent); False routes to
-    the XLA reference `mha`.
+    off-TPU); False routes to the XLA reference `mha`.
     """
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / (d ** 0.5)
-    on_tpu = jax.default_backend() == "tpu"
     if use_pallas is None:
-        # auto mode degrades gracefully when the tpu pallas ext is missing
-        use_pallas = pallas_opt_in() and pltpu is not None
-    if use_pallas and pltpu is None:
-        raise RuntimeError(
-            "flash_attention(use_pallas=True): jax.experimental.pallas.tpu "
-            "is unavailable on this install (VMEM scratch needs it); use "
-            "use_pallas=None/False to route to the XLA reference")
+        use_pallas = pallas_opt_in()
     if not use_pallas:
         return mha(q, k, v, scale=scale)
-    return _flash(q, k, v, scale, not on_tpu, block_q, block_k)
+    return _flash(q, k, v, scale, not on_tpu(), block_q, block_k)

@@ -1,22 +1,30 @@
-"""Shared gating + imports for the opt-in Pallas kernels.
+"""Shared gating + imports for the Pallas kernels.
 
-Kernels default OFF and engage when SHIFU_TPU_PALLAS is set truthy (they are
-validated in interpret mode on CPU and against the XLA references on a real
-v5e chip; see pallas_attention.py / pallas_embedding.py for their
-hardware-specific constraints).
+Three kernels engage by themselves on a TPU backend: the fused
+FT-Transformer block (`ModelSpec.fused_block="auto"`), the small-token
+attention kernel (S <= 64, D <= 16) and the fused int8 dequant+matmul
+(int8 wire / resident format); the fused embedding rows-update engages
+with the sparse-update plan when D % 128 == 0.  Flash attention and the
+embedding lookup stay behind SHIFU_TPU_PALLAS.  Off a TPU backend every
+kernel runs in interpret mode and only under that same opt-in (or an
+explicit `use_pallas=True`, the tests' exactness path).  `chip_smoke.py`
+compiles each kernel natively on the chip and holds it to an f64 oracle.
 """
 
 from __future__ import annotations
 
 import os
 
-try:  # TPU-specific pallas namespace (VMEM scratch, DMA); absent on some
-    # CPU-only installs — kernels that need it must check for None
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+import jax
+from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["pallas_opt_in", "pltpu"]
+__all__ = ["on_tpu", "pallas_opt_in", "pltpu"]
+
+
+def on_tpu() -> bool:
+    """True when the default JAX backend is a TPU: kernels then compile
+    natively (`interpret=False`); anywhere else they interpret."""
+    return jax.default_backend() == "tpu"
 
 
 def pallas_opt_in() -> bool:

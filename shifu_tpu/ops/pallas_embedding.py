@@ -33,7 +33,25 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-from .pallas_common import pltpu
+from .pallas_common import on_tpu, pallas_opt_in, pltpu
+
+
+# Scalar-prefetch ids live in SMEM (1 MiB on a v5e).  A 2-D (rows, Nc)
+# int32 array pads its minor dim to 128 lanes there — 2,048 rows of 6
+# fields already overflow (Mosaic: "Ran out of memory in memory space
+# smem") — so ids ride FLAT (row-major, 4 B per id) and a call covers at
+# most this many of them; longer id lists run as sequential calls.
+_SMEM_IDS_PER_CALL = 64 * 1024
+
+
+def _row_chunks(n_rows: int, nc: int, rows_per_step: int):
+    """[(start, stop)] row ranges whose flat ids fit the SMEM budget, each
+    a multiple of `rows_per_step` (which already divides n_rows)."""
+    per_call = max(rows_per_step,
+                   (_SMEM_IDS_PER_CALL // nc) // rows_per_step
+                   * rows_per_step)
+    return [(lo, min(lo + per_call, n_rows))
+            for lo in range(0, n_rows, per_call)]
 
 
 def _make_lookup_kernel(nc: int, rows_per_step: int):
@@ -48,7 +66,7 @@ def _make_lookup_kernel(nc: int, rows_per_step: int):
             b_idx = i * rows_per_step + r
             for f in range(nc):
                 dma = pltpu.make_async_copy(
-                    table_ref.at[f, ids_ref[b_idx, f]],
+                    table_ref.at[f, ids_ref[b_idx * nc + f]],
                     out_ref.at[r, f],
                     sem_ref.at[r, f],
                 )
@@ -66,24 +84,29 @@ def _pallas_lookup(table: jax.Array, ids: jax.Array,
     while b % rows_per_step != 0:
         rows_per_step //= 2  # degrade gracefully for odd batch sizes
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,           # ids (SMEM)
-        grid=(b // rows_per_step,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),      # table stays in HBM
-        ],
-        out_specs=pl.BlockSpec(
-            (rows_per_step, nc, dim),
-            lambda i, ids_ref: (i, 0, 0),
-        ),
-        scratch_shapes=[pltpu.SemaphoreType.DMA((rows_per_step, nc))],
-    )
-    return pl.pallas_call(
-        _make_lookup_kernel(nc, rows_per_step),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nc, dim), table.dtype),
-        interpret=interpret,
-    )(ids, table)
+    def call(ids_c):
+        n = ids_c.shape[0]
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,           # flat ids (SMEM)
+            grid=(n // rows_per_step,),
+            in_specs=[
+                pl.BlockSpec(memory_space=pl.ANY),      # table stays in HBM
+            ],
+            out_specs=pl.BlockSpec(
+                (rows_per_step, nc, dim),
+                lambda i, ids_ref: (i, 0, 0),
+            ),
+            scratch_shapes=[pltpu.SemaphoreType.DMA((rows_per_step, nc))],
+        )
+        return pl.pallas_call(
+            _make_lookup_kernel(nc, rows_per_step),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((n, nc, dim), table.dtype),
+            interpret=interpret,
+        )(ids_c.reshape(-1), table)
+
+    parts = [call(ids[lo:hi]) for lo, hi in _row_chunks(b, nc, rows_per_step)]
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
 def _xla_lookup(table: jax.Array, ids: jax.Array) -> jax.Array:
@@ -113,7 +136,7 @@ def _onehot_ok(vocab: int, n_lookups: int) -> bool:
                                  _ONEHOT_MAX_VOCAB))
     except ValueError:
         cap = _ONEHOT_MAX_VOCAB
-    return jax.default_backend() == "tpu" and 0 < vocab <= cap
+    return on_tpu() and 0 < vocab <= cap
 
 
 def _onehot_num_chunks(n_lookups: int, vocab: int) -> int:
@@ -166,22 +189,18 @@ def embedding_lookup(table: jax.Array, ids: jax.Array,
 
 
 def _forward(table, ids, use_pallas):
-    from .pallas_common import pallas_opt_in
-
-    on_tpu = jax.default_backend() == "tpu"
     auto = use_pallas is None
     if auto:
-        # Opt-in (SHIFU_TPU_PALLAS=1); validated in interpret mode on CPU
-        # and on a real v5e chip (exact vs the XLA gather).
-        use_pallas = pallas_opt_in() and pltpu is not None
-    if use_pallas and pltpu is not None:
-        if on_tpu and table.shape[-1] % 128 != 0:
+        use_pallas = pallas_opt_in()  # SHIFU_TPU_PALLAS=1
+    if use_pallas:
+        if on_tpu() and table.shape[-1] % 128 != 0:
             # Mosaic DMA tiling: an HBM row slice needs its minor dim
             # 128-lane aligned, so sub-128 embedding dims (the tabular
             # default D=16) cannot use the per-row DMA design — the XLA
             # gather serves those; the kernel pays off for D >= 128 tables.
             return _xla_lookup(table, ids.astype(jnp.int32))
-        return _pallas_lookup(table, ids.astype(jnp.int32), interpret=not on_tpu)
+        return _pallas_lookup(table, ids.astype(jnp.int32),
+                              interpret=not on_tpu())
     # one-hot strategy only on the AUTO path: an explicit use_pallas=False
     # keeps its documented "force the XLA gather" contract (the reference
     # implementation validation/benchmarks compare against)
@@ -318,7 +337,7 @@ def _bwd(use_pallas, res, g):
     auto = use_pallas is None
     if auto and _onehot_ok(table_shape[1], ids.size):
         return _onehot_grad(ids, table_shape, g).astype(table_dtype), None
-    if auto and jax.default_backend() == "tpu":
+    if auto and on_tpu():
         # CPU scatters fine; TPU does not.  Auto-path only: an explicit
         # use_pallas=False keeps the reference scatter-add for A/Bs.
         return _segment_grad(ids, table_shape, g).astype(table_dtype), None
@@ -417,7 +436,7 @@ def _make_rows_update_kernel(nc: int, rows_per_step: int, vocab: int,
             for r in range(rows_per_step):
                 u = i * rows_per_step + r
                 for f in range(nc):
-                    idx = ids_ref[u, f]
+                    idx = ids_ref[u * nc + f]
                     valid = (idx >= 0) & (idx < vocab)
 
                     @pl.when(valid)
@@ -471,57 +490,53 @@ def _pallas_rows_update(table, slots, g_rows, ids, rule, lr,
     adadelta = rule == "adadelta"
     n_bufs = 3 if adadelta else 1
     lr_arr = jnp.asarray(lr, jnp.float32).reshape(1, 1)
-
-    row_block = pl.BlockSpec((rows_per_step, nc, dim),
-                             lambda i, ids_ref: (i, 0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,               # ids (SMEM)
-        grid=(u // rows_per_step,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda i, ids_ref: (0, 0),
-                         memory_space=pltpu.SMEM),          # lr
-            row_block,                                      # g_rows (VMEM)
-        ] + [pl.BlockSpec(memory_space=pl.ANY)] * n_bufs,   # table (+slots)
-        out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_bufs,
-        scratch_shapes=[
-            pltpu.VMEM((rows_per_step, nc, dim), jnp.float32)
-        ] * n_bufs + [pltpu.SemaphoreType.DMA((n_bufs, rows_per_step, nc))],
-    )
-    out_shape = [jax.ShapeDtypeStruct(table.shape, table.dtype)]
-    operands = [ids.astype(jnp.int32), lr_arr,
-                g_rows.astype(jnp.float32), table]
-    if adadelta:
-        accu, delta = slots
-        operands += [accu, delta]
-        out_shape += [jax.ShapeDtypeStruct(accu.shape, accu.dtype),
-                      jax.ShapeDtypeStruct(delta.shape, delta.dtype)]
+    ids = ids.astype(jnp.int32)
+    g_rows = g_rows.astype(jnp.float32)
+    bufs = [table] + (list(slots) if adadelta else [])
     # alias table (+slots) inputs onto the outputs: the update is in-place,
     # so steady-state table traffic is touched-rows only.  Operand indices
     # count every pallas_call argument incl. the scalar-prefetch ids.
     aliases = {3 + k: k for k in range(n_bufs)}
-    outs = pl.pallas_call(
-        _make_rows_update_kernel(nc, rows_per_step, vocab, rule),
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        input_output_aliases=aliases,
-        interpret=interpret,
-    )(*operands)
+    row_block = pl.BlockSpec((rows_per_step, nc, dim),
+                             lambda i, ids_ref: (i, 0, 0))
+    kernel = _make_rows_update_kernel(nc, rows_per_step, vocab, rule)
+    # sequential calls over SMEM-sized id chunks; each updates the same
+    # aliased buffers (in-range ids are unique across the WHOLE id list,
+    # so chunks never touch the same row)
+    for lo, hi in _row_chunks(u, nc, rows_per_step):
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,               # flat ids (SMEM)
+            grid=((hi - lo) // rows_per_step,),
+            in_specs=[
+                pl.BlockSpec((1, 1), lambda i, ids_ref: (0, 0),
+                             memory_space=pltpu.SMEM),          # lr
+                row_block,                                      # g_rows
+            ] + [pl.BlockSpec(memory_space=pl.ANY)] * n_bufs,   # table, slots
+            out_specs=[pl.BlockSpec(memory_space=pl.ANY)] * n_bufs,
+            scratch_shapes=[
+                pltpu.VMEM((rows_per_step, nc, dim), jnp.float32)
+            ] * n_bufs + [
+                pltpu.SemaphoreType.DMA((n_bufs, rows_per_step, nc))],
+        )
+        bufs = pl.pallas_call(
+            kernel,
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct(b.shape, b.dtype) for b in bufs],
+            input_output_aliases=aliases,
+            interpret=interpret,
+        )(ids[lo:hi].reshape(-1), lr_arr, g_rows[lo:hi], *bufs)
     if adadelta:
-        return outs[0], (outs[1], outs[2])
-    return outs[0], slots
+        return bufs[0], (bufs[1], bufs[2])
+    return bufs[0], slots
 
 
 def fused_update_available(dim: int) -> bool:
     """True where the fused rows-touched update kernel can actually run:
-    any CPU/interpret context with the TPU pallas namespace present, or a
-    real TPU with a 128-lane-aligned embedding dim (the same Mosaic DMA
-    constraint as the lookup kernel — a narrower HBM row cannot be sliced).
-    train/sparse_embed.py's auto gate keys off this."""
-    if pltpu is None:
-        return False
-    if jax.default_backend() == "tpu":
-        return dim % 128 == 0
-    return True
+    any interpret context, or a TPU with a 128-lane-aligned embedding dim
+    (the same Mosaic DMA constraint as the lookup kernel — a narrower HBM
+    row cannot be sliced).  train/sparse_embed.py's auto gate keys off
+    this."""
+    return dim % 128 == 0 if on_tpu() else True
 
 
 def fused_rows_update(table: jax.Array, slots, g_rows: jax.Array,
@@ -530,25 +545,20 @@ def fused_rows_update(table: jax.Array, slots, g_rows: jax.Array,
     """Rows-touched optimizer update: gather touched rows + apply the
     Adadelta/SGD rule + scatter back, fused into one Pallas pass
     (interpret mode off-TPU).  Falls back to `rows_update_reference` when
-    the kernel cannot run (no pltpu, unaligned D on real TPU, non-f32
-    table) or when use_pallas=False.  In-range ids must be unique per
+    the kernel cannot run (unaligned D on a TPU, non-f32 table) or when
+    use_pallas=False.  In-range ids must be unique per
     field within a call (see the kernel contract above); out-of-range ids
     (the dedup sentinel V) are skipped, matching the reference's
     scatter-drop.  use_pallas=None auto-selects: the kernel wherever
     `fused_update_available` holds AND the Pallas opt-in
     (SHIFU_TPU_PALLAS) is set off-TPU."""
-    from .pallas_common import pallas_opt_in
-
     if rule not in ("sgd", "adadelta"):
         raise ValueError(f"fused_rows_update: unknown rule {rule!r}")
-    on_tpu = jax.default_backend() == "tpu"
     if use_pallas is None:
-        use_pallas = fused_update_available(table.shape[-1]) and (
-            on_tpu or pallas_opt_in())
-    kernel_ok = (use_pallas and pltpu is not None
-                 and fused_update_available(table.shape[-1])
+        use_pallas = on_tpu() or pallas_opt_in()
+    kernel_ok = (use_pallas and fused_update_available(table.shape[-1])
                  and table.dtype == jnp.float32)
     if not kernel_ok:
         return rows_update_reference(table, slots, g_rows, ids, rule, lr)
     return _pallas_rows_update(table, slots, g_rows, ids, rule, lr,
-                               interpret=not on_tpu)
+                               interpret=not on_tpu())

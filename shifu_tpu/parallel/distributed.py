@@ -55,12 +55,8 @@ def initialize(coordinator: Optional[str] = None,
         # CPU backends need an explicit cross-process collectives transport
         # (gloo) — the stand-in for ICI/DCN when simulating hosts locally;
         # must be set before backend init or collectives silently hang
-        try:
-            if jax.config.jax_platforms in ("cpu", None) or \
-                    "cpu" in str(jax.config.jax_platforms or ""):
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass  # older jax or already-initialized backend
+        if "cpu" in str(jax.config.jax_platforms or "cpu"):
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
         jax.distributed.initialize(
             coordinator_address=coordinator,
             num_processes=num_processes,
@@ -90,11 +86,10 @@ def is_chief() -> bool:
 
 
 def barrier(name: str = "barrier") -> None:
-    """Cross-host sync point (ZK-watch-latch successor).  Implemented as a
-    tiny psum over all devices so it needs no extra service."""
-    import jax.numpy as jnp
-
+    """Cross-host sync point (ZK-watch-latch successor): every process
+    blocks until all have reached the barrier of this `name`."""
     if jax.process_count() == 1:
         return
-    x = jnp.ones((jax.local_device_count(),))
-    jax.pmap(lambda v: jax.lax.psum(v, "i"), axis_name="i")(x).block_until_ready()
+    from jax.experimental import multihost_utils
+
+    multihost_utils.sync_global_devices(name)

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..utils.jaxcompat import shard_map as shard_map_compat
 from .mesh import DATA_AXIS, PIPE_AXIS
 
 PyTree = Any
@@ -104,6 +103,6 @@ def pipeline_apply(stage_fn: Callable[[PyTree, jax.Array], jax.Array],
     x_spec = P(None, batch_axis, *([None] * (x.ndim - 2)))
     p_specs = jax.tree_util.tree_map(
         lambda leaf: P(axis, *([None] * (leaf.ndim - 1))), stacked_params)
-    fn = shard_map_compat(local, mesh=mesh, in_specs=(p_specs, x_spec),
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(p_specs, x_spec),
                        out_specs=x_spec, check_vma=False)
     return fn(stacked_params, x)

@@ -482,7 +482,16 @@ class ProcessMember:
     production spawn path, riding the launcher plane's process-group
     teardown (launcher/supervisor._kill_tree).  The child writes its own
     lease (`shifu-tpu serve --heartbeat-s`) into its telemetry dir, so
-    the manager's monitor reads it exactly like an in-proc member's."""
+    the manager's monitor reads it exactly like an in-proc member's.
+
+    A child with a device engine opens the chip, and a chip belongs to
+    one process: a second such child on the same host is refused with
+    `ChipOwnershipError` (launcher/pod.py) unless the children are a
+    local CPU simulation.  In-proc members share this process's chip and
+    are not affected."""
+
+    # host id -> live `serve` children there that score on the device
+    _device_children: dict = {}
 
     def __init__(self, member_id: str, export_dir: str, *,
                  serving: ServingConfig, fleet: FleetConfig,
@@ -494,6 +503,19 @@ class ProcessMember:
         import subprocess
         import sys
 
+        from .serve import DEVICE_ENGINES
+
+        on_device = serving.engine in DEVICE_ENGINES
+        if on_device:
+            from ..launcher import pod
+            peers = [p for p in self._device_children.get(host_id, ())
+                     if p.poll() is None]
+            self._device_children[host_id] = peers
+            local = argv is None or argv[0] == sys.executable
+            pod.require_one_chip_owner(
+                len(peers) + 1,
+                f"fleet member {member_id} (--engine {serving.engine}) on "
+                f"host {host_id or 'local'!r}", local=local)
         self.member_id = member_id
         self.tele_dir = tele_dir
         os.makedirs(tele_dir, exist_ok=True)
@@ -522,6 +544,8 @@ class ProcessMember:
         # the CLI shim (launcher/supervisor.py's spawn contract)
         self.proc = subprocess.Popen(cmd, env=env,
                                      start_new_session=True)
+        if on_device:
+            self._device_children[host_id].append(self.proc)
 
     @property
     def version(self) -> Optional[int]:

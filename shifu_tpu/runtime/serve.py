@@ -56,6 +56,11 @@ CHAOS_SITE = "runtime.serve"
 CHAOS_DISPATCH_SITE = "runtime.serve.dispatch"
 
 
+# engines that score on the default JAX backend (the chip, where there is
+# one); numpy and native score on the host and never open it
+DEVICE_ENGINES = ("jax", "aot", "stablehlo")
+
+
 class ServeOverload(RuntimeError):
     """Admission queue at `serving.queue_limit` — backpressure to the
     caller (retry / shed upstream), never an unbounded-latency queue."""
@@ -1227,8 +1232,15 @@ def serve_forever(export_dir: str, config: ServingConfig,
          f"(budget={config.latency_budget_ms}ms "
          f"max_batch={config.max_batch})")
     from .. import obs
+    device = {}
+    if handle.engine_name in DEVICE_ENGINES:
+        import jax
+        devices = jax.devices()
+        device = dict(platform=devices[0].platform,
+                      device_kind=devices[0].device_kind,
+                      device_count=len(devices))
     obs.event("serve_start", path=export_dir, engine=handle.engine_name,
-              port=server.port, pid=os.getpid())
+              port=server.port, pid=os.getpid(), **device)
     try:
         stop_evt.wait()
     except KeyboardInterrupt:

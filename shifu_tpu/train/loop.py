@@ -469,10 +469,14 @@ def train(job: JobConfig,
         # callers (the CLI configures sinks explicitly before calling in);
         # non-chief ranks keep their registry in memory and journal nothing
         obs.configure_from_env()
+    devices = jax.devices()
     obs.event("train_start", model=job.model.model_type,
               epochs=job.train.epochs, batch_size=job.data.batch_size,
               processes=jax.process_count(),
-              devices=len(jax.devices()) if mesh is None else mesh.size)
+              devices=len(devices) if mesh is None else mesh.size,
+              platform=devices[0].platform,
+              device_kind=devices[0].device_kind,
+              device_count=len(devices))
     wmode = pipe.wire_mode(job.schema, job.data, job.model.compute_dtype)
     # streamed-path cast: per-BLOCK compact target/weight detection
     # (content-driven, so a resume replays identical formats) on a single

@@ -63,7 +63,7 @@ _EPS = 1e-8
 # embed/ engine's kernel sidesteps it: touched rows move by per-row DMA
 # with the rule fused in, table traffic batch-proportional, duplicates
 # removed upstream by the feeder dedup.  Where the kernel cannot run
-# (no pltpu, TPU with an unaligned dim, no CPU opt-in), "auto" stays
+# (TPU with an unaligned dim, no CPU opt-in), "auto" stays
 # off and "on" keeps the reference path for its IndexedSlices lazy-
 # update SEMANTICS (untouched rows see no decay), exactly as before.
 _AUTO_MIN_VOCAB = 100_000
@@ -71,8 +71,8 @@ _AUTO_MIN_VOCAB = 100_000
 
 def _auto_engages(job: JobConfig) -> bool:
     from ..models.embedding import field_layout
+    from ..ops.pallas_common import on_tpu, pallas_opt_in
     from ..ops.pallas_embedding import fused_update_available
-    from ..ops.pallas_common import pallas_opt_in
     vocabs = field_layout(job.schema).vocab_sizes
     if not vocabs or max(vocabs) < _AUTO_MIN_VOCAB:
         return False
@@ -80,7 +80,7 @@ def _auto_engages(job: JobConfig) -> bool:
         return False
     # off-TPU the kernel runs in interpret mode — correct but slow, so it
     # stays behind the same explicit opt-in as every other Pallas kernel
-    return jax.default_backend() == "tpu" or pallas_opt_in()
+    return on_tpu() or pallas_opt_in()
 
 
 # model types that build stacked CategoricalEmbed tables the sparse rule

@@ -3,10 +3,18 @@
 Every `train` CLI invocation (and every supervisor restart attempt — the
 checkpoint-restart fault-tolerance story launches a fresh process per
 attempt) retraces and recompiles the same programs; the reference paid the
-same tax re-building its TF graph on every container start.  Pointing JAX's
-persistent compilation cache at a stable directory turns those repeat
-compiles into sub-second deserializations (measured ~3.1s -> ~1.5s for the
-staged epoch program on a v5e chip).
+same tax re-building its TF graph on every container start.  JAX's
+persistent compilation cache turns those repeat compiles into
+deserializations: on a TPU v5 lite the flagship train job's summed
+`compile_s` read 6.85 s cold and 2.50 s warm (CHANGES.md PR 21;
+`chip_smoke.py` prints both on every run).
+
+Where the cache lives is decided from outside.  With
+`JAX_COMPILATION_CACHE_DIR` set, JAX itself reads it at import and this
+module sets no directory; without it the cache goes to one fixed path
+inside the checkout (`<repo>/.jax_cache`, git-ignored).  The directory is
+part of the cache key, so it is never derived from `~`, a temp name, a pid
+or a time.
 """
 
 from __future__ import annotations
@@ -17,7 +25,9 @@ from typing import Optional
 
 ENV_DISABLE = "SHIFU_TPU_NO_COMPILE_CACHE"
 ENV_DIR = "JAX_COMPILATION_CACHE_DIR"
-DEFAULT_DIR = "~/.cache/shifu_tpu/xla"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 # persistent-cache observation state (obs/introspect.py classifies each
 # XLA compile as hit/miss from the entry-set delta): the directory the
@@ -57,14 +67,14 @@ def observe_compile() -> str:
     return "miss" if fresh else "hit"
 
 
-def enable_persistent_cache(directory: str | None = None,
-                            min_compile_time_secs: float = 0.5
+def enable_persistent_cache(min_compile_time_secs: float = 0.5
                             ) -> str | None:
-    """Enable JAX's persistent compilation cache (idempotent, best-effort).
+    """Enable JAX's persistent compilation cache (idempotent).
 
-    Precedence: explicit `directory` > JAX_COMPILATION_CACHE_DIR env >
-    the default under ~/.cache.  SHIFU_TPU_NO_COMPILE_CACHE=1 disables.
-    Returns the directory in use, or None when disabled/unavailable.
+    The directory is `JAX_COMPILATION_CACHE_DIR` when set — JAX has then
+    already configured it and no directory is set here — else
+    `DEFAULT_DIR`.  SHIFU_TPU_NO_COMPILE_CACHE=1 disables.  Returns the
+    directory in use, or None when disabled or not creatable.
 
     `min_compile_time_secs` is the persistence floor: compiles faster
     than this are never written.  The 0.5s default fits the TRAIN path
@@ -79,31 +89,30 @@ def enable_persistent_cache(directory: str | None = None,
     unbounded-shape workloads."""
     if os.environ.get(ENV_DISABLE):
         return None
-    path = directory or os.environ.get(ENV_DIR) or os.path.expanduser(
-        DEFAULT_DIR)
+    import jax
+
     global _active_dir, _seen_entries
+    from_env = os.environ.get(ENV_DIR)
+    path = from_env or DEFAULT_DIR
     try:
         os.makedirs(path, exist_ok=True)
-        import jax
+    except OSError:
+        return None  # e.g. a read-only install: the job runs uncached
+    if not from_env:
         jax.config.update("jax_compilation_cache_dir", path)
-        # default thresholds skip small/fast programs; job programs are the
-        # multi-second compiles this cache exists for, serving bucket
-        # programs the sub-second ones (callers pick the floor)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(min_compile_time_secs))
-        with _lock:
-            _active_dir = path
-            _seen_entries = _list_entries(path)
-    except Exception:
-        return None  # cache is an optimization, never a failure
-    try:
-        from .. import obs
-        # registry-only (sinks are usually configured later in run_train):
-        # the scrape file records whether repeat compiles could deserialize
-        obs.gauge("compile_cache_enabled",
-                  "1 when the persistent XLA compile cache is active").set(1)
-        obs.event("compile_cache", directory=path)
-    except Exception:
-        pass
+    # default thresholds skip small/fast programs; job programs are the
+    # multi-second compiles this cache exists for, serving bucket
+    # programs the sub-second ones (callers pick the floor)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(min_compile_time_secs))
+    with _lock:
+        _active_dir = path
+        _seen_entries = _list_entries(path)
+    from .. import obs
+    # registry-only (sinks are usually configured later in run_train):
+    # the scrape file records whether repeat compiles could deserialize
+    obs.gauge("compile_cache_enabled",
+              "1 when the persistent XLA compile cache is active").set(1)
+    obs.event("compile_cache", directory=path)
     return path

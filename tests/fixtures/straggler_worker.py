@@ -20,14 +20,7 @@ import time
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 1)
-except AttributeError:
-    # older jax: the option doesn't exist — the XLA_FLAGS spelling must be
-    # in place before first backend use (we are, nothing initialized yet)
-    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
-                               + " --xla_force_host_platform_device_count=1"
-                               ).strip()
+jax.config.update("jax_num_cpu_devices", 1)
 try:
     jax.config.update("jax_cpu_collectives_implementation", "gloo")
 except Exception:

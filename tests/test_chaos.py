@@ -678,8 +678,8 @@ def test_e2e_chaos_drill_supervised_run(tmp_path):
 
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["SHIFU_TPU_PLATFORM"] = "cpu"
-    env["SHIFU_TPU_CPU_DEVICES"] = "4"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_NUM_CPU_DEVICES"] = "4"
     out = tmp_path / "out"
     r = subprocess.run(
         [sys.executable, "-m", "shifu_tpu.launcher.cli", "train",

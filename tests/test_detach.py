@@ -18,8 +18,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _env():
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["SHIFU_TPU_PLATFORM"] = "cpu"
-    env["SHIFU_TPU_CPU_DEVICES"] = "2"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_NUM_CPU_DEVICES"] = "2"
     return env
 
 

@@ -42,8 +42,8 @@ def job_dir(tmp_path):
 def _cli_env():
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env["SHIFU_TPU_PLATFORM"] = "cpu"
-    env["SHIFU_TPU_CPU_DEVICES"] = "4"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["JAX_NUM_CPU_DEVICES"] = "4"
     return env
 
 
@@ -191,7 +191,7 @@ def test_pod_timeout_is_terminal(job_dir):
     terminal: exit 3, one gang attempt, no whole-gang restart loop."""
     out = job_dir / "out_pt"
     env = _cli_env()
-    env["SHIFU_TPU_CPU_DEVICES"] = "2"
+    env["JAX_NUM_CPU_DEVICES"] = "2"
     r = _run_cli(["train",
                   "--modelconfig", str(job_dir / "ModelConfig.json"),
                   "--columnconfig", str(job_dir / "ColumnConfig.json"),

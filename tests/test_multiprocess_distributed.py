@@ -292,8 +292,8 @@ def test_pod_ssh_transport_end_to_end(tmp_path):
     synthetic.write_files(rows, str(tmp_path / "data"), num_files=2)
 
     env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    env.update({"SHIFU_TPU_PLATFORM": "cpu", "SHIFU_TPU_CPU_DEVICES": "1",
+           if k != "XLA_FLAGS"}
+    env.update({"JAX_PLATFORMS": "cpu", "JAX_NUM_CPU_DEVICES": "1",
                 "PATH": f"{fake_bin}:{env.get('PATH', '')}",
                 "PYTHONPATH": os.path.dirname(os.path.dirname(
                     os.path.abspath(__file__)))})
@@ -347,8 +347,8 @@ def test_multihost_streamed_first_epoch(tmp_path):
     synthetic.write_files(rows, str(tmp_path / "data"), num_files=6)
 
     env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    env.update({"SHIFU_TPU_PLATFORM": "cpu", "SHIFU_TPU_CPU_DEVICES": "1",
+           if k != "XLA_FLAGS"}
+    env.update({"JAX_PLATFORMS": "cpu", "JAX_NUM_CPU_DEVICES": "1",
                 "PYTHONPATH": os.path.dirname(os.path.dirname(
                     os.path.abspath(__file__)))})
     out = tmp_path / "job"
@@ -410,8 +410,8 @@ def test_multihost_streamed_epoch_unbalanced_shards(tmp_path):
     write_one(small[200:], "part-10003.gz")
 
     env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    env.update({"SHIFU_TPU_PLATFORM": "cpu", "SHIFU_TPU_CPU_DEVICES": "1",
+           if k != "XLA_FLAGS"}
+    env.update({"JAX_PLATFORMS": "cpu", "JAX_NUM_CPU_DEVICES": "1",
                 "PYTHONPATH": os.path.dirname(os.path.dirname(
                     os.path.abspath(__file__)))})
     out = tmp_path / "job"
@@ -481,8 +481,8 @@ def test_pod_ssh_transient_connect_failure_retries(tmp_path):
     synthetic.write_files(rows, str(tmp_path / "data"), num_files=2)
 
     env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    env.update({"SHIFU_TPU_PLATFORM": "cpu", "SHIFU_TPU_CPU_DEVICES": "1",
+           if k != "XLA_FLAGS"}
+    env.update({"JAX_PLATFORMS": "cpu", "JAX_NUM_CPU_DEVICES": "1",
                 "PATH": f"{fake_bin}:{env.get('PATH', '')}",
                 "PYTHONPATH": os.path.dirname(os.path.dirname(
                     os.path.abspath(__file__)))})
@@ -533,8 +533,8 @@ def test_pod_launch_gang_restart_end_to_end(tmp_path):
     synthetic.write_files(rows, str(tmp_path / "data"), num_files=4)
 
     env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    env.update({"SHIFU_TPU_PLATFORM": "cpu", "SHIFU_TPU_CPU_DEVICES": "1",
+           if k != "XLA_FLAGS"}
+    env.update({"JAX_PLATFORMS": "cpu", "JAX_NUM_CPU_DEVICES": "1",
                 "SHIFU_TPU_FAULT_EPOCH": "0", "SHIFU_TPU_FAULT_PROCESS": "2",
                 "PYTHONPATH": os.path.dirname(os.path.dirname(
                     os.path.abspath(__file__)))})
@@ -603,8 +603,8 @@ def test_pod_elastic_reshape_on_permanent_host_loss(tmp_path):
                             str(tmp_path / "global.xml"))
 
     env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    env.update({"SHIFU_TPU_PLATFORM": "cpu", "SHIFU_TPU_CPU_DEVICES": "1",
+           if k != "XLA_FLAGS"}
+    env.update({"JAX_PLATFORMS": "cpu", "JAX_NUM_CPU_DEVICES": "1",
                 "SHIFU_TPU_FAULT_HOST_DOWN": "1",
                 "PYTHONPATH": os.path.dirname(os.path.dirname(
                     os.path.abspath(__file__)))})
@@ -668,8 +668,8 @@ def test_cli_num_processes_end_to_end(tmp_path, tier_keys):
     synthetic.write_files(rows, str(tmp_path / "data"), num_files=4)
 
     env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    env.update({"SHIFU_TPU_PLATFORM": "cpu", "SHIFU_TPU_CPU_DEVICES": "2",
+           if k != "XLA_FLAGS"}
+    env.update({"JAX_PLATFORMS": "cpu", "JAX_NUM_CPU_DEVICES": "2",
                 "PYTHONPATH": os.path.dirname(os.path.dirname(
                     os.path.abspath(__file__)))})
     from shifu_tpu.utils import xmlconfig

@@ -186,8 +186,8 @@ def test_train_provision_end_to_end(tmp_path):
     synthetic.write_files(rows, str(tmp_path / "data"), num_files=2)
 
     env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    env.update({"SHIFU_TPU_PLATFORM": "cpu", "SHIFU_TPU_CPU_DEVICES": "1",
+           if k != "XLA_FLAGS"}
+    env.update({"JAX_PLATFORMS": "cpu", "JAX_NUM_CPU_DEVICES": "1",
                 "PATH": f"{fake_bin}{os.pathsep}{env.get('PATH', '')}",
                 "FAKE_GCLOUD_LOG": str(tmp_path / "gcloud.log"),
                 "FAKE_GCLOUD_STATE": str(tmp_path / "gcloud.state"),
@@ -611,8 +611,8 @@ def test_foreground_sigterm_releases_slice(tmp_path):
     (tmp_path / "data" / "part-0.psv").write_text("1|0.5\n0|0.1\n")
 
     env = {k: v for k, v in os.environ.items()
-           if k not in ("XLA_FLAGS", "JAX_PLATFORMS")}
-    env.update({"SHIFU_TPU_PLATFORM": "cpu", "SHIFU_TPU_CPU_DEVICES": "1",
+           if k != "XLA_FLAGS"}
+    env.update({"JAX_PLATFORMS": "cpu", "JAX_NUM_CPU_DEVICES": "1",
                 "PATH": f"{fake_bin}{os.pathsep}{env.get('PATH', '')}",
                 "FAKE_GCLOUD_LOG": str(tmp_path / "gcloud.log"),
                 "FAKE_GCLOUD_STATE": str(tmp_path / "gcloud.state"),
