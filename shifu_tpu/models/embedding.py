@@ -119,7 +119,8 @@ class CategoricalEmbed(nn.Module):
         # ops/pallas_embedding.embedding_lookup: XLA gather by default, the
         # manual-DMA Pallas kernel under SHIFU_TPU_PALLAS=1.
         from ..ops.pallas_embedding import embedding_lookup
-        return embedding_lookup(self.table(), ids.astype(jnp.int32))
+        with jax.named_scope("embed_gather"):
+            return embedding_lookup(self.table(), ids.astype(jnp.int32))
 
 
 def fused_lookup(embeds: Sequence[CategoricalEmbed], ids: jax.Array
@@ -142,9 +143,10 @@ def fused_lookup(embeds: Sequence[CategoricalEmbed], ids: jax.Array
 
     if pallas_opt_in():
         return [e(ids) for e in embeds]
-    fused = embedding_lookup(
-        jnp.concatenate([e.table() for e in embeds], axis=-1),
-        ids.astype(jnp.int32))
+    with jax.named_scope("embed_gather"):
+        fused = embedding_lookup(
+            jnp.concatenate([e.table() for e in embeds], axis=-1),
+            ids.astype(jnp.int32))
     outs, off = [], 0
     for e in embeds:
         outs.append(fused[..., off:off + e.dim])

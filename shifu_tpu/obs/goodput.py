@@ -23,6 +23,18 @@ alone.  This module is that accounting for shifu_tpu:
   tests).  On backends where cost capture is off (see introspect.py)
   MFU is null, never guessed.
 
+- **Phases**: finer host intervals inside the buckets.  A
+  `obs.span(..., journal=False)` that closes while a ledger is open
+  (obs/spans.py) adds seconds and a count under its full nested path,
+  and the loop folds in its garbage-collection hook's pauses where it
+  closes the epoch (the hook itself never takes this ledger's lock: a
+  collection can start under it); the epoch's one `goodput`
+  event carries them as `"phases": {"<path>": [seconds, count]}`.  A
+  phase's parent is its path's prefix and its epoch is the event's
+  `epoch`, so its self time is its seconds less those of the paths
+  under it.  Phases are raw host seconds: a compile or a collection
+  (`gc/gen<N>`) that ran inside a phase is in that phase's seconds too.
+
 Every epoch journals ONE `goodput` event and feeds the
 `goodput_bucket_seconds_total{bucket=...}` counter plus the
 `goodput_fraction` / `mfu` gauges, so `shifu-tpu profile`,
@@ -86,6 +98,7 @@ class GoodputLedger:
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._seconds: dict[str, float] = {}
+        self._phases: dict[str, list] = {}   # path -> [seconds, count]
         self._flops = 0.0
         self._compiles = 0
 
@@ -100,6 +113,20 @@ class GoodputLedger:
             if bucket == "compile":
                 self._compiles += 1
 
+    def add_phase(self, path: str, seconds: float, count: int = 1) -> None:
+        """`count` closed intervals of phase `path`, `seconds` in all (see
+        the module docstring).  A zero-length one still counts; a NaN or a
+        negative one does not."""
+        if not (seconds >= 0) or seconds == float("inf"):
+            return
+        with self._lock:
+            cell = self._phases.get(path)
+            if cell is None:
+                self._phases[path] = [seconds, count]
+            else:
+                cell[0] += seconds
+                cell[1] += count
+
     def add_flops(self, flops: float) -> None:
         if flops > 0 and flops != float("inf"):  # NaN > 0 is False
             with self._lock:
@@ -113,6 +140,8 @@ class GoodputLedger:
         the wall, with `other` absorbing the unclassified residue."""
         with self._lock:
             b = dict(self._seconds)
+            phases = {k: [round(v[0], 6), v[1]]
+                      for k, v in self._phases.items()}
             flops = self._flops
             compiles = self._compiles
         compile_s = b.get("compile", 0.0)
@@ -129,6 +158,7 @@ class GoodputLedger:
             "goodput_fraction": round(buckets["step"] / wall_s, 4)
             if wall_s > 0 else None,
             "compiles": compiles,
+            "phases": phases,
         }
         peak = peak_tflops()
         achieved = (flops / wall_s / 1e12) if wall_s > 0 and flops > 0 \
@@ -170,6 +200,19 @@ def note(bucket: str, seconds: float) -> None:
             led.add(bucket, seconds)
         except Exception:
             pass
+
+
+def note_phase(path: str, seconds: float) -> bool:
+    """Credit one closed interval of phase `path` to the active ledger.
+    False between epochs, where there is none to take it.  Never raises."""
+    led = _current
+    if led is None:
+        return False
+    try:
+        led.add_phase(path, seconds)
+    except Exception:
+        pass
+    return True
 
 
 def note_flops(flops: float) -> None:

@@ -312,24 +312,25 @@ def _accumulate_streaming(triples, score_sink=None) -> tuple[float, float]:
     instrumentation (and any accumulator fix) had to land twice.  Binned
     AUC matches the exact statistic to < 1e-6 at the default 2^20 bins."""
     sm = metrics_lib.StreamingMetrics()
-    lat = obs.histogram("eval_batch_seconds",
-                        "eval batch score+gather latency")
     # nonzero-weight rows: the one definition that reads the same on every
     # topology (the multihost branch's gathered global batches keep their
     # zero-weight padding; the single-host branch pre-trims real rows —
     # counting raw lengths would make the counter topology-dependent)
     rows = obs.counter("eval_rows_total", "rows evaluated (nonzero weight)")
-    t0 = time.perf_counter()
-    for s, t, w in triples:
-        lat.observe(time.perf_counter() - t0)
-        sm.update(s, t, w)
-        rows.inc(int(np.count_nonzero(np.asarray(w))))
-        if score_sink is not None:
-            # baseline score sketch: only rows that counted (zero-weight
-            # padding would skew the frozen score distribution)
-            score_sink(np.asarray(s)[np.asarray(w) > 0])
-        t0 = time.perf_counter()
-    return sm.weighted_error(), sm.auc()
+    # phase `accumulate` opens per chunk, never across the generator's
+    # resumption: `triples` opens its own phases (prep, dispatch, fetch)
+    for chunk in triples:
+        with obs.span("accumulate", journal=False):
+            s, t, w = chunk
+            sm.update(s, t, w)
+            rows.inc(int(np.count_nonzero(np.asarray(w))))
+            if score_sink is not None:
+                # baseline score sketch: only rows that counted (zero-weight
+                # padding would skew the frozen score distribution)
+                score_sink(np.asarray(s)[np.asarray(w) > 0])
+            del chunk, s, t, w  # the chunk's buffers go inside the phase
+    with obs.span("accumulate", journal=False):  # the final reduction
+        return sm.weighted_error(), sm.auc()
 
 
 def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
@@ -343,7 +344,14 @@ def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
     Multi-host: `ds` is this host's shard; every process contributes its
     rows to global eval batches, runs the same number of collective steps
     (shorter hosts feed zero-weight padding), and the gathered scores give
-    identical global metrics on every host."""
+    identical global metrics on every host.
+
+    Four hot spans split the pass on every topology — `prep` (slice, pad,
+    wire cast), `dispatch` (batch placement and the `eval_step` call: the
+    host's re-tiling, the H2D enqueue and the dispatch), `fetch` (the wait
+    for the oldest in-flight scores and their D2H) and `accumulate` —
+    nested under the caller's span (`epoch/eval/...` from `train`), each
+    opened and closed within one resumption of `triples`."""
     multihost = jax.process_count() > 1 and mesh is not None
     if not multihost and ds.num_rows == 0:
         return float("nan"), float("nan")
@@ -385,23 +393,34 @@ def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
 
             pend: "deque" = deque()
 
-            def fetch(entry):
-                s, n, tgt, wgt = entry
-                return (np.asarray(jax.device_get(s))[:n, 0], tgt, wgt)
+            def fetch():
+                with obs.span("fetch", journal=False):
+                    s, n, tgt, wgt = pend.popleft()
+                    out = (np.asarray(jax.device_get(s))[:n, 0], tgt, wgt)
+                    del s  # the device buffer is released inside the phase
+                return out
 
-            for batch in pipe.batch_iterator(ds, bs, shuffle=False,
-                                             drop_remainder=False):
-                padded, mask = pipe.pad_to_batch(batch, bs)
-                if wcast is not None:
-                    padded = wcast(padded)
-                if mesh is not None:
-                    padded = shard_lib.shard_batch(padded, mesh)
-                pend.append((eval_step(state, padded), int(mask.sum()),
-                             batch["target"][:, 0], batch["weight"][:, 0]))
+            batches = pipe.batch_iterator(ds, bs, shuffle=False,
+                                          drop_remainder=False)
+            while True:
+                with obs.span("prep", journal=False):
+                    batch = next(batches, None)
+                    if batch is not None:
+                        padded, mask = pipe.pad_to_batch(batch, bs)
+                        if wcast is not None:
+                            padded = wcast(padded)
+                if batch is None:
+                    break
+                with obs.span("dispatch", journal=False):
+                    if mesh is not None:
+                        padded = shard_lib.shard_batch(padded, mesh)
+                    pend.append((eval_step(state, padded), int(mask.sum()),
+                                 batch["target"][:, 0],
+                                 batch["weight"][:, 0]))
                 if len(pend) >= window:
-                    yield fetch(pend.popleft())
+                    yield fetch()
             while pend:
-                yield fetch(pend.popleft())
+                yield fetch()
 
         return _accumulate_streaming(triples(), score_sink)
 
@@ -425,20 +444,25 @@ def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
 
     def triples():
         for i in range(n_steps):
-            lo = min(i * local_bs, ds.num_rows)
-            hi = min(lo + local_bs, ds.num_rows)
-            local = {"features": ds.features[lo:hi],
-                     "target": ds.target[lo:hi],
-                     "weight": ds.weight[lo:hi]}
-            local, _ = pipe.pad_to_batch(local, local_bs)  # zero-weight tail
-            if wcast is not None:
-                local = wcast(local)
-            gbatch = shard_lib.shard_batch_process_local(local, mesh)
-            s, t, w = gather3(eval_step(state, gbatch), gbatch["target"],
-                              gbatch["weight"])
-            yield (np.asarray(s.addressable_data(0))[:, 0],
-                   np.asarray(t.addressable_data(0))[:, 0],
-                   np.asarray(w.addressable_data(0))[:, 0])
+            with obs.span("prep", journal=False):
+                lo = min(i * local_bs, ds.num_rows)
+                hi = min(lo + local_bs, ds.num_rows)
+                local = {"features": ds.features[lo:hi],
+                         "target": ds.target[lo:hi],
+                         "weight": ds.weight[lo:hi]}
+                # zero-weight tail
+                local, _ = pipe.pad_to_batch(local, local_bs)
+                if wcast is not None:
+                    local = wcast(local)
+            with obs.span("dispatch", journal=False):
+                gbatch = shard_lib.shard_batch_process_local(local, mesh)
+                scores = eval_step(state, gbatch)
+            with obs.span("fetch", journal=False):
+                s, t, w = gather3(scores, gbatch["target"], gbatch["weight"])
+                out = (np.asarray(s.addressable_data(0))[:, 0],
+                       np.asarray(t.addressable_data(0))[:, 0],
+                       np.asarray(w.addressable_data(0))[:, 0])
+            yield out
 
     return _accumulate_streaming(triples(), score_sink)
 
@@ -1016,6 +1040,10 @@ def train(job: JobConfig,
     pending_loader = None  # streamed loader whose train set is not yet built
     pending_thread = None  # background assembly of the retained dataset
     pending_assembly: dict = {}
+    # the collector's pauses, by generation, on the profiler's clock and
+    # (folded in at each epoch's close) as phases of the epoch's ledger;
+    # its thresholds stay as they are
+    gc_phases = obs.spans.GcPhases()
     try:
       for epoch in range(start_epoch, job.train.epochs):
         # chaos site "train.epoch_start": the epoch boundary BEFORE any
@@ -1028,6 +1056,7 @@ def train(job: JobConfig,
         # buckets; instrumented compiles and checkpoint saves credit it
         # from their own call sites while it is open
         led_open = obs.goodput.begin_epoch()
+        gc_phases.fold()  # a pause between two ledgers is in neither
         # the blocking dataset load that ran before the loop is charged to
         # the first epoch it fed: its seconds go to the input bucket and
         # its wall extends this epoch's wall at close, so the buckets
@@ -1300,12 +1329,17 @@ def train(job: JobConfig,
                     timer.mark_step_done()
                     if not multihost:  # collectives forbid divergent exits
                         maybe_midtrain_save(epoch)
-        if loss_n == 0:
-            raise ValueError(
-                f"epoch {epoch} produced 0 batches "
-                f"({train_ds.num_rows} rows, batch_size {bs}, "
-                f"drop_remainder={job.data.drop_remainder})")
-        loss_sum = float(jax.device_get(loss_acc))
+            if loss_n == 0:
+                raise ValueError(
+                    f"epoch {epoch} produced 0 batches "
+                    f"({train_ds.num_rows} rows, batch_size {bs}, "
+                    f"drop_remainder={job.data.drop_remainder})")
+            # the epoch's one host sync.  The scan tiers' dispatches return
+            # before the device is done, so the wait for it is here: its
+            # seconds belong to the ledger's `step` bucket (dispatch-to-
+            # done on every tier), not to `other`
+            with obs.span("device_wait", journal=False) as device_wait:
+                loss_sum = float(jax.device_get(loss_acc))
         epoch_time = time.perf_counter() - t0
 
         tv0 = time.perf_counter()
@@ -1487,14 +1521,16 @@ def train(job: JobConfig,
         # + saves): input is the consumer-visible wait (the gap the device
         # sat idle before each dispatch — producer-side host_input_times
         # overlap compute and are the straggler line's lens, not this
-        # one's), step is dispatch-to-done; compile/checkpoint/restore
+        # one's), step is dispatch-to-done (the dispatches plus the
+        # epoch's device_wait); compile/checkpoint/restore
         # were credited in-flight; `other` absorbs the residue so the
         # buckets always sum to the wall
         led = obs.goodput.current()
         if led is not None:
             led.add("input", sum(timer.input_times))
-            led.add("step", sum(timer.step_times))
+            led.add("step", sum(timer.step_times) + device_wait.seconds)
             led.add("eval", valid_time)
+            gc_phases.fold(led)
             obs.goodput.end_epoch(
                 epoch, time.perf_counter() - t0 + ingest_wall_s)
 
@@ -1598,6 +1634,7 @@ def train(job: JobConfig,
         if early_stop_now:
             break
     finally:
+      gc_phases.close()
       # never leave jax.profiler tracing, however the loop exits (an open
       # trace would poison the next capture in this process)
       devprof.close()

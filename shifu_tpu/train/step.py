@@ -149,6 +149,19 @@ def make_apply_gradients(job: JobConfig, mesh: Optional[Mesh] = None):
     return lambda st, grads, batch: sparse(st, grads, batch)
 
 
+def _fwd_bwd_and_update(loss_fn, apply_grads, st: TrainState, xs: Batch):
+    """One optimizer step, the body every step builder shares: (new state,
+    loss).  Its two parts carry stable device-side names (`jax.named_scope`:
+    HLO metadata only, the program is the same) so that a profile's
+    operations can be rolled up by part whatever the fusion numbering."""
+    with jax.named_scope("fwd_bwd"):
+        loss, grads = jax.value_and_grad(loss_fn)(
+            st.params, st.apply_fn, xs, st.step)
+    with jax.named_scope("optimizer"):
+        st = apply_grads(st, grads, xs)
+    return st, loss
+
+
 def _input_donate_argnums(donate: bool, donate_batch: bool) -> tuple:
     """donate_argnums for a (state, batch/blocks) step.  Donating the INPUT
     pytree (argnum 1) marks each chunk's device buffers dead at dispatch,
@@ -187,9 +200,8 @@ def make_train_step(job: JobConfig, mesh: Optional[Mesh] = None,
     apply_grads = make_apply_gradients(job, mesh)
 
     def step(state: TrainState, batch: Batch):
-        loss, grads = jax.value_and_grad(loss_fn)(
-            state.params, state.apply_fn, batch, state.step)
-        new_state = apply_grads(state, grads, batch)
+        new_state, loss = _fwd_bwd_and_update(loss_fn, apply_grads, state,
+                                              batch)
         return new_state, {"loss": loss}
 
     # Shardings ride on the input arrays themselves (state placed by
@@ -220,9 +232,7 @@ def make_epoch_scan_step(job: JobConfig, mesh: Optional[Mesh] = None,
     def epoch_step(state: TrainState, blocks: Batch):
         def body(carry, xs):
             st, acc = carry
-            loss, grads = jax.value_and_grad(loss_fn)(
-                st.params, st.apply_fn, xs, st.step)
-            st = apply_grads(st, grads, xs)
+            st, loss = _fwd_bwd_and_update(loss_fn, apply_grads, st, xs)
             return (st, acc + loss), None
 
         (state2, acc), _ = jax.lax.scan(
@@ -258,9 +268,7 @@ def make_device_epoch_step(job: JobConfig, mesh: Optional[Mesh] = None,
                 lambda a: jax.lax.dynamic_index_in_dim(a, idx, axis=0,
                                                        keepdims=False),
                 blocks)
-            loss, grads = jax.value_and_grad(loss_fn)(
-                st.params, st.apply_fn, xs, st.step)
-            st = apply_grads(st, grads, xs)
+            st, loss = _fwd_bwd_and_update(loss_fn, apply_grads, st, xs)
             return (st, acc + loss), None
 
         (state2, acc), _ = jax.lax.scan(body, (state, jnp.float32(0.0)), order)
@@ -376,12 +384,14 @@ def make_local_sgd_epoch_step(job: JobConfig, mesh: Optional[Mesh] = None,
                 wgt = jnp.ones((n_shards, local_bs, 1), jnp.float32)
             shard_steps = ((state.step + i) * n_shards
                            + jnp.arange(n_shards, dtype=jnp.int32))
-            losses, grads = vgrad(params_p, resh["features"], resh["target"],
-                                  wgt, shard_steps)
-            params_p = constrain(
-                jax.tree_util.tree_map(lambda p, g: p - lr * g,
-                                       params_p, grads),
-                0)
+            with jax.named_scope("fwd_bwd"):
+                losses, grads = vgrad(params_p, resh["features"],
+                                      resh["target"], wgt, shard_steps)
+            with jax.named_scope("optimizer"):
+                params_p = constrain(
+                    jax.tree_util.tree_map(lambda p, g: p - lr * g,
+                                           params_p, grads),
+                    0)
             params_p = jax.lax.cond((i + 1) % K == 0, sync,
                                     lambda pp: pp, params_p)
             return (params_p, acc + jnp.mean(losses), i + 1), None
