@@ -215,7 +215,8 @@ class ScoreSketch:
         s = np.asarray(scores, np.float64).ravel()
         if s.size == 0:
             return
-        idx = np.clip((s * self.bins).astype(np.int64), 0, self.bins - 1)
+        idx = (s * self.bins).astype(np.int64)
+        np.clip(idx, 0, self.bins - 1, out=idx)  # no second array a batch
         self.hist += np.bincount(idx, minlength=self.bins)
         self.n += int(s.size)
         self.sum += float(s.sum())

@@ -56,6 +56,15 @@ def weighted_error(scores: np.ndarray, labels: np.ndarray, weights: np.ndarray |
     return float(np.sum(w * (scores - labels) ** 2) / nonzero)
 
 
+def _as_chunk(a) -> np.ndarray:
+    """(n,) array of a chunk's column: float32 where float32 holds its dtype
+    exactly (float16, bfloat16, bool, small ints), else as it arrives."""
+    a = np.asarray(a).ravel()
+    if np.can_cast(a.dtype, np.float32, "safe"):
+        return a.astype(np.float32, copy=False)
+    return a
+
+
 class StreamingMetrics:
     """Out-of-core metric accumulation for eval sets that do not fit RAM.
 
@@ -66,38 +75,76 @@ class StreamingMetrics:
     never aggregated eval metrics at all (its eval module scored row by row
     and left metrics to the Shifu host); this bounds the framework's own
     `eval` CLI at O(bins) memory regardless of row count.
+
+    A chunk is reduced once, in the dtype it arrives in: one float64 pass for
+    the error, one index a row into a `2 * bins` histogram (negatives, then
+    positives), and only the bins the chunk touches are written.
     """
 
     def __init__(self, bins: int = 1 << 20):
+        if not 0 < bins <= 1 << 30:
+            raise ValueError(f"bins must be in [1, 2**30], got {bins}")
         self.bins = bins
-        self._pos = np.zeros(bins, np.float64)
-        self._neg = np.zeros(bins, np.float64)
+        self._hist = np.zeros(2 * bins, np.float64)
         self._err_sum = 0.0
         self._nonzero = 0
         self._rows = 0
 
-    def update(self, scores, labels, weights=None) -> None:
-        scores = np.asarray(scores, np.float64).ravel()
-        labels = np.asarray(labels, np.float64).ravel()
-        w = (np.ones_like(scores) if weights is None
-             else np.asarray(weights, np.float64).ravel())
-        self._rows += scores.shape[0]
-        self._err_sum += float(np.sum(w * (scores - labels) ** 2))
-        self._nonzero += int(np.sum(w != 0))
-        keep = w > 0
-        scores, labels, w = scores[keep], labels[keep], w[keep]
-        idx = np.clip((scores * self.bins).astype(np.int64), 0, self.bins - 1)
-        pos = labels >= 0.5
-        # bincount, not add.at: buffered and vectorized (~10-50x faster per
-        # chunk), which matters at the billion-row scale this class targets
-        self._pos += np.bincount(idx[pos], weights=w[pos],
-                                 minlength=self.bins)
-        self._neg += np.bincount(idx[~pos], weights=w[~pos],
-                                 minlength=self.bins)
+    @property
+    def _neg(self) -> np.ndarray:
+        return self._hist[:self.bins]
+
+    @property
+    def _pos(self) -> np.ndarray:
+        return self._hist[self.bins:]
+
+    def update(self, scores, labels, weights=None) -> np.ndarray:
+        """Fold one chunk in; returns the (n,) mask of the rows that went
+        into the histogram (weight > 0), for callers that want the same
+        rows (train/loop's score sink)."""
+        s, t = _as_chunk(scores), _as_chunk(labels)
+        # scores * bins is exact in float32 when bins is a power of two: a
+        # float32 score then lands in the bin its float64 product names
+        if s.dtype != np.float32 or self.bins & (self.bins - 1):
+            s = s.astype(np.float64, copy=False)
+        n = s.shape[0]
+        self._rows += n
+        err = np.subtract(s, t, dtype=np.float64)
+        np.multiply(err, err, out=err)
+        if weights is None:
+            keep, w = np.ones(n, np.bool_), 1.0
+            self._nonzero += n
+        else:
+            w = _as_chunk(weights)
+            np.multiply(err, w, out=err)
+            self._nonzero += int(np.count_nonzero(w != 0))
+            keep = w > 0
+            # zero weight, not compaction: x + 0.0 is x, and the rows keep
+            # their places.  Float64 on every numpy: any other operand takes
+            # np.add.at off its in-place path
+            kept, w = w, np.zeros(n, np.float64)
+            np.copyto(w, kept, where=keep)
+        self._err_sum += float(np.sum(err))
+        idx = (s * s.dtype.type(self.bins)).astype(np.int64)
+        np.clip(idx, 0, self.bins - 1, out=idx)
+        idx += (t >= 0.5) * np.int64(self.bins)
+        # np.add.at, not bincount: since numpy 1.25 it adds 65,536 rows in
+        # place in 0.19 ms on the TPU host, where bincount(minlength=2 * bins)
+        # takes 0.44 ms to fill a fresh 16 MB and the totals 0.65 ms to add
+        # it, for a few thousand touched bins (PERF.md, PR 26).  A bin takes
+        # its rows in row order; float64 holds the sums of a job's float32
+        # weights exactly, so the order shows only with float64 weights.
+        np.add.at(self._hist, idx, w)
+        return keep
 
     @property
     def rows(self) -> int:
         return self._rows
+
+    @property
+    def nonzero_rows(self) -> int:
+        """Rows whose weight is not 0: the error's denominator."""
+        return self._nonzero
 
     def weighted_error(self) -> float:
         return self._err_sum / max(self._nonzero, 1)
@@ -119,8 +166,7 @@ class StreamingMetrics:
             raise ValueError(
                 f"cannot merge StreamingMetrics with bins={other.bins} "
                 f"into bins={self.bins}")
-        self._pos += other._pos
-        self._neg += other._neg
+        self._hist += other._hist
         self._err_sum += other._err_sum
         self._nonzero += other._nonzero
         self._rows += other._rows

@@ -322,12 +322,13 @@ def _accumulate_streaming(triples, score_sink=None) -> tuple[float, float]:
     for chunk in triples:
         with obs.span("accumulate", journal=False):
             s, t, w = chunk
-            sm.update(s, t, w)
-            rows.inc(int(np.count_nonzero(np.asarray(w))))
+            seen = sm.nonzero_rows
+            counted = sm.update(s, t, w)  # the rows with weight > 0
+            rows.inc(sm.nonzero_rows - seen)
             if score_sink is not None:
                 # baseline score sketch: only rows that counted (zero-weight
                 # padding would skew the frozen score distribution)
-                score_sink(np.asarray(s)[np.asarray(w) > 0])
+                score_sink(np.asarray(s)[counted])
             del chunk, s, t, w  # the chunk's buffers go inside the phase
     with obs.span("accumulate", journal=False):  # the final reduction
         return sm.weighted_error(), sm.auc()

@@ -32,33 +32,42 @@ def _reset_obs():
     obs.reset_for_tests()
 
 
+EVAL_BATCH = 16384
+
+
 @pytest.fixture(scope="module")
 def datasets(small_job):
-    """512 train rows and a valid set of twenty eval batches, so that the
-    pass is long beside `evaluate()`'s fixed costs."""
+    """(512 train rows; a valid set of twenty eval batches of 16,384, so
+    that a batch is long beside the generator's glue between two spans,
+    ~50 us, and the pass beside `evaluate()`'s fixed costs; two train
+    batches of that size)."""
     from shifu_tpu.data import pipeline, reader, synthetic
 
-    rows = synthetic.make_rows(512 + 20 * 4096, small_job.schema, seed=11,
-                               noise=0.3)
+    rows = synthetic.make_rows(512 + 22 * EVAL_BATCH, small_job.schema,
+                               seed=11, noise=0.3)
     cols = reader.project_columns(rows, small_job.schema)
     full = pipeline.TabularDataset(cols["features"], cols["target"],
                                    cols["weight"])
+    at = 512 + 2 * EVAL_BATCH
     return (full.take(np.arange(512)),
-            full.take(np.arange(512, full.num_rows)))
+            full.take(np.arange(at, full.num_rows)),
+            full.take(np.arange(512, at)))
 
 
 @pytest.fixture(scope="module")
 def two_epochs(small_job, datasets):
     """(the `goodput` events of a two-epoch `train()`, gc.callbacks' length
-    before it, after it, and the span path after it)."""
+    before it, after it, and the span path after it).  The job's batch is
+    the eval batch: `evaluate()` takes the larger of it and 4,096."""
     obs.reset_for_tests()
     journal = obs.RunJournal(None)
     obs.set_journal(journal)
-    job = small_job.replace(train=dataclasses.replace(small_job.train,
-                                                      epochs=2))
+    job = small_job.replace(
+        data=dataclasses.replace(small_job.data, batch_size=EVAL_BATCH),
+        train=dataclasses.replace(small_job.train, epochs=2))
     hooks = len(gc.callbacks)
     try:
-        train(job, *datasets, console=lambda s: None)
+        train(job, datasets[2], datasets[1], console=lambda s: None)
     finally:
         obs.set_journal(None)
     good = [r for r in journal.records if r["kind"] == "goodput"]
@@ -149,6 +158,44 @@ def test_evaluate_closes_its_spans_also_when_a_phase_raises(small_job,
 
     with pytest.raises(RuntimeError, match="dispatch phase"):
         loop_mod.evaluate(state, datasets[0], small_job, bad_step)
+    assert obs.current_path() == ""
+
+
+def test_accumulate_feeds_the_sink_and_the_row_counter_from_one_update():
+    """PR 26: the accumulate phase takes its row count and the score sink's
+    mask from `StreamingMetrics.update` (it used to compute both again).
+    The sink still gets exactly the scores whose weight is > 0, in order,
+    and `eval_rows_total` still counts the rows whose weight is not 0."""
+    from shifu_tpu.ops.metrics import StreamingMetrics
+
+    rng = np.random.default_rng(26)
+    chunks = []
+    for n in (4096, 1, 0, 300, 4096):
+        s = rng.random(n).astype(np.float32)
+        t = (rng.random(n) < 0.4).astype(np.float32)
+        w = rng.choice([0.0, -2.0, 0.5, 1.0, 3.0], n).astype(np.float32)
+        chunks.append((s, t, w))
+    chunks.append((chunks[0][0], chunks[0][1], np.zeros(4096, np.float32)))
+
+    counter = obs.counter("eval_rows_total", "rows evaluated (nonzero weight)")
+    before = counter.total()
+    sunk = []
+    err, auc = loop_mod._accumulate_streaming(iter(chunks), sunk.append)
+
+    assert len(sunk) == len(chunks)  # one call a chunk, empty ones too
+    for got, (s, _, w) in zip(sunk, chunks):
+        assert got.dtype == s.dtype and got.ndim == 1
+        np.testing.assert_array_equal(got, s[w > 0])
+    assert sunk[-1].size == 0
+    assert counter.total() - before == sum(
+        int(np.count_nonzero(w)) for _, _, w in chunks)
+    assert counter.total() - before > sum(g.size for g in sunk)  # negatives
+
+    sm = StreamingMetrics()
+    for c in chunks:
+        sm.update(*c)
+    assert (err, auc) == (sm.weighted_error(), sm.auc())
+    assert loop_mod._accumulate_streaming(iter(chunks)) == (err, auc)
     assert obs.current_path() == ""
 
 
