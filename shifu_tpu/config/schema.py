@@ -259,8 +259,113 @@ class DataConfig:
 # ---------------------------------------------------------------------------
 
 VALID_MODEL_TYPES = ("mlp", "wide_deep", "deepfm", "multitask",
-                     "ft_transformer", "moe_mlp")
+                     "ft_transformer", "moe_mlp", "block_stack")
 VALID_ACTIVATIONS = ("sigmoid", "tanh", "relu", "leakyrelu")
+#: model types the training path runs and nothing downstream of it does
+TRAINING_ONLY_MODEL_TYPES = ("block_stack",)
+
+
+def refuse_training_only(model_type: Any, what: str) -> None:
+    """Export and serving refuse a training-only model by name, with what
+    is missing, instead of failing deep inside a lowering."""
+    if model_type in TRAINING_ONLY_MODEL_TYPES:
+        raise ConfigError(
+            f"{what} does not support model_type {model_type!r}: it is "
+            "trained only (train() and evaluate()); the op-list program has "
+            "no scan, causal-attention or routed-expert opcode, and "
+            "runtime/serve.py keeps no recurrent state between requests")
+
+
+@dataclass(frozen=True)
+class BlockStackSpec:
+    """The `block_stack` model's one configuration group: a causal sequence
+    scorer over fixed-width rows of token ids (every selected column a
+    categorical column of one vocabulary, one column a position).
+
+    `pattern` has one letter a block: `M` a Mamba-2 mixer, `*` causal
+    grouped-query attention (no positional term), `E` a routed-expert
+    layer with one shared expert.  Each block is
+    `x <- x + mixer(RMSNorm(x))`; a final RMSNorm and the last position's
+    vector feed the shared `shifu_output_0` head.  The keys are the ones a
+    published `config.json` of the hybrid families carries, so a
+    configuration is copied, not translated.
+
+    `experts_held` / `first_expert_held` say which routed experts this
+    program holds (expert parallelism's share): the router scores all
+    `n_routed_experts` and picks `num_experts_per_tok` of them a token; the
+    layer adds the part of the result its own experts give.
+    """
+
+    pattern: str = ""
+    hidden_size: int = 0
+    norm_eps: float = 1e-5
+    # M: Mamba-2 (d_inner = mamba_num_heads * mamba_head_dim)
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    n_groups: int = 1
+    ssm_state_size: int = 0
+    conv_kernel: int = 4
+    # *: causal grouped-query attention
+    num_attention_heads: int = 0
+    num_key_value_heads: int = 0
+    head_dim: int = 0
+    # E: routed experts (relu^2, no gate) beside one shared expert
+    n_routed_experts: int = 0
+    num_experts_per_tok: int = 0
+    moe_intermediate_size: int = 0
+    moe_shared_expert_intermediate_size: int = 0
+    routed_scaling_factor: float = 1.0
+    experts_held: int = 0           # 0 = all of them
+    first_expert_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.n_routed_experts
+
+    def validate(self) -> None:
+        if not self.pattern or set(self.pattern) - set("ME*"):
+            raise ConfigError(
+                f"block_stack.pattern must be letters of 'M', 'E', '*': "
+                f"{self.pattern!r}")
+        if self.hidden_size < 1:
+            raise ConfigError("block_stack.hidden_size must be positive")
+        if "M" in self.pattern:
+            if min(self.mamba_num_heads, self.mamba_head_dim,
+                   self.ssm_state_size, self.n_groups,
+                   self.conv_kernel) < 1:
+                raise ConfigError("block_stack: an 'M' block needs "
+                                  "mamba_num_heads, mamba_head_dim, n_groups, "
+                                  "ssm_state_size, conv_kernel")
+            if self.mamba_num_heads % self.n_groups:
+                raise ConfigError("block_stack.mamba_num_heads must be a "
+                                  "multiple of n_groups")
+        if "*" in self.pattern:
+            if min(self.num_attention_heads, self.num_key_value_heads,
+                   self.head_dim) < 1:
+                raise ConfigError("block_stack: a '*' block needs "
+                                  "num_attention_heads, num_key_value_heads, "
+                                  "head_dim")
+            if self.num_attention_heads % self.num_key_value_heads:
+                raise ConfigError("block_stack.num_attention_heads must be "
+                                  "a multiple of num_key_value_heads")
+        if "E" in self.pattern:
+            if min(self.n_routed_experts, self.num_experts_per_tok,
+                   self.moe_intermediate_size,
+                   self.moe_shared_expert_intermediate_size) < 1:
+                raise ConfigError("block_stack: an 'E' block needs "
+                                  "n_routed_experts, num_experts_per_tok, "
+                                  "moe_intermediate_size, "
+                                  "moe_shared_expert_intermediate_size")
+            if self.num_experts_per_tok > self.n_routed_experts:
+                raise ConfigError("block_stack.num_experts_per_tok exceeds "
+                                  "n_routed_experts")
+            if (self.first_expert_held < 0 or self.experts_held < 0
+                    or self.first_expert_held + self.held
+                    > self.n_routed_experts):
+                raise ConfigError(
+                    "block_stack: experts first_expert_held.."
+                    "first_expert_held+experts_held must lie within "
+                    "n_routed_experts")
 
 
 @dataclass(frozen=True)
@@ -322,6 +427,8 @@ class ModelSpec:
     # block's activations in the backward pass instead of storing them —
     # trades FLOPs for HBM on deep stacks / long token axes (jax.checkpoint)
     remat: bool = False
+    # block_stack: the layer pattern and the published widths, one group
+    block_stack: BlockStackSpec = field(default_factory=BlockStackSpec)
     # numerics
     param_dtype: str = "float32"
     compute_dtype: str = "bfloat16"
@@ -346,6 +453,15 @@ class ModelSpec:
                 f"fused_block must be auto/on/off: {self.fused_block!r}")
         if self.model_type == "moe_mlp" and self.num_experts < 2:
             raise ConfigError("moe_mlp requires num_experts >= 2")
+        if self.model_type == "block_stack":
+            self.block_stack.validate()
+            if self.attention_impl in ("ring", "ulysses"):
+                raise ConfigError(
+                    "block_stack runs its causal attention on one device: "
+                    f"attention_impl {self.attention_impl!r} has no causal "
+                    "mask (ops/attention.py)")
+            if self.dropout_rate > 0:
+                raise ConfigError("block_stack has no dropout")
         if self.pipeline_stages < 1 or self.pipeline_microbatches < 0:
             raise ConfigError("pipeline_stages must be >= 1 and "
                               "pipeline_microbatches >= 0")
@@ -1118,6 +1234,19 @@ class JobConfig:
                 "resident_format=int8 requires a categorical-free feature "
                 f"matrix ({len(self.schema.categorical_indices)} categorical "
                 "columns selected); use auto/wire")
+        if self.model.model_type == "block_stack":
+            by_index = {c.index: c for c in self.schema.columns}
+            cols = [by_index.get(i) for i in self.schema.selected_indices]
+            vocabs = {c.vocab_size if c is not None and c.is_categorical
+                      else 0 for c in cols}
+            if len(vocabs) != 1 or min(vocabs) < 1:
+                raise ConfigError(
+                    "block_stack reads fixed-width rows of token ids: every "
+                    "selected column must be categorical, all of one "
+                    "vocab_size (one column a position)")
+            if self.train.local_sgd_window > 0:
+                raise ConfigError("block_stack does not train under "
+                                  "local_sgd_window")
         return self
 
     # -- serialization ------------------------------------------------------

@@ -144,6 +144,8 @@ def save_artifact(params: Any, job: JobConfig, export_dir: str,
     buffers (`input:<name>`) the op-list program can reference.
     """
     import dataclasses as _dc
+    from ..config.schema import refuse_training_only
+    refuse_training_only(job.model.model_type, "export")
     if (job.model.model_type == "ft_transformer"
             and job.model.pipeline_stages > 1):
         # pipeline parallelism is a training-time layout: export ships the

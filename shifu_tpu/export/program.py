@@ -39,7 +39,7 @@ from __future__ import annotations
 
 from typing import Any, Optional
 
-from ..config.schema import DataSchema, ModelSpec
+from ..config.schema import DataSchema, ModelSpec, refuse_training_only
 from ..models.embedding import FieldLayout, field_layout
 
 PROGRAM_VERSION = 2
@@ -275,6 +275,7 @@ def build_program_v2(spec: ModelSpec,
     `schema` may be None only for models whose program is layout-free (the
     plain MLP); layout-dependent models return None without a schema.
     """
+    refuse_training_only(spec.model_type, "the op-list program")
     builder = _BUILDERS.get(spec.model_type)
     if builder is None:
         return None

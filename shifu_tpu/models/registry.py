@@ -1,7 +1,9 @@
 """Model factory: ModelSpec.model_type -> Flax module.
 
 The model ladder tracks BASELINE.md's benchmark configs: MLP (parity with the
-reference trainer), Wide&Deep, DeepFM, multi-task heads, FT-Transformer.
+reference trainer), Wide&Deep, DeepFM, multi-task heads, FT-Transformer; and
+`block_stack`, a causal sequence scorer over rows of token ids, built from a
+layer-pattern string (training only).
 """
 
 from __future__ import annotations
@@ -89,3 +91,12 @@ def _build_ft_transformer(spec: ModelSpec, schema: DataSchema,
     from .embedding import field_layout
     from .ft_transformer import FTTransformer
     return FTTransformer(spec=spec, layout=field_layout(schema), mesh=mesh)
+
+
+@register("block_stack")
+def _build_block_stack(spec: ModelSpec, schema: DataSchema,
+                       mesh=None) -> nn.Module:
+    from .block_stack import BlockStack
+    from .embedding import field_layout
+    return BlockStack(spec=spec,
+                      vocab_size=max(field_layout(schema).vocab_sizes))

@@ -140,3 +140,45 @@ def ulysses_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         check_vma=False,
     )
     return fn(q, k, v)
+
+
+#: queries of one row are taken this many at a time, each against the keys
+#: up to its own end: a (heads, 1024, T) score block, not (heads, T, T)
+CAUSAL_QUERY_BLOCK = 1024
+
+
+def _causal_block(q, k, v, first: int, scale: float):
+    """Queries first..first+Q of one row against keys 0..first+Q.  q (Q, G,
+    R, D) with R query heads a key-value head, k / v (S, G, D), S = first+Q.
+    Softmax in float32; the products take operands in v's dtype."""
+    scores = jnp.einsum("qgrd,sgd->grqs", q, k,
+                        preferred_element_type=jnp.float32) * scale
+    qpos = first + jnp.arange(q.shape[0])
+    mask = qpos[:, None] >= jnp.arange(k.shape[0])[None, :]
+    w = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("grqs,sgd->qgrd", w.astype(v.dtype), v,
+                      preferred_element_type=jnp.float32).astype(v.dtype)
+
+
+def causal_gqa(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """Causal grouped-query attention, no positional term.  q (B, T, Hq, D),
+    k / v (B, T, Hkv, D), Hq a multiple of Hkv (query head h reads key-value
+    head h // (Hq // Hkv)).  Returns (B, T, Hq, D).
+
+    Plain XLA: a row at a time, a block of queries at a time, each block
+    rematerialized so that the backward holds a row's q, k, v and one
+    block's scores; keys past a block's last query are never read."""
+    b, t, hq, d = q.shape
+    g = k.shape[2]
+    scale = 1.0 / (d ** 0.5)
+    blk = CAUSAL_QUERY_BLOCK if t % CAUSAL_QUERY_BLOCK == 0 else t
+    block = jax.checkpoint(_causal_block, static_argnums=(3, 4))
+
+    def row(xs):
+        q_r, k_r, v_r = xs
+        q_r = q_r.reshape(t, g, hq // g, d)
+        out = [block(q_r[lo:lo + blk], k_r[:lo + blk], v_r[:lo + blk], lo,
+                     scale) for lo in range(0, t, blk)]
+        return jnp.concatenate(out, axis=0).reshape(t, hq, d)
+
+    return jax.lax.map(row, (q, k, v))

@@ -43,7 +43,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from ..config.schema import ServingConfig
+from ..config.schema import ServingConfig, refuse_training_only
 from ..obs import drift as drift_mod
 from ..obs import slo as slo_mod
 
@@ -66,6 +66,17 @@ class ServeOverload(RuntimeError):
     caller (retry / shed upstream), never an unbounded-latency queue."""
 
 
+def _artifact_model_type(export_dir: str):
+    """The artifact's `model_type`, or None where it has no readable
+    topology (the engines then say what is wrong with it themselves)."""
+    from ..export.artifact import TOPOLOGY
+    try:
+        with open(os.path.join(export_dir, TOPOLOGY)) as f:
+            return json.load(f).get("model_type")
+    except (OSError, ValueError):
+        return None
+
+
 def load_engine(export_dir: str, engine: str = "auto"):
     """Build one scoring engine for an artifact — the tier ladder shared
     by `shifu-tpu score/eval` (launcher/cli.py delegates here) and the
@@ -79,6 +90,7 @@ def load_engine(export_dir: str, engine: str = "auto"):
     `aot_load`); any mismatch or damage journals `aot_fallback` and
     degrades to JaxScorer — an explicit `--engine aot` is a preference,
     never a refused load."""
+    refuse_training_only(_artifact_model_type(export_dir), "serving")
     if engine == "aot":
         from ..export.aot import try_load_aot
         scorer = try_load_aot(export_dir)

@@ -30,7 +30,8 @@ from ..parallel import mesh as mesh_lib
 from ..parallel import sharding as shard_lib
 from . import checkpoint as ckpt_lib
 from .optimizers import build_optimizer
-from .step import make_epoch_scan_step, make_eval_step, make_train_step
+from .step import (make_epoch_scan_step, make_eval_step, make_train_step,
+                   split_readback)
 from .train_state import TrainState
 
 Console = Callable[[str], None]
@@ -117,8 +118,10 @@ def init_state(job: JobConfig, num_features: int,
         init_batch *= (job.model.pipeline_microbatches
                        or job.model.pipeline_stages)
     dummy = jnp.zeros((init_batch, num_features), jnp.float32)
-    variables = model.init(rng, dummy)
-    params = variables["params"]
+    # one program, and only the parameters come out of it: the forward pass
+    # that shapes them is dead code there, where op-by-op it would run (and
+    # compile) every operation of a deep model once
+    params = jax.jit(lambda r, x: model.init(r, x)["params"])(rng, dummy)
     # sparse embedding updates (train/sparse_embed.py): tables are masked
     # OUT of the dense optax transformation and their moment slots live on
     # TrainState.table_slots, updated rows-touched-only by the step
@@ -158,7 +161,7 @@ def init_state(job: JobConfig, num_features: int,
             rules += tuple(VOCAB_SHARD_RULES)
         if job.runtime.mesh.model > 1:
             rules += tuple(shard_lib.DEFAULT_RULES)
-            if job.model.model_type == "moe_mlp":
+            if job.model.model_type in ("moe_mlp", "block_stack"):
                 # expert parallelism: stacked expert trunks shard by expert
                 # over `model`; XLA inserts the psum of the gated combine
                 rules += ((r".*\bexperts/.*", P("model")),)
@@ -305,6 +308,24 @@ def _baseline_feature_names(schema, num_features: int):
     return names if len(names) == num_features else None
 
 
+def _tree_sum(acc, new):
+    """`acc + new` over what an epoch's steps hand back (a loss sum, or a
+    loss sum with counters beside it); `acc` None is the first."""
+    if acc is None:
+        return new
+    return jax.tree_util.tree_map(jnp.add, acc, new)
+
+
+def _moe_layers(counters: dict) -> list[dict]:
+    """The `moe` journal event's layers from the model's summed counters
+    (models/block_stack.ExpertsBlock), a leading axis an E layer."""
+    per = np.asarray(counters["tokens_per_expert"])
+    return [{"tokens_per_expert": [int(v) for v in per[i]],
+             **{k: int(np.asarray(counters[k])[i]) for k in
+                ("routed_slots", "held_slots", "tokens_dropped")}}
+            for i in range(per.shape[0])]
+
+
 def _accumulate_streaming(triples, score_sink=None) -> tuple[float, float]:
     """THE eval accumulation: one StreamingMetrics over (scores, labels,
     weights) chunks, shared by the single-host and multihost branches of
@@ -356,15 +377,20 @@ def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
     multihost = jax.process_count() > 1 and mesh is not None
     if not multihost and ds.num_rows == 0:
         return float("nan"), float("nan")
-    bs = batch_size or max(job.data.batch_size, 4096)
+    # an eval batch holds at least 4,096 rows, which amortizes a tabular
+    # batch's dispatch; a row that is itself that wide is a sequence of
+    # thousands of positions, a batch's worth of work alone, and there the
+    # floor is the train batch, whose activations are known to fit
+    floor = 4096 if ds.num_features < 4096 else job.data.batch_size
+    bs = batch_size or max(job.data.batch_size, floor)
     if not multihost and ds.num_rows < bs:
         # a huge train batch must not size the eval batch: padding a small
         # valid set up to a 100k-row batch wastes H2D bytes and device work
         # on zero-weight rows every epoch.  Cap at the dataset rounded up
-        # to a 4096 quantum (static shapes; single-host only — multihost
-        # derives collective step counts from the shared bs, and a
-        # host-local row count there would diverge the program)
-        bs = max(-(-ds.num_rows // 4096) * 4096, 4096)
+        # to a quantum of the floor (static shapes; single-host only —
+        # multihost derives collective step counts from the shared bs, and
+        # a host-local row count there would diverge the program)
+        bs = max(-(-ds.num_rows // floor) * floor, floor)
     if mesh is not None:
         # keep the per-device shard static
         bs = -(-bs // mesh.size) * mesh.size
@@ -1161,8 +1187,7 @@ def train(job: JobConfig,
                             break
                         timer.mark_input_ready()
                         state, loss_sum_blk = epoch_scan_step(state, pending)
-                        loss_acc = (loss_sum_blk if loss_acc is None
-                                    else loss_acc + loss_sum_blk)
+                        loss_acc = _tree_sum(loss_acc, loss_sum_blk)
                         loss_n += nb_stream
                         timer.mark_step_done()
                     if epoch + 1 >= job.train.epochs:
@@ -1191,8 +1216,7 @@ def train(job: JobConfig,
                             put_fn=_block_put_fn(wcast_stream)):
                         timer.mark_input_ready()
                         state, loss_sum_blk = epoch_scan_step(state, blocks)
-                        loss_acc = (loss_sum_blk if loss_acc is None
-                                    else loss_acc + loss_sum_blk)
+                        loss_acc = _tree_sum(loss_acc, loss_sum_blk)
                         timer.mark_step_done()
                         # chunk boundary = consistent state: SIGTERM drain
                         # + time-cadence saves mid-epoch (long first epochs
@@ -1285,8 +1309,7 @@ def train(job: JobConfig,
                     timer.mark_input_ready()
                     nb = blocks["features"].shape[0]
                     state, loss_sum_blk = epoch_scan_step(state, blocks)
-                    loss_acc = (loss_sum_blk if loss_acc is None
-                                else loss_acc + loss_sum_blk)
+                    loss_acc = _tree_sum(loss_acc, loss_sum_blk)
                     loss_n += nb
                     timer.mark_step_done()
                     if not multihost:
@@ -1324,8 +1347,9 @@ def train(job: JobConfig,
                 for batch in batch_iter:
                     timer.mark_input_ready()
                     state, step_metrics = train_step(state, batch)
-                    loss = step_metrics["loss"]
-                    loss_acc = loss if loss_acc is None else loss_acc + loss
+                    loss_acc = _tree_sum(
+                        loss_acc, step_metrics if "counters" in step_metrics
+                        else step_metrics["loss"])
                     loss_n += 1
                     timer.mark_step_done()
                     if not multihost:  # collectives forbid divergent exits
@@ -1340,7 +1364,8 @@ def train(job: JobConfig,
             # seconds belong to the ledger's `step` bucket (dispatch-to-
             # done on every tier), not to `other`
             with obs.span("device_wait", journal=False) as device_wait:
-                loss_sum = float(jax.device_get(loss_acc))
+                loss_sum, step_counters = split_readback(
+                    jax.device_get(loss_acc))
         epoch_time = time.perf_counter() - t0
 
         tv0 = time.perf_counter()
@@ -1534,6 +1559,11 @@ def train(job: JobConfig,
             gc_phases.fold(led)
             obs.goodput.end_epoch(
                 epoch, time.perf_counter() - t0 + ingest_wall_s)
+        if "moe" in step_counters:
+            # where the routed expert layers' tokens went this epoch, one
+            # entry an E layer: summed on the device, read with the loss
+            obs.event("moe", epoch=epoch, layers=_moe_layers(
+                step_counters["moe"]))
 
         # flight-recorder epoch boundary: close a one-shot anomaly trace
         # still open (anomaly on the epoch's last chunk) and journal the

@@ -82,6 +82,18 @@ def make_wire_decode(job: JobConfig):
     return decode
 
 
+def split_readback(got) -> tuple[float, dict]:
+    """(loss sum, counters) of what an epoch's step handed back: the loss
+    sum alone, or with the sums of what the model sowed beside it."""
+    if isinstance(got, dict):
+        return float(got["loss"]), got.get("counters", {})
+    return float(got), {}
+
+
+def _with_counters(loss, counters):
+    return {"loss": loss, "counters": counters} if counters else loss
+
+
 def make_loss_fn(job: JobConfig):
     """Training loss.  With ModelConfig DropoutRate > 0 the forward pass
     runs with `train=True` and a per-update dropout rng derived from
@@ -149,17 +161,54 @@ def make_apply_gradients(job: JobConfig, mesh: Optional[Mesh] = None):
     return lambda st, grads, batch: sparse(st, grads, batch)
 
 
+def _catching_counters(loss_fn):
+    """`loss_fn` as `(loss, counters)`: the model is applied with its
+    `counters` collection mutable and what it sowed there (per-call counts
+    for the step to sum over an epoch: the block stack's routed expert
+    layers count where their tokens went; most models sow nothing, and {}
+    comes out) is handed out beside the loss, whatever `loss_fn` does
+    around the call (it stays a scalar loss, as `make_loss_fn` promises its
+    other callers)."""
+    def with_counters(params, apply_fn, xs, step):
+        sown = {}
+
+        def apply(variables, *args, **kwargs):
+            out, state = apply_fn(variables, *args, mutable=["counters"],
+                                  **kwargs)
+            sown.update(state.get("counters", {}))
+            return out
+
+        return loss_fn(params, apply, xs, step), sown
+
+    return with_counters
+
+
 def _fwd_bwd_and_update(loss_fn, apply_grads, st: TrainState, xs: Batch):
     """One optimizer step, the body every step builder shares: (new state,
-    loss).  Its two parts carry stable device-side names (`jax.named_scope`:
-    HLO metadata only, the program is the same) so that a profile's
-    operations can be rolled up by part whatever the fusion numbering."""
+    loss, what the model sowed in `counters`: {} for most).  Its two parts
+    carry stable device-side names (`jax.named_scope`: HLO metadata only,
+    the program is the same) so that a profile's operations can be rolled up
+    by part whatever the fusion numbering."""
     with jax.named_scope("fwd_bwd"):
-        loss, grads = jax.value_and_grad(loss_fn)(
+        (loss, counters), grads = jax.value_and_grad(
+            _catching_counters(loss_fn), has_aux=True)(
             st.params, st.apply_fn, xs, st.step)
     with jax.named_scope("optimizer"):
         st = apply_grads(st, grads, xs)
-    return st, loss
+    return st, loss, counters
+
+
+def _scan_steps(body_step, state: TrainState, xs):
+    """Scan `body_step(state, x) -> (state, loss, counters)` over `xs`:
+    (new state, the loss sum, or with the counters' sums beside it)."""
+    def body(carry, x):
+        st, acc = carry
+        st, loss, counters = body_step(st, x)
+        return (st, acc + loss), counters
+
+    (state, acc), counters = jax.lax.scan(body, (state, jnp.float32(0.0)), xs)
+    return state, _with_counters(
+        acc, jax.tree_util.tree_map(lambda v: jnp.sum(v, axis=0), counters))
 
 
 def _input_donate_argnums(donate: bool, donate_batch: bool) -> tuple:
@@ -200,9 +249,12 @@ def make_train_step(job: JobConfig, mesh: Optional[Mesh] = None,
     apply_grads = make_apply_gradients(job, mesh)
 
     def step(state: TrainState, batch: Batch):
-        new_state, loss = _fwd_bwd_and_update(loss_fn, apply_grads, state,
-                                              batch)
-        return new_state, {"loss": loss}
+        new_state, loss, counters = _fwd_bwd_and_update(
+            loss_fn, apply_grads, state, batch)
+        metrics = {"loss": loss}
+        if counters:
+            metrics["counters"] = counters
+        return new_state, metrics
 
     # Shardings ride on the input arrays themselves (state placed by
     # init_state, batches device_put by the loop with data-axis sharding);
@@ -221,7 +273,9 @@ def make_epoch_scan_step(job: JobConfig, mesh: Optional[Mesh] = None,
 
     Input: {'features': (nb, B, F), 'target': (nb, B, H), 'weight': (nb, B, 1)}
     (sharded on the batch axis over `data` when a mesh is in play).  Returns
-    (new_state, loss_sum over the nb batches).  One jit dispatch and one H2D
+    (new_state, loss_sum over the nb batches; where the model sows counters,
+    `{"loss": loss_sum, "counters": their sums}` - `split_readback` reads
+    either).  One jit dispatch and one H2D
     transfer cover nb optimizer steps — the input-path design that closes the
     gap between host-fed (~5M samples/s) and compute-bound (~650M samples/s)
     throughput on a v5e chip.
@@ -230,14 +284,9 @@ def make_epoch_scan_step(job: JobConfig, mesh: Optional[Mesh] = None,
     apply_grads = make_apply_gradients(job, mesh)
 
     def epoch_step(state: TrainState, blocks: Batch):
-        def body(carry, xs):
-            st, acc = carry
-            st, loss = _fwd_bwd_and_update(loss_fn, apply_grads, st, xs)
-            return (st, acc + loss), None
-
-        (state2, acc), _ = jax.lax.scan(
-            body, (state, jnp.float32(0.0)), blocks)
-        return state2, acc
+        return _scan_steps(
+            lambda st, xs: _fwd_bwd_and_update(loss_fn, apply_grads, st, xs),
+            state, blocks)
 
     from ..obs.introspect import instrument_jit
     return instrument_jit(
@@ -260,19 +309,16 @@ def make_device_epoch_step(job: JobConfig, mesh: Optional[Mesh] = None,
     apply_grads = make_apply_gradients(job, mesh)
 
     def epoch_step(state: TrainState, blocks: Batch, order: jax.Array):
-        def body(carry, idx):
-            st, acc = carry
+        def one(st, idx):
             # dynamic slice (no dataset copy): axis 0 is unsharded, so this
             # is a local HBM read on every device
             xs = jax.tree_util.tree_map(
                 lambda a: jax.lax.dynamic_index_in_dim(a, idx, axis=0,
                                                        keepdims=False),
                 blocks)
-            st, loss = _fwd_bwd_and_update(loss_fn, apply_grads, st, xs)
-            return (st, acc + loss), None
+            return _fwd_bwd_and_update(loss_fn, apply_grads, st, xs)
 
-        (state2, acc), _ = jax.lax.scan(body, (state, jnp.float32(0.0)), order)
-        return state2, acc
+        return _scan_steps(one, state, order)
 
     from ..obs.introspect import instrument_jit
     donate_argnums = (0,) if donate else ()
