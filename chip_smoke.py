@@ -1023,9 +1023,8 @@ def child_kernels(args) -> int:
         "forward": "one-hot matmul" if pe._onehot_ok(dfm_vocab, 0)
         else "XLA gather",
         "backward": "one-hot matmul" if pe._onehot_ok(dfm_vocab, 0)
-        else ("segment_sum, " + ("flat" if pe._segment_use_flat(
-            6, dfm_vocab) else "per table") if on_tpu()
-            else "scatter-add")})
+        else ("segment_sum, a field at a time" if on_tpu()
+              else "scatter-add")})
     three_steps("deepfm_100k_vocab", JobConfig(
         schema=synthetic.make_schema(num_features=NUM_FEATURES,
                                      num_categorical=6,

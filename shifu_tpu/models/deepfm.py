@@ -37,8 +37,7 @@ class DeepFM(nn.Module):
 
         # field vectors (B, F, k): numeric + categorical share the FM space.
         # The k-dim FM/deep table and the scalar first-order table read the
-        # SAME ids, so they share one fused lookup (embedding.fused_lookup)
-        # — the gather/segment-grad cost is per-row, not per-byte.
+        # SAME ids, each with a lookup of its own (embedding.paired_cat_embed)
         vecs = []
         cat_first = None
         if self.layout.num_numeric:

@@ -3,8 +3,8 @@
 Nothing like this exists in the reference (its MLP consumes pre-normalized
 floats only — resources/ssgd_monitor.py:113-121); the design is fresh for the
 BASELINE ladder's Wide&Deep / DeepFM / FT-Transformer rungs.  TPU-first
-choices: one fused table per categorical field; lookups are `jnp.take` so XLA
-lowers them to gathers that shard cleanly when tables carry a
+choices: one stacked table per model input; lookups are XLA gathers
+(ops/pallas_embedding.py) that shard cleanly when tables carry a
 `PartitionSpec("model", None)` (parallel/sharding.py DEFAULT_RULES) — the
 successor of the reference's variables-on-PS placement
 (ssgd_monitor.py:202-206), with the gather's collective riding ICI.
@@ -13,7 +13,6 @@ successor of the reference's variables-on-PS placement
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Sequence
 
 import flax.linen as nn
 import jax
@@ -86,11 +85,11 @@ class CategoricalEmbed(nn.Module):
 
     Tables are stacked per field (ragged vocabs padded to the max) so one
     gather serves all fields — fewer, larger ops for XLA, and a single
-    sharding rule puts the vocab axis on `model`.  `table()` exposes the
-    compute-dtype table so a caller holding several embeds over the SAME
-    ids can concat along dim and pay ONE lookup (see fused_lookup) — the
-    per-update cost of a gather/segment-grad pair is mostly per-row, not
-    per-byte, so two lookups cost nearly twice one.
+    sharding rule puts the vocab axis on `model`.  The lookup reads the
+    rows where the table lives: it gathers from the `param_dtype`
+    parameter and casts the (B, Nc, dim) rows it got, so nothing the size
+    of the table is made on the way in, and the gradient comes back in
+    the parameter's dtype, summed at the rows (ops/pallas_embedding.py).
     """
 
     layout: FieldLayout
@@ -108,66 +107,43 @@ class CategoricalEmbed(nn.Module):
                 (self.layout.num_categorical, max_vocab, self.dim),
                 dtype_of(self.param_dtype))
 
-    def table(self) -> jax.Array:
-        return self.embedding.astype(dtype_of(self.compute_dtype))
-
     def __call__(self, ids: jax.Array) -> jax.Array:
-        if self.layout.num_categorical == 0:
-            return jnp.zeros((ids.shape[0], 0, self.dim),
-                             dtype_of(self.compute_dtype))
-        # gather per field: ids (B, Nc) -> (B, Nc, dim).  Routed through
-        # ops/pallas_embedding.embedding_lookup: XLA gather by default, the
-        # manual-DMA Pallas kernel under SHIFU_TPU_PALLAS=1.
-        from ..ops.pallas_embedding import embedding_lookup
-        with jax.named_scope("embed_gather"):
-            return embedding_lookup(self.table(), ids.astype(jnp.int32))
+        return lookup_embeds([self], ids)[0]
 
 
-def fused_lookup(embeds: Sequence[CategoricalEmbed], ids: jax.Array
-                 ) -> list[jax.Array]:
-    """One lookup for several CategoricalEmbeds sharing the same ids.
+def lookup_embeds(embeds, ids: jax.Array) -> list[jax.Array]:
+    """ids (B, Nc) -> one (B, Nc, dim) per embed, for CategoricalEmbeds of
+    one layout and compute dtype that read the same ids.  Routed through
+    ops/pallas_embedding.lookup_rows: an XLA gather from each parameter by
+    default (the manual-DMA Pallas kernel under SHIFU_TPU_PALLAS=1), one
+    MXU product a field for all of them together at small vocabularies."""
+    from ..ops.pallas_embedding import lookup_rows
 
-    Concats the tables along dim (cheap: HBM copy, exact), gathers once,
-    splits the result back per embed.  Identical values to calling each
-    embed separately; roughly halves the sparse-path cost for the models
-    that pair a k-dim FM/deep table with a scalar first-order table over
-    the same fields (DeepFM, Wide&Deep).
-
-    Under the SHIFU_TPU_PALLAS=1 opt-in the embeds are looked up
-    separately instead: the manual-DMA kernel requires D % 128 == 0, and
-    a concat of a 128-aligned table with a scalar one would silently
-    demote BOTH to the XLA gather.
-    """
-    from ..ops.pallas_embedding import embedding_lookup
-    from ..ops.pallas_common import pallas_opt_in
-
-    if pallas_opt_in():
-        return [e(ids) for e in embeds]
+    first = embeds[0]
+    cdt = dtype_of(first.compute_dtype)
+    if first.layout.num_categorical == 0:
+        return [jnp.zeros((ids.shape[0], 0, e.dim), cdt) for e in embeds]
     with jax.named_scope("embed_gather"):
-        fused = embedding_lookup(
-            jnp.concatenate([e.table() for e in embeds], axis=-1),
-            ids.astype(jnp.int32))
-    outs, off = [], 0
-    for e in embeds:
-        outs.append(fused[..., off:off + e.dim])
-        off += e.dim
-    return outs
+        return lookup_rows([e.embedding for e in embeds],
+                           ids.astype(jnp.int32), cdt)
 
 
 def paired_cat_embed(layout: FieldLayout, spec: ModelSpec, big_name: str,
                      small_name: str, ids: jax.Array
                      ) -> tuple[jax.Array, jax.Array]:
     """The (embedding_dim table, num_heads table) pair over shared ids
-    that DeepFM and Wide&Deep both use, through one fused lookup.
+    that DeepFM and Wide&Deep both use, each looked up in its own
+    parameter (joining them along dim for one gather makes, splits and
+    re-lays-out whole tables, forward and backward: far more than a second
+    gather and scatter-add of a batch's rows cost; `lookup_rows` joins
+    them only where the one-hot strategy serves).
     Returns ((B, Nc, embedding_dim), (B, Nc, num_heads))."""
-    big, small = fused_lookup(
-        [CategoricalEmbed(layout=layout, dim=spec.embedding_dim,
+    big, small = lookup_embeds(
+        [CategoricalEmbed(layout=layout, dim=dim,
                           param_dtype=spec.param_dtype,
-                          compute_dtype=spec.compute_dtype, name=big_name),
-         CategoricalEmbed(layout=layout, dim=spec.num_heads,
-                          param_dtype=spec.param_dtype,
-                          compute_dtype=spec.compute_dtype,
-                          name=small_name)], ids)
+                          compute_dtype=spec.compute_dtype, name=name)
+         for dim, name in ((spec.embedding_dim, big_name),
+                           (spec.num_heads, small_name))], ids)
     return big, small
 
 

@@ -37,9 +37,8 @@ class WideDeep(nn.Module):
                           param_dtype=self.spec.param_dtype,
                           compute_dtype=self.spec.compute_dtype,
                           name="wide_linear")(numeric)
-        # wide per-id bias + deep embedding read the SAME ids: one fused
-        # lookup (embedding.fused_lookup) — gather/segment-grad cost is
-        # per-row, not per-byte
+        # wide per-id bias + deep embedding read the SAME ids, each with a
+        # lookup of its own (embedding.paired_cat_embed)
         emb = None
         if self.layout.num_categorical:
             emb, cat_bias = paired_cat_embed(

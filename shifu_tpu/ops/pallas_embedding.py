@@ -12,9 +12,11 @@ the table itself never materializes in VMEM.  Per grid step the kernel body
 is a pure VMEM copy of one (1, 1, D) row.  The backward pass picks one of
 three gradient strategies under a custom VJP: small-vocab tables become
 one-hot matmuls on the MXU (`_onehot_grad`), large-vocab tables on TPU use
-per-table 1-D segment reductions (`_segment_grad` — 4.2x the combined 2-D
-scatter-add on a v5e), and CPU (or an explicit use_pallas=False reference
-request) keeps the plain XLA `.at[].add` scatter (`_scatter_grad`).
+1-D segment reductions, a field at a time (`_segment_grad`), and CPU (or an
+explicit use_pallas=False reference request) keeps the plain XLA `.at[].add`
+scatter (`_scatter_grad`).  Models whose compute dtype is not their
+parameters' come in through `lookup_rows`: float32 rows are gathered and
+cast, and the gradient is summed in float32 at the rows.
 
 CPU/testing: falls back to `interpret=True` off-TPU so the same code path is
 unit-tested on the virtual CPU mesh.  On real TPU hardware the kernel is
@@ -110,9 +112,21 @@ def _pallas_lookup(table: jax.Array, ids: jax.Array,
 
 
 def _xla_lookup(table: jax.Array, ids: jax.Array) -> jax.Array:
-    # reference implementation (same math as models/embedding.CategoricalEmbed)
-    return jnp.take_along_axis(
-        table[None, :, :, :], ids[:, :, None, None], axis=2)[:, :, 0, :]
+    """The XLA gather, `table[f, ids[b, f], :]` for every (b, f): ids in
+    [-V, 0) wrap, anything outside [-V, V) NaN-fills.
+
+    A table whose rows are one value wide (DeepFM's and Wide&Deep's
+    first-order tables) is gathered as scalars, the unit axis indexed and
+    not sliced: same values, same device time.  The row gather asks XLA:TPU
+    for such a table re-tiled ({1,0,2:T(8,128)} where the runtime keeps it
+    {1,2,0:T(1,128)}); inside an epoch's scan that is a second copy of the
+    table and of both its optimizer slots for the length of the loop, and
+    Criteo-size state has no room for it (PERF.md, PR 28)."""
+    field = jnp.arange(table.shape[0], dtype=ids.dtype)[None, :]
+    if table.shape[-1] == 1:
+        return table.at[field, ids, 0].get(
+            mode="fill", fill_value=jnp.nan)[..., None]
+    return table.at[field, ids].get(mode="fill", fill_value=jnp.nan)
 
 
 # One-hot-matmul strategy caps: the one-hot operand's size (and the matmul's
@@ -265,70 +279,33 @@ def _scatter_grad(ids: jax.Array, table_shape, g: jax.Array) -> jax.Array:
         g.reshape(-1, table_shape[-1]).astype(jnp.float32))
 
 
-# Per-table unrolled segment sums measured fastest at small field counts
-# (NC=6), but the unroll emits NC independent ops — at the 1000-column
-# rung's ~50 fields the backward HLO grows linearly and compile time with
-# it.  Wide schemas therefore flatten to ONE segment_sum over NC*V
-# segments (constant op count at any width); the crossover is coarse and
-# overridable for A/Bs.
-_SEGMENT_FLAT_MIN_FIELDS = 16
-
-
-def _segment_flat_min_fields() -> int:
-    import os
-    try:
-        return int(os.environ.get("SHIFU_TPU_SEGMENT_FLAT_MIN_FIELDS",
-                                  _SEGMENT_FLAT_MIN_FIELDS))
-    except ValueError:
-        return _SEGMENT_FLAT_MIN_FIELDS
-
-
-def _segment_use_flat(nc: int, v: int) -> bool:
-    """Route wide schemas to the flattened single-segment_sum form — but
-    ONLY while the flat id space nc*V (+1 sentinel) fits int32: past that,
-    `field * v` would silently overflow and alias gradients into other
-    tables' rows, so giant-vocab-times-many-fields schemas keep the
-    per-table unroll (which has no combined-id limit)."""
-    return (nc >= _segment_flat_min_fields()
-            and nc * v + 1 <= np.iinfo(np.int32).max)
-
-
 def _segment_grad(ids: jax.Array, table_shape, g: jax.Array) -> jax.Array:
-    """The same gradient as `_scatter_grad`, lowered as 1-D segment
-    reductions instead of one combined 2-D scatter — XLA:TPU turns the
-    segment form into a far faster program (measured 4.2x on a v5e at
-    vocab 100k: 11.2M vs 2.6M update-rows/s; no pre-sort needed, a sort
-    actually measured slower).  Id semantics match the scatter exactly:
-    negative ids wrap once, anything outside [-V, V) contributes nothing
-    (segment_sum drops out-of-range segment ids the way `.at[].add` drops
-    out-of-bounds updates).
+    """The same gradient as `_scatter_grad`, accumulated a field at a time:
+    a 1-D segment reduction of that field's rows into its own (V, D)
+    table, the fields walked by `lax.map` and stacked.  Id semantics match
+    the scatter exactly: negative ids wrap once, anything outside [-V, V)
+    contributes nothing (segment_sum drops out-of-range segment ids the way
+    `.at[].add` drops out-of-bounds updates).
 
-    Narrow schemas keep the per-table unroll (fastest at NC=6); wide ones
-    (NC >= SHIFU_TPU_SEGMENT_FLAT_MIN_FIELDS) flatten every (row, field)
-    update into one segment_sum over NC*V segments so the backward program
-    stays one op regardless of field count.  The threshold env is read at
-    TRACE time: under jit it bakes into the compiled program, so A/Bs must
-    set it before the first compile (fresh process / fresh jit), not flip
-    it mid-run."""
-    nc, v, _ = table_shape
+    Why a field at a time, on a v5e (PERF.md, PR 28): XLA:TPU lowers every
+    scatter-add, this one included, onto a (D, rows) buffer, rows minor.
+    One reduction over all fields at once - flat ids `field * V + id`, or a
+    2-D scatter into the stacked table, which the compiler flattens the
+    same way - therefore hands back (D, Nc*V), and turning that into the
+    stacked (Nc, V, D) table the optimizer reads is a re-tiling of the
+    whole gradient by slice updates: 75 ms a step at Criteo size against
+    21 ms for the reduction.  A field's (D, V) result is already the
+    field's slice of the stacked table as the device lays it out, so
+    stacking is a plain copy.  The walk is one loop whatever the field
+    count (an unrolled one grew the program, and its compile, with the
+    schema's width), and no combined id exists to overflow int32."""
+    _, v, _ = table_shape
     ids = ids.astype(jnp.int32)
     wrapped = jnp.where(ids < 0, ids + v, ids)
-    gf = g.astype(jnp.float32)
-    if not _segment_use_flat(nc, v):
-        return jnp.stack([
-            jax.ops.segment_sum(gf[:, f, :], wrapped[:, f], num_segments=v)
-            for f in range(nc)])
-    # flattened: segment id = field*V + wrapped id.  Out-of-range ids must
-    # be masked BEFORE the field offset (id V+3 in field f would otherwise
-    # alias into field f+1's table); NC*V is one past the last segment, so
-    # segment_sum drops it — same drop semantics as the per-table form.
-    valid = (wrapped >= 0) & (wrapped < v)
-    field = jnp.broadcast_to(jnp.arange(nc, dtype=jnp.int32)[None, :],
-                             wrapped.shape)
-    flat = jnp.where(valid, field * v + wrapped, nc * v)
-    out = jax.ops.segment_sum(gf.reshape(-1, gf.shape[-1]), flat.reshape(-1),
-                              num_segments=nc * v + 1)
-    return out[:nc * v].reshape(table_shape)
+    gf = jnp.swapaxes(g.astype(jnp.float32), 0, 1)      # (Nc, B, D)
+    return jax.lax.map(
+        lambda x: jax.ops.segment_sum(x[0], x[1], num_segments=v),
+        (gf, wrapped.T))
 
 
 def _bwd(use_pallas, res, g):
@@ -345,6 +322,32 @@ def _bwd(use_pallas, res, g):
 
 
 embedding_lookup.defvjp(_fwd, _bwd)
+
+
+def lookup_rows(tables, ids: jax.Array, dtype) -> list[jax.Array]:
+    """`embedding_lookup(table, ids)` in `dtype` for each of `tables` (same
+    fields and vocabulary, the same ids), for a model whose compute dtype
+    is not its parameters'.
+
+    The rows are gathered from each parameter itself and cast once
+    gathered: a cast commutes with a gather, so the values are those of a
+    lookup in the cast table, while nothing table-sized is made on the way
+    in, and on the way back the gradient is summed at the rows in the
+    parameter's dtype and never rounded to `dtype`.
+
+    The small-vocab one-hot strategy is the exception, by the predicate
+    `embedding_lookup` itself goes by: it multiplies by the table on the
+    MXU, wants it in `dtype` (exact row copies only then), and serves any
+    number of tables with one product a field - so there the tables are
+    cast and joined along dim first, which costs less than the batch's
+    rows do (such a table is smaller than they are), and split after."""
+    tables = list(tables)
+    if not pallas_opt_in() and _onehot_ok(tables[0].shape[1], ids.size):
+        joined = embedding_lookup(
+            jnp.concatenate([t.astype(dtype) for t in tables], axis=-1), ids)
+        ends = np.cumsum([t.shape[-1] for t in tables])
+        return jnp.split(joined, ends[:-1], axis=-1)
+    return [embedding_lookup(t, ids).astype(dtype) for t in tables]
 
 
 # ---------------------------------------------------------------------------

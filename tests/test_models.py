@@ -193,26 +193,127 @@ def test_moe_mlp_learns():
     assert result.history[-1].valid_auc > 0.62, result.history[-1]
 
 
-def test_fused_pair_lookup_matches_separate(monkeypatch):
-    """DeepFM / Wide&Deep logits are bit-identical whether the paired
-    categorical tables go through the fused single lookup or per-embed
-    lookups (the SHIFU_TPU_PALLAS fallback path)."""
-    from shifu_tpu.models import embedding as emb_mod
-
+def _table_model(model_type, vocab=50, **spec_kw):
+    """A small table model at bfloat16 compute over float32 parameters,
+    its variables and a batch whose categorical cells hold ids."""
     schema = synthetic.make_schema(num_features=12, num_categorical=4,
-                                   vocab_size=50)
+                                   vocab_size=vocab)
     x = np.random.default_rng(3).standard_normal((16, 12)).astype(np.float32)
-    x[:, 8:] = np.random.default_rng(4).integers(0, 50, (16, 4))
+    x[:, 8:] = np.random.default_rng(4).integers(0, vocab, (16, 4))
     x = jnp.asarray(x)
-    for model_type in ("deepfm", "wide_deep"):
-        spec = ModelSpec(model_type=model_type, hidden_nodes=(8,),
-                         activations=("relu",), embedding_dim=16)
-        model = build_model(spec, schema)
-        variables = model.init(jax.random.PRNGKey(0), x)
-        fused = model.apply(variables, x)
-        monkeypatch.setattr(
-            emb_mod, "fused_lookup", lambda embeds, ids: [e(ids) for e in embeds])
-        separate = model.apply(variables, x)
-        monkeypatch.undo()
-        np.testing.assert_array_equal(np.asarray(fused),
-                                      np.asarray(separate))
+    kw = dict(hidden_nodes=(8,), activations=("relu",), embedding_dim=16,
+              token_dim=8, num_attention_heads=2, num_layers=1,
+              param_dtype="float32", compute_dtype="bfloat16")
+    kw.update(spec_kw)
+    model = build_model(ModelSpec(model_type=model_type, **kw), schema)
+    return model, model.init(jax.random.PRNGKey(0), x), x
+
+
+@pytest.mark.parametrize("one_hot", [False, True],
+                         ids=["gather", "one_hot_forced"])
+@pytest.mark.parametrize("model_type",
+                         ["deepfm", "wide_deep", "ft_transformer"])
+def test_lookup_from_param_matches_cast_then_gather(model_type, one_hot,
+                                                    monkeypatch):
+    """The lookup gathers float32 rows and casts the rows it got (or, where
+    the one-hot strategy serves - forced here, a TPU with a small
+    vocabulary in life - multiplies by the cast tables); the logits are
+    bit-identical to the formula it replaced, kept frozen here: cast the
+    whole table to the compute dtype, then gather (for the paired tables of
+    DeepFM / Wide&Deep: cast both, concatenate along dim, gather once,
+    split)."""
+    from shifu_tpu.ops import pallas_embedding as pe
+
+    model, variables, x = _table_model(model_type)
+    if one_hot:
+        monkeypatch.setattr(pe, "_onehot_ok", lambda v, n: True)
+    got = model.apply(variables, x)
+
+    seen = []
+
+    def cast_then_gather(tables, ids, dtype):
+        tables = list(tables)
+        seen.append([t.shape for t in tables])
+        assert all(t.dtype == jnp.float32 for t in tables)  # the parameters
+        field = jnp.arange(ids.shape[1])[None, :]
+        fused = jnp.concatenate([t.astype(dtype) for t in tables],
+                                axis=-1)[field, ids]
+        ends = np.cumsum([t.shape[-1] for t in tables])[:-1]
+        return jnp.split(fused, ends, axis=-1)
+
+    monkeypatch.setattr(pe, "lookup_rows", cast_then_gather)
+    frozen = model.apply(variables, x)
+    assert len(seen) == 1 and len(seen[0]) == (
+        1 if model_type == "ft_transformer" else 2)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(frozen))
+
+
+def _walk(jaxpr, scope=""):
+    """(equation, its name stack, nested one level deeper per enclosing
+    loop or call) for every equation of `jaxpr` and of the jaxprs inside
+    its equations' parameters."""
+    for eqn in jaxpr.eqns:
+        stack = scope + "/" + str(eqn.source_info.name_stack)
+        yield eqn, stack
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _walk(sub, stack)
+
+
+def test_train_step_makes_nothing_table_sized_but_the_gradient(monkeypatch):
+    """One train step of a DeepFM whose vocabulary is over the one-hot cap,
+    traced as the TPU runs it: under `fwd_bwd`, the only equations whose
+    result is as large as a table are, for each of the two tables, the loop
+    over its fields that stacks their gradients - and inside that loop the
+    only results as large as one field's table are its zero fill and the
+    scatter-add into it.  No cast, concatenation, slice, reshape or copy of
+    a table: the forward gathers rows from the parameter itself."""
+    from shifu_tpu.ops import pallas_embedding as pe
+    from shifu_tpu.train.loop import init_state
+    from shifu_tpu.train.step import (_fwd_bwd_and_update,
+                                      make_apply_gradients, make_loss_fn)
+
+    monkeypatch.setattr(pe, "on_tpu", lambda: True)
+    nc, vocab, dim, batch = 4, 4096, 10, 64
+    assert vocab > pe._ONEHOT_MAX_VOCAB
+    schema = synthetic.make_schema(num_features=12, num_categorical=nc,
+                                   vocab_size=vocab)
+    job = JobConfig(
+        schema=schema, data=DataConfig(batch_size=batch),
+        model=ModelSpec(model_type="deepfm", hidden_nodes=(16,),
+                        activations=("relu",), embedding_dim=dim,
+                        param_dtype="float32", compute_dtype="bfloat16"),
+        train=TrainConfig(epochs=1, optimizer=OptimizerConfig(
+            name="adadelta", learning_rate=1.0))).validate()
+    state = init_state(job, 12)
+    xs = {"features": jnp.zeros((batch, 12), jnp.float32),
+          "target": jnp.zeros((batch, 1), jnp.float32),
+          "weight": jnp.ones((batch, 1), jnp.float32)}
+    loss_fn, apply_grads = make_loss_fn(job), make_apply_gradients(job)
+    jaxpr = jax.make_jaxpr(
+        lambda st, x: _fwd_bwd_and_update(loss_fn, apply_grads, st, x)[:2])(
+        state, xs)
+
+    def size(eqn):
+        return max((int(np.prod(v.aval.shape)) for v in eqn.outvars
+                    if hasattr(v.aval, "shape")), default=0)
+
+    small_table, field_table = nc * vocab * 1, vocab * 1
+    fwd_bwd = [(e, s) for e, s in _walk(jaxpr.jaxpr) if "fwd_bwd" in s]
+    assert any(e.primitive.name == "gather" for e, _ in fwd_bwd)
+    loops = [e for e, _ in fwd_bwd if e.primitive.name == "scan"]
+    in_loops = {id(e) for loop in loops
+                for e, _ in _walk(loop.params["jaxpr"].jaxpr)}
+    table_sized = [e for e, _ in fwd_bwd
+                   if size(e) >= small_table and id(e) not in in_loops]
+    assert sorted(e.primitive.name for e in table_sized) == ["scan", "scan"]
+    assert sorted(tuple(e.outvars[0].aval.shape) for e in table_sized) == [
+        (nc, vocab, 1), (nc, vocab, dim)]
+    assert all(e.outvars[0].aval.dtype == jnp.float32 for e in table_sized)
+    for loop in table_sized:
+        inner = [e.primitive.name
+                 for e, _ in _walk(loop.params["jaxpr"].jaxpr)
+                 if size(e) >= field_table]
+        assert sorted(inner) == ["broadcast_in_dim", "scatter-add"], inner
+    # and the optimizer does read table-sized gradients: the walk sees them
+    assert any(size(e) >= small_table for e, s in _walk(jaxpr.jaxpr)
+               if "optimizer" in s)

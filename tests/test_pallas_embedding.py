@@ -143,66 +143,159 @@ def test_onehot_chunked_matches_unchunked(monkeypatch):
     np.testing.assert_allclose(chunked, ref, rtol=1e-6, atol=1e-6)
 
 
-def test_segment_grad_matches_scatter_grad():
-    """The TPU gather-path gradient (per-table segment reductions) equals
-    the scatter-add reference for every id class: in-range, duplicate,
-    negative-wrapping [-V, 0), and dropped outside [-V, V)."""
+@pytest.mark.parametrize("table_shape", [(4, 37, 8), (20, 37, 8), (3, 41, 1)])
+def test_segment_grad_matches_scatter_grad(table_shape):
+    """The TPU gather-path gradient (a segment reduction a field, the
+    fields walked by one loop whatever their count) equals the scatter-add
+    reference for every id class: in-range, duplicate, negative-wrapping
+    [-V, 0), and dropped outside [-V, V) - an id >= V must DROP, not land
+    in the next field's table, and an id < -V must drop, not shift into
+    the previous field's."""
     from shifu_tpu.ops import pallas_embedding as pe
 
+    nc, v, d = table_shape
     rng = np.random.default_rng(11)
-    table_shape = (4, 37, 8)
     # dense duplicates plus every boundary class
-    ids = rng.integers(-80, 90, (257, 4)).astype(np.int32)
-    ids[0] = [0, 36, -1, -37]       # wrap boundaries
-    ids[1] = [-38, 37, 89, -80]     # all dropped
-    ids[2] = ids[3] = [5, 5, 5, 5]  # duplicates
-    g = rng.standard_normal((257, 4, 8)).astype(np.float32)
-    got = np.asarray(pe._segment_grad(jnp.asarray(ids), table_shape,
-                                      jnp.asarray(g)))
+    ids = rng.integers(-2 * v - 6, 2 * v + 16, (257, nc)).astype(np.int32)
+    ids[0, :3] = [0, v - 1, -1]             # wrap boundaries
+    ids[1, :3] = [-v, v, v + 3]             # last wrap, then dropped
+    ids[2, :3] = [-v - 1, 2 * v + 15, -2 * v - 6]   # all dropped
+    ids[3] = ids[4] = 5                     # duplicates
+    g = rng.standard_normal((257, nc, d)).astype(np.float32)
+    got = pe._segment_grad(jnp.asarray(ids), table_shape, jnp.asarray(g))
+    assert got.shape == table_shape and got.dtype == jnp.float32
     want = np.asarray(pe._scatter_grad(jnp.asarray(ids), table_shape,
                                        jnp.asarray(g)))
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6, atol=1e-6)
 
 
-def test_segment_flat_routing_guards_int32_overflow(monkeypatch):
-    """The flattened form's id space is field*V + id in int32: past 2^31
-    combined segments `field * v` would silently alias gradients into
-    other tables, so routing must fall back to the per-table unroll."""
+def test_segment_grad_is_one_loop_at_any_width():
+    """The program does not grow with the schema's width: the walk over
+    the fields is one `scan` around one scatter-add, and nothing in it is
+    as large as the stacked table but the scan's own result."""
     from shifu_tpu.ops import pallas_embedding as pe
 
-    monkeypatch.setenv("SHIFU_TPU_SEGMENT_FLAT_MIN_FIELDS", "16")
-    assert pe._segment_use_flat(50, 1000) is True
-    assert pe._segment_use_flat(4, 1000) is False       # narrow: unroll
-    assert pe._segment_use_flat(50, 45_000_000) is False  # nc*v > int32
-    assert pe._segment_use_flat(16, (2**31 - 2) // 16) is True  # boundary
-    assert pe._segment_use_flat(16, 2**31 // 16) is False
+    def eqns(nc):
+        jaxpr = jax.make_jaxpr(
+            lambda i, g: pe._segment_grad(i, (nc, 64, 4), g))(
+            jnp.zeros((8, nc), jnp.int32), jnp.zeros((8, nc, 4)))
+        return [e.primitive.name for e in jaxpr.jaxpr.eqns]
+
+    assert eqns(3) == eqns(50)
+    assert eqns(50).count("scan") == 1
+    assert "scatter-add" not in eqns(50)     # it is inside the loop
 
 
-def test_segment_grad_flattened_matches_scatter_grad(monkeypatch):
-    """Wide schemas take the FLATTENED single-segment_sum form (one op at
-    any field count instead of an NC-long unroll): same gradient as the
-    scatter reference, including the id classes where flattening could go
-    wrong — an id >= V must DROP, not alias into the next field's table,
-    and an id < -V must drop, not shift into the previous field's."""
+def _force_branch(monkeypatch, branch):
+    """Steer `_bwd` without a chip: 'scatter' is the CPU's own branch,
+    'segment' the TPU's for a vocabulary over the one-hot cap (`on_tpu`
+    forced; the gathers and reductions themselves run anywhere)."""
     from shifu_tpu.ops import pallas_embedding as pe
 
-    rng = np.random.default_rng(13)
-    nc, v, d = 20, 37, 8  # nc >= the flat-form threshold
-    table_shape = (nc, v, d)
-    ids = rng.integers(-80, 90, (129, nc)).astype(np.int32)
-    ids[0, :4] = [0, v - 1, -1, -v]         # wrap boundaries
-    ids[1, :4] = [v, v + 3, -v - 1, 89]     # alias candidates: all dropped
-    ids[2] = ids[3] = 5                     # duplicates
-    g = rng.standard_normal((129, nc, d)).astype(np.float32)
-    monkeypatch.setenv("SHIFU_TPU_SEGMENT_FLAT_MIN_FIELDS", "16")
-    got = np.asarray(pe._segment_grad(jnp.asarray(ids), table_shape,
-                                      jnp.asarray(g)))
-    want = np.asarray(pe._scatter_grad(jnp.asarray(ids), table_shape,
-                                       jnp.asarray(g)))
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
-    # forcing the per-table form on the same inputs agrees too (the A/B
-    # switch the threshold env exists for)
-    monkeypatch.setenv("SHIFU_TPU_SEGMENT_FLAT_MIN_FIELDS", "1000")
-    per_table = np.asarray(pe._segment_grad(jnp.asarray(ids), table_shape,
-                                            jnp.asarray(g)))
-    np.testing.assert_allclose(per_table, want, rtol=1e-6, atol=1e-6)
+    if branch == "segment":
+        monkeypatch.setattr(pe, "on_tpu", lambda: True)
+        monkeypatch.setenv("SHIFU_TPU_ONEHOT_EMBED_MAX_VOCAB", "0")
+
+
+_BRANCHES = ["scatter", "segment"]
+_DIMS = [5, 1]      # a table of rows, and one of scalars (its own gather)
+
+
+@pytest.mark.parametrize("dim", _DIMS)
+@pytest.mark.parametrize("branch", _BRANCHES)
+def test_lookup_rows_matches_cast_then_gather(branch, dim, monkeypatch):
+    """`lookup_rows` gathers from the float32 table and casts the rows:
+    bit-identical to a lookup in the cast table, dirty ids included."""
+    from shifu_tpu.ops import pallas_embedding as pe
+
+    _force_branch(monkeypatch, branch)
+    rng = np.random.default_rng(21)
+    table = jnp.asarray(rng.standard_normal((3, 40, dim)), jnp.float32)
+    ids = jnp.asarray(rng.integers(-50, 60, (33, 3)), jnp.int32)
+    got, = pe.lookup_rows([table], ids, jnp.bfloat16)
+    # the row gather, spelled out: the formula `lookup_rows` replaced
+    want = jnp.take_along_axis(table.astype(jnp.bfloat16)[None],
+                               ids[:, :, None, None], axis=2)[:, :, 0, :]
+    assert got.dtype == jnp.bfloat16
+    g32, w32 = (np.asarray(a.astype(jnp.float32)) for a in (got, want))
+    np.testing.assert_array_equal(np.isnan(g32), np.isnan(w32))
+    np.testing.assert_array_equal(np.nan_to_num(g32), np.nan_to_num(w32))
+
+
+@pytest.mark.parametrize("dim", _DIMS)
+@pytest.mark.parametrize("branch", _BRANCHES)
+def test_lookup_rows_grad_is_float32_at_the_rows(branch, dim, monkeypatch):
+    """The gradient with respect to the float32 table is the float32
+    scatter-add of the rows' cotangents (`_scatter_grad`), comes back
+    float32 and has not been through the compute dtype: two cotangents a
+    bfloat16 holds each, 1 and 2**-9, land on one row, and their sum,
+    which bfloat16 cannot hold, is there to the bit.  Duplicates sum, a
+    negative id wraps once, an id outside [-V, V) drops."""
+    from shifu_tpu.ops import pallas_embedding as pe
+
+    _force_branch(monkeypatch, branch)
+    nc, v, d = 3, 40, dim
+    rng = np.random.default_rng(22)
+    table = jnp.asarray(rng.standard_normal((nc, v, d)), jnp.float32)
+    ids = rng.integers(8, v, (64, nc)).astype(np.int32)   # row 7 kept free
+    ids[0] = ids[1] = [7, 7, 7]          # duplicates: the unrepresentable sum
+    ids[2] = [-1, -v, 39]                # wraps to 39, 0, and 39 itself
+    ids[3] = [v, -v - 1, 1000]           # all dropped
+    w = rng.standard_normal((64, nc, d)).astype(np.float32)
+    w = np.array(jnp.asarray(w).astype(jnp.bfloat16).astype(jnp.float32))
+    w[0], w[1] = 1.0, 2.0 ** -9
+    ids, w = jnp.asarray(ids), jnp.asarray(w)
+
+    def loss(t):
+        out, = pe.lookup_rows([t], ids, jnp.bfloat16)
+        # the forward NaN-fills rows of dropped ids: keep them out of the sum
+        return jnp.sum(jnp.where(jnp.isnan(out), 0, out).astype(jnp.float32)
+                       * w)
+
+    g = jax.grad(loss)(table)
+    assert g.dtype == jnp.float32 and g.shape == table.shape
+    np.testing.assert_array_equal(
+        np.asarray(g[:, 7]), np.full((nc, d), 1.0 + 2.0 ** -9, np.float32))
+    want = pe._scatter_grad(ids, table.shape, w)
+    np.testing.assert_allclose(np.asarray(g), np.asarray(want),
+                               rtol=1e-6, atol=1e-6)
+    # the dropped row of ids contributed nowhere, the wrapped one did
+    clean = ids.at[3].set(0)
+    w_clean = w.at[3].set(0.0)
+    np.testing.assert_allclose(
+        np.asarray(g), np.asarray(pe._scatter_grad(clean, table.shape,
+                                                   w_clean)),
+        rtol=1e-6, atol=1e-6)
+
+
+def test_lookup_rows_joins_the_tables_only_for_the_one_hot_strategy(monkeypatch):
+    """Several tables over the same ids: where the one-hot strategy serves
+    (forced here; a TPU with a small vocabulary), one product a field over
+    the tables cast and joined along dim; everywhere else a gather from
+    each parameter.  The rows are the same to the bit either way."""
+    from shifu_tpu.ops import pallas_embedding as pe
+
+    rng = np.random.default_rng(23)
+    tables = [jnp.asarray(rng.standard_normal((3, 40, d)), jnp.float32)
+              for d in (6, 1)]
+    ids = jnp.asarray(rng.integers(-50, 60, (33, 3)), jnp.int32)
+
+    def run():
+        seen = []
+        real = pe.embedding_lookup
+        monkeypatch.setattr(pe, "embedding_lookup",
+                            lambda t, i: seen.append((t.shape, t.dtype))
+                            or real(t, i))
+        outs = pe.lookup_rows(tables, ids, jnp.bfloat16)
+        monkeypatch.setattr(pe, "embedding_lookup", real)
+        return seen, [np.asarray(o.astype(jnp.float32)) for o in outs]
+
+    seen, apart = run()
+    assert seen == [((3, 40, 6), jnp.float32), ((3, 40, 1), jnp.float32)]
+    monkeypatch.setattr(pe, "_onehot_ok", lambda v, n: True)
+    seen, joined = run()
+    assert seen == [((3, 40, 7), jnp.bfloat16)]
+    for a, j, t in zip(apart, joined, tables):
+        assert a.shape == j.shape == (33, 3, t.shape[-1])
+        np.testing.assert_array_equal(np.isnan(a), np.isnan(j))
+        np.testing.assert_array_equal(np.nan_to_num(a), np.nan_to_num(j))

@@ -1,0 +1,137 @@
+"""Programs of the main path compiled for the chip, without one.
+
+libtpu compiles ahead of time for a TPU that is described and not attached
+(`jax.experimental.topologies`), so what the chip's compiler would refuse -
+a program that does not fit the device's memory, a layout it cannot make -
+is refused here, at no chip time.  Nothing runs: this says nothing about
+results or speed.
+
+Only one process at a time may load libtpu, so the topology is described
+inside a fixture (never while a module is imported), every test that needs
+it lives in this one file, and where it cannot be described the tests skip.
+"""
+
+import os
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    for key, value in (("TPU_LOG_DIR", "disabled"),
+                       ("TPU_ACCELERATOR_TYPE", "v5litepod-4"),
+                       ("TPU_WORKER_HOSTNAMES", "localhost"),
+                       ("TPU_SKIP_MDS_QUERY", "1")):
+        os.environ.setdefault(key, value)
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+_SHAPE = re.compile(r"(?:f32|bf16|s32|u32|pred)\[([\d,]*)\](?:\{[^}]*\})?")
+
+
+def _table_sized_in_loops(hlo: str, elements: int):
+    """(name, op) of the instructions whose result holds `elements` or more,
+    in every computation but the entry and the fusions' own bodies."""
+    fused = set(re.findall(r"kind=k\w+, calls=%([\w.\-]+)", hlo))
+    out, keep = [], False
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            keep = not head.group(1) and head.group(2) not in fused
+            continue
+        if not keep or " = " not in line:
+            continue
+        lhs, _, rhs = line.strip().partition(" = ")
+        shape = _SHAPE.match(rhs)
+        if not shape:
+            continue
+        n = 1
+        for d in shape.group(1).split(","):
+            n *= int(d or 1)
+        op = rhs[shape.end():].strip().split("(")[0]
+        if n >= elements and op not in ("parameter", "get-tuple-element",
+                                        "bitcast"):
+            out.append((lhs.split()[-1].lstrip("%"), op))
+    return out
+
+
+def test_criteo_size_deepfm_epoch_fits_the_chip(one_chip, no_compile_cache,
+                                                monkeypatch):
+    """The epoch program of the benchmark's `deepfm_criteo.train_resident`
+    cell (13 numeric + 26 categorical fields of 1,300,000 buckets, latent
+    dim 10, 400x400x400, batch 8,192, 128 steps resident, Adadelta over
+    float32 tables: 4.5 GB of state) compiles for one v5e chip - it fits its
+    16 GB beside the copy of the state in the loop's own layout, which
+    leaves a few hundred MB - and its loop makes nothing table-sized by
+    casting, slicing, padding, reshaping or re-tiling a table."""
+    from shifu_tpu.config import (DataConfig, JobConfig, ModelSpec,
+                                  OptimizerConfig, TrainConfig)
+    from shifu_tpu.data import synthetic
+    from shifu_tpu.ops import pallas_common
+    from shifu_tpu.train.loop import init_state
+    from shifu_tpu.train.step import make_device_epoch_step
+
+    n_num, n_cat, vocab, batch, steps = 13, 26, 1_300_000, 8192, 128
+    schema = synthetic.make_schema(num_features=n_num + n_cat,
+                                   num_categorical=n_cat, vocab_size=vocab)
+    job = JobConfig(
+        schema=schema, data=DataConfig(batch_size=batch),
+        model=ModelSpec(model_type="deepfm", hidden_nodes=(400, 400, 400),
+                        activations=("relu",) * 3, embedding_dim=10,
+                        param_dtype="float32", compute_dtype="bfloat16"),
+        train=TrainConfig(epochs=1, loss="weighted_mse",
+                          optimizer=OptimizerConfig(name="adadelta",
+                                                    learning_rate=1.0)),
+    ).validate()
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        on_chip, jax.eval_shape(lambda: init_state(job, n_num + n_cat)))
+    blocks = {k: on_chip(jax.ShapeDtypeStruct((steps, batch, w), jnp.float32))
+              for k, w in (("features", n_num + n_cat), ("target", 1),
+                           ("weight", 1))}
+    order = on_chip(jax.ShapeDtypeStruct((steps,), jnp.int32))
+    # the code asks `jax.default_backend()`, which is the CPU here: steer it
+    # onto its TPU branches while the program is traced
+    monkeypatch.setattr(pallas_common.jax, "default_backend", lambda: "tpu")
+    step = make_device_epoch_step(job, None)
+    lowered = step._fn.trace(state, blocks, order).lower(
+        lowering_platforms=("tpu",))
+    monkeypatch.undo()
+    hlo = lowered.compile().as_text()       # raises what the chip would
+
+    made = _table_sized_in_loops(hlo, n_cat * vocab)
+    assert made, "the reader found no table-sized instruction at all"
+    bad = [(name, op) for name, op in made
+           if op in ("convert", "slice", "reshape", "pad", "concatenate",
+                     "transpose", "dynamic-slice")
+           or name.startswith(("slice", "pad", "convert", "dynamic-slice"))]
+    assert not bad, bad
