@@ -10,7 +10,7 @@ as a child process, one at a time, so the chip always belongs to exactly one
 process and each phase proves the one before it released the chip:
 
   device    assert a TPU backend; print platform, device_kind, count, versions,
-            which parser serves ingest, what the two peak tables return
+            which parser serves ingest
   train     `launcher.cli train` on seeded Shifu-format data, flagship width
             (mlp 3x100 relu, 30 features, bf16, weighted MSE, Adadelta, global
             batch 65,536, 3 epochs of >= 4 steps): once with defaults
@@ -30,9 +30,8 @@ process and each phase proves the one before it released the chip:
             data=2 x model=2 DeepFM through the CLI
 
 Adadelta runs at its canonical lr 1.0, not the parity job's 0.003: at 0.003
-twelve optimizer steps cannot move AUC off chance (bench.py's e2e tier makes
-the same choice for the same reason), and the learning rate does not change
-the work.
+twelve optimizer steps cannot move AUC off chance, and the learning rate does
+not change the work.
 
 The last line of stdout is the one JSON result, printed only when every
 assertion held.  Any failure raises: there is no degraded pass.
@@ -195,8 +194,8 @@ def write_flagship_inputs(work: str, size: dict) -> dict:
 
 
 def write_deepfm_inputs(work: str, size: dict) -> dict:
-    """The DeepFM of bench.py's high-cardinality ladder rung (24 numeric +
-    6 categorical features, hidden 100x100, embedding 16), with a vocab
+    """A high-cardinality DeepFM (24 numeric + 6 categorical features,
+    hidden 100x100, embedding 16), with a vocab
     large and a batch small enough that the tables and their optimizer
     slots are most of what a device holds: whether they are split across
     the model axis then shows in each device's bytes_in_use."""
@@ -552,7 +551,6 @@ def child_device(args) -> int:
     import jax
 
     from shifu_tpu.data import native_parser
-    from shifu_tpu.obs import devprof, goodput
 
     d = jax.devices()[0]
     want = "cpu" if args.rehearsal else "tpu"
@@ -567,15 +565,6 @@ def child_device(args) -> int:
                       for pkg in ("jax", "jaxlib", "libtpu", "flax")})
     fact("ingest_parser", "native" if native_parser.available()
          else f"numpy ({native_parser.unavailable_reason()})")
-    # an unmatched kind must give None from both tables, never a default
-    fact("peak_lookup", {"device_kind": d.device_kind,
-                         "goodput.peak_tflops": goodput.peak_tflops(
-                             d.device_kind),
-                         "devprof.peak_hbm_gbps": devprof.peak_hbm_gbps(
-                             d.device_kind)})
-    check(goodput.peak_tflops("no such part") is None
-          and devprof.peak_hbm_gbps("no such part") is None,
-          "a peak table returned a default for an unknown device kind")
     with open(os.path.join(args.work, "device.json"), "w") as f:
         json.dump(info, f)
     return 0
@@ -821,7 +810,7 @@ def child_kernels(args) -> int:
         *(((1, 8, 8192, 64), 1, 2) if tpu else ((1, 2, 256, 16), 1, 2)),
         2e-2, "f32 operands take bf16 passes through the MXU")
 
-    # fused FT block: the bench ladder rung, 30 features + CLS
+    # fused FT block: 30 features + CLS
     b_ft, s_ft, d_ft, heads, ratio = (8192 if tpu else 16), 31, 64, 8, 4
     spec = ModelSpec(model_type="ft_transformer", token_dim=d_ft,
                      num_layers=3, num_attention_heads=heads,
@@ -1037,7 +1026,7 @@ def child_kernels(args) -> int:
                           optimizer=adadelta)).validate(), None)
 
     # --- cost_analysis() of the flagship train step against the analytic
-    # count bench.py uses (fwd 2*m*k*n per dense layer; bwd twice that)
+    # count (fwd 2*m*k*n per dense layer; bwd twice that)
     job = JobConfig(schema=plain, data=DataConfig(batch_size=mlp_bs),
                     model=flagship,
                     train=TrainConfig(epochs=1, loss="weighted_mse",

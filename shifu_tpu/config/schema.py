@@ -566,18 +566,10 @@ class TrainConfig:
     # that dense optimizer traffic dominates; "on": require it (raise with
     # the specific blocker otherwise); "off": always dense.
     sparse_embedding_update: str = "auto"
-    # minimum acceptable train_scaling_efficiency for the pod data-plane
-    # scaling sweep (bench.py / tools/perf_gate.py 13th axis): achieved
-    # speedup over n_hosts divided by ideal.  0 disables the gate; the
-    # perf gate's own floor (0.6) still applies to recorded benchmarks.
-    scaling_gate: float = 0.6
 
     def validate(self) -> None:
         if self.epochs <= 0:
             raise ConfigError("epochs must be positive")
-        if not (0.0 <= self.scaling_gate <= 1.0):
-            raise ConfigError(
-                f"scaling_gate must be in [0, 1]: {self.scaling_gate}")
         if self.sparse_embedding_update not in ("auto", "on", "off"):
             raise ConfigError(
                 f"sparse_embedding_update must be auto/on/off: "
@@ -698,17 +690,17 @@ class EmbedConfig:
     # frequency-tiered table placement: "off" (default — the whole table
     # is device-resident) or "host" (cold tail served from a host
     # memmap; see embed/tiering.py).  Training-step residency swap is
-    # future work (ROADMAP); "host" today serves bench/feeder lookups.
+    # future work (ROADMAP); "host" today serves feeder lookups.
     tiering: str = "off"
     # cold-tier storage dtype: "float32" (exact) or "int8" (4x smaller,
-    # rides the cache-v2 wire quantization grid — lossy, bench-only).
+    # rides the cache-v2 wire quantization grid — lossy).
     tier_dtype: str = "float32"
     # hot-tier size: explicit row count, or 0 to derive from
     # hot_fraction of the vocab.
     hot_rows: int = 0
     hot_fraction: float = 0.05
     # where the cold-tier memmap + manifest land ("" = beside the job's
-    # cache dir; bench passes a tempdir).
+    # cache dir).
     cold_dir: str = ""
     # overlap next-batch cold-row fetches with the device step
     # (feeder-style background thread).

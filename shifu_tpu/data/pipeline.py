@@ -249,7 +249,7 @@ def _emit_ingest_report(stats: list, pool_width: int, wall_s: float,
     """One `ingest_report` journal event per completed ingest: the pool
     shape, the per-phase cost split, which cache tier served each file,
     and a (capped) per-file table — the observable record of the cold/warm
-    ingest gap docs/PERF.md "Data plane" reasons about.  Never raises."""
+    ingest gap docs/DATA.md "Columnar cache" reasons about.  Never raises."""
     try:
         files = sorted(stats, key=lambda r: r["file"])
         tiers: dict[str, int] = {}
@@ -569,7 +569,7 @@ def resident_feature_format(schema: DataSchema, data: DataConfig,
 def wire_quantize(x: np.ndarray, scale: np.ndarray,
                   offset: np.ndarray) -> np.ndarray:
     """The ONE int8 wire encoder (grid contract single-sourced: callers at
-    parse time, per-block cast time, and the bench all share it; the
+    parse time and per-block cast time share it; the
     device-side inverse is train/step.make_wire_decode):
     round((x - offset) / scale), saturated to [-127, 127], int8."""
     xf = np.asarray(x, np.float32)
@@ -1224,7 +1224,7 @@ class FeederError(RuntimeError):
 
 class EpochFeeder:
     """Persistent cross-epoch input feeder — the overlap engine's producer
-    side (docs/PERF.md "Overlap engine").
+    side (docs/DATA.md "Overlap engine").
 
     Replaces the per-epoch producer thread prefetch_to_device spins up:
     ONE pair of host threads lives for the whole job and runs ahead across

@@ -123,9 +123,9 @@ def build_parser() -> argparse.ArgumentParser:
     pf = sub.add_parser(
         "profile", help="render a job's goodput ledger: per-epoch wall-time "
                         "buckets (compile/input/step/checkpoint/restore/"
-                        "eval/other), MFU, top compiled functions by XLA "
-                        "cost, and the recovery tax (docs/PERF.md "
-                        "'Goodput & MFU')")
+                        "eval/other), top compiled functions by XLA "
+                        "cost, and the recovery tax "
+                        "(docs/OBSERVABILITY.md 'Goodput ledger')")
     pf.add_argument("job_dir",
                     help="job dir, telemetry dir, or journal.jsonl path "
                          "(local or gs:// hdfs:// URI)")
@@ -170,7 +170,7 @@ def build_parser() -> argparse.ArgumentParser:
         "cache", help="inspect the columnar data cache: list entries "
                       "(tier/version/bytes/source) and prune superseded, "
                       "orphaned, or legacy-format ones (data/cache.py, "
-                      "docs/PERF.md 'Data plane')")
+                      "docs/DATA.md 'Columnar cache')")
     ch.add_argument("cache_dir",
                     help="cache directory (DataConfig.cache_dir / "
                          "SHIFU_TPU_DATA_CACHE)")
@@ -356,8 +356,8 @@ def build_parser() -> argparse.ArgumentParser:
                             "ingest, per-epoch order/shard digests "
                             "journaled per rank, no device training — "
                             "the gang child the elastic recovery drill "
-                            "and the bench scaling sweep dispatch under "
-                            "`supervise_pod` (docs/DATA.md)")
+                            "dispatches under `supervise_pod` "
+                            "(docs/DATA.md)")
     dd.add_argument("--data", required=True,
                     help="directory (or file) of delimited part files; "
                          "layout [target, f0..fN-1]")
@@ -421,7 +421,7 @@ def build_parser() -> argparse.ArgumentParser:
     lt = sub.add_parser(
         "loadtest", help="open-loop (Poisson-arrival) load harness for "
                          "the scoring plane: reports scores/s and "
-                         "p50/p99 latency (tools/loadtest.py, "
+                         "p50/p99 latency (runtime/loadtest.py, "
                          "docs/SERVING.md)")
     lt.add_argument("--model", default=None,
                     help="artifact dir — in-process mode: spin up a "
@@ -1931,7 +1931,7 @@ def run_fleet(args) -> int:
 
 def run_loadtest(args) -> int:
     """`shifu-tpu loadtest`: the open-loop Poisson harness
-    (runtime/loadtest.py; standalone spelling in tools/loadtest.py)."""
+    (runtime/loadtest.py)."""
     from .. import obs
     from ..config.schema import ServingConfig
     from ..runtime import loadtest as lt

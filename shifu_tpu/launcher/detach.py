@@ -253,12 +253,11 @@ def _telemetry_quick_summary(jpath: str) -> Optional[dict]:
                         "observed_p99_ms": rec.get("observed_p99_ms")}
         if goodput is None and rec.get("kind") == "goodput":
             # latest goodput ledger record within the tail window: the
-            # at-a-glance "is the job actually stepping" numbers
-            # (docs/PERF.md "Goodput & MFU"); a run that never emitted
-            # one (pre-ledger journal) just omits the key
+            # at-a-glance "is the job actually stepping" number
+            # (docs/OBSERVABILITY.md "Goodput ledger"); a run that never
+            # emitted one (pre-ledger journal) just omits the key
             goodput = {"epoch": rec.get("epoch"),
-                       "goodput_fraction": rec.get("goodput_fraction"),
-                       "mfu": rec.get("mfu")}
+                       "goodput_fraction": rec.get("goodput_fraction")}
         if hbm is None and rec.get("kind") == "hbm_watermark":
             # latest HBM watermark (obs/devprof.py): the at-a-glance
             # "how close to the memory cliff" number next to goodput

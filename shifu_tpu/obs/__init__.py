@@ -23,8 +23,8 @@ and `shifu-tpu profile`.  On top of the pillars, ISSUE 3 adds
 `obs/introspect.py` (per-compiled-program XLA cost/memory capture,
 `xla_compile` events) and `obs/goodput.py` (the per-epoch goodput
 ledger: wall time classified into compile / input / step / checkpoint /
-restore / eval / other buckets, with MFU against a per-platform peak
-table) — docs/PERF.md "Goodput & MFU".  ISSUE 6 opens the `step` bucket
+restore / eval / other buckets) — docs/OBSERVABILITY.md "Goodput
+ledger".  ISSUE 6 opens the `step` bucket
 itself: `obs/devprof.py` + `obs/tracefmt.py` (the device flight
 recorder — per-kernel device-time rollups from scheduled jax.profiler
 windows, roofline attribution, HBM watermarks, and an anomaly-triggered

@@ -8,8 +8,8 @@ and — when a chunk suddenly runs slow — a trace of the very next chunk so
 the anomaly is attributable after the fact.  Four legs:
 
 - **Windowed trace capture** — `DeviceProfiler.epoch_capture(epoch)`
-  wraps the train loop's `jax.profiler` seam (train/profiler.trace) on
-  the `obs.trace_epochs` schedule (default off; "first" = the first
+  is the train loop's `jax.profiler` seam, on the
+  `obs.trace_epochs` schedule (default off; "first" = the first
   trained epoch only); the emitted Chrome-trace files parse into a
   per-kernel rollup (obs/tracefmt.py) journaled as a `device_profile`
   event.  The capture is chaos-probed (site `obs.trace`): a failing or
@@ -17,7 +17,7 @@ the anomaly is attributable after the fact.  Four legs:
   epoch trains on untraced.
 - **Roofline attribution** — the rollup joins obs/introspect.py's
   cost-analysis FLOPs/bytes (matched per hlo_module) against the
-  platform peaks (`goodput.PEAK_BF16_TFLOPS`, `PEAK_HBM_GBPS` below):
+  platform peaks (`PEAKS` below):
   each matched kernel carries its program's achieved-vs-peak FLOP/s and
   HBM-bandwidth fractions and a `bound` verdict (compute vs hbm).
 - **HBM watermarks** — `hbm_snapshot()` polls
@@ -51,19 +51,17 @@ from typing import Callable, Iterator, Optional
 
 from . import tracefmt
 
-# peak HBM GB/s per chip by device-kind substring (public specs) — the
-# roofline's bandwidth axis, next to goodput.PEAK_BF16_TFLOPS (same
-# first-match-wins convention: "v5p" before "v5").
-PEAK_HBM_GBPS: tuple[tuple[str, float], ...] = (
-    ("v6", 1640.0),      # Trillium / v6e
-    ("v5p", 2765.0),
-    ("v5", 819.0),       # v5e
-    ("v4", 1228.0),
-    ("v3", 900.0),
-    ("v2", 700.0),
+# (device-kind substring, peak dense bf16 TFLOP/s, peak HBM GB/s) per
+# chip (public specs) — the roofline's two axes.  First match wins, so
+# "v5p" must precede "v5".
+PEAKS: tuple[tuple[str, float, float], ...] = (
+    ("v6", 918.0, 1640.0),      # Trillium / v6e
+    ("v5p", 459.0, 2765.0),
+    ("v5", 197.0, 819.0),       # v5e / "TPU v5 lite"
+    ("v4", 275.0, 1228.0),
+    ("v3", 123.0, 900.0),
+    ("v2", 45.0, 700.0),
 )
-
-ENV_PEAK_HBM_GBPS = "SHIFU_TPU_PEAK_HBM_GBPS"
 
 # hlo_module -> instrumented-fn aliases the suffix match can't reach (the
 # module name comes from the INNER function jit wrapped, the stats key
@@ -79,27 +77,22 @@ _MODULE_ALIASES = {
 CHAOS_SITE = "obs.trace"
 
 
-def peak_hbm_gbps(device_kind: Optional[str] = None) -> Optional[float]:
-    """Peak HBM GB/s for a device kind (current backend's device 0 when
-    omitted); SHIFU_TPU_PEAK_HBM_GBPS overrides; None when unknown (CPU,
+def peaks(device_kind: Optional[str] = None
+          ) -> tuple[Optional[float], Optional[float]]:
+    """(peak bf16 TFLOP/s, peak HBM GB/s) for a device kind (current
+    backend's device 0 when omitted); (None, None) when unknown (CPU,
     new parts) — roofline fractions are then null, never guessed."""
-    env = os.environ.get(ENV_PEAK_HBM_GBPS)
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
     if device_kind is None:
         try:
             import jax
             device_kind = jax.devices()[0].device_kind
         except Exception:
-            return None
+            return None, None
     kind = str(device_kind).lower()
-    for sub, peak in PEAK_HBM_GBPS:
+    for sub, tflops, gbps in PEAKS:
         if sub in kind:
-            return peak
-    return None
+            return tflops, gbps
+    return None, None
 
 
 # the one definition of "tracing off" — parse_trace_epochs and
@@ -203,7 +196,7 @@ def roofline_join(rollup: dict, stats: Optional[dict] = None,
     introspect.dispatch_counts() around each capture; a window holding
     1000 step dispatches must not read as 1000x under-utilized).  When
     `dispatches` is omitted the window is assumed to hold ONE dispatch
-    per module (bench-style micro-windows).  The module's device-time
+    per module (a micro-window around one call).  The module's device-time
     denominator is the rollup's pre-truncation `modules` total, so
     tail kernels folded into other_us still count.
 
@@ -219,13 +212,7 @@ def roofline_join(rollup: dict, stats: Optional[dict] = None,
     if stats is None:
         from . import introspect
         stats = introspect.stats()
-    peak_tf = None
-    try:
-        from . import goodput
-        peak_tf = goodput.peak_tflops()
-    except Exception:
-        pass
-    peak_bw = peak_hbm_gbps()
+    peak_tf, peak_bw = peaks()
     rollup["peak_tflops"] = peak_tf
     rollup["peak_hbm_gbps"] = peak_bw
     # module device time: pre-truncation totals when the rollup carries
@@ -530,18 +517,6 @@ class DeviceProfiler:
             if not os.path.exists(cand):
                 return cand
         return base  # pathological; the merge is the lesser evil
-
-    def note_superseded(self, epoch: int) -> None:
-        """The legacy SHIFU_TPU_PROFILE_DIR dump owns this epoch's
-        capture (the two can't nest): when the schedule would have fired,
-        say so in the journal instead of silently producing nothing."""
-        if (self.enabled and self.tracing_enabled
-                and self._sched(epoch, self.start_epoch)):
-            from . import _sinks
-            _sinks.event(
-                "trace_fallback", epoch=int(epoch), stage="superseded",
-                error="SHIFU_TPU_PROFILE_DIR owns this epoch's capture "
-                      "(raw TensorBoard dump; no device_profile rollup)")
 
     @contextlib.contextmanager
     def epoch_capture(self, epoch: int) -> Iterator[None]:

@@ -15,14 +15,6 @@ alone.  This module is that accounting for shifu_tpu:
   wall by construction (`other` absorbs the remainder).
 - **Goodput fraction** = step seconds / wall: the fraction of the epoch
   the devices spent advancing the model.
-- **MFU** = achieved FLOP/s ÷ the platform's peak.  Achieved FLOPs come
-  from the XLA `cost_analysis()` of the instrumented step programs
-  (per-dispatch FLOPs x dispatches, accumulated via `note_flops`); the
-  peak comes from `PEAK_BF16_TFLOPS` below, overridable with
-  `SHIFU_TPU_PEAK_TFLOPS` (the escape hatch for new parts and for CPU
-  tests).  On backends where cost capture is off (see introspect.py)
-  MFU is null, never guessed.
-
 - **Phases**: finer host intervals inside the buckets.  A
   `obs.span(..., journal=False)` that closes while a ledger is open
   (obs/spans.py) adds seconds and a count under its full nested path,
@@ -37,69 +29,28 @@ alone.  This module is that accounting for shifu_tpu:
 
 Every epoch journals ONE `goodput` event and feeds the
 `goodput_bucket_seconds_total{bucket=...}` counter plus the
-`goodput_fraction` / `mfu` gauges, so `shifu-tpu profile`,
-`shifu-tpu status`, bench.py, and tools/perf_gate.py all read the same
-record (docs/PERF.md "Goodput & MFU").
+`goodput_fraction` gauge, so `shifu-tpu profile` and `shifu-tpu status`
+read the same record (docs/OBSERVABILITY.md "Goodput ledger").
 """
 
 from __future__ import annotations
 
-import os
 import threading
 from typing import Optional
-
-# peak dense bf16 TFLOP/s per chip by device-kind substring (public
-# specs) — THE per-platform table the MFU denominator comes from
-# (bench.py imports this; one table, one truth).  First match wins, so
-# "v5p" must precede "v5".
-PEAK_BF16_TFLOPS: tuple[tuple[str, float], ...] = (
-    ("v6", 918.0),       # Trillium / v6e
-    ("v5p", 459.0),
-    ("v5", 197.0),       # v5e / "TPU v5 lite"
-    ("v4", 275.0),
-    ("v3", 123.0),
-    ("v2", 45.0),
-)
-
-ENV_PEAK_TFLOPS = "SHIFU_TPU_PEAK_TFLOPS"
 
 BUCKETS = ("compile", "input", "step", "checkpoint", "restore", "eval",
            "other")
 
 
-def peak_tflops(device_kind: Optional[str] = None) -> Optional[float]:
-    """Peak bf16 TFLOP/s for a device kind (current backend's device 0
-    when omitted); SHIFU_TPU_PEAK_TFLOPS overrides the table; None when
-    the platform is unknown (CPU, new parts) — MFU is then null."""
-    env = os.environ.get(ENV_PEAK_TFLOPS)
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass  # a typo'd override must not crash telemetry
-    if device_kind is None:
-        try:
-            import jax
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            return None
-    kind = str(device_kind).lower()
-    for sub, peak in PEAK_BF16_TFLOPS:
-        if sub in kind:
-            return peak
-    return None
-
-
 class GoodputLedger:
-    """One epoch's wall-time classification.  Threads may `add` /
-    `add_flops` concurrently (the prefetch producer compiles its
-    device_put path; checkpoint saves may run from hooks)."""
+    """One epoch's wall-time classification.  Threads may `add`
+    concurrently (the prefetch producer compiles its device_put path;
+    checkpoint saves may run from hooks)."""
 
     def __init__(self) -> None:
         self._lock = threading.Lock()
         self._seconds: dict[str, float] = {}
         self._phases: dict[str, list] = {}   # path -> [seconds, count]
-        self._flops = 0.0
         self._compiles = 0
 
     def add(self, bucket: str, seconds: float) -> None:
@@ -127,11 +78,6 @@ class GoodputLedger:
                 cell[0] += seconds
                 cell[1] += count
 
-    def add_flops(self, flops: float) -> None:
-        if flops > 0 and flops != float("inf"):  # NaN > 0 is False
-            with self._lock:
-                self._flops += float(flops)
-
     def summary(self, wall_s: float) -> dict:
         """The goodput record for an epoch of `wall_s` seconds.  Compile
         time happens INSIDE the timed step/eval dispatches (a compiling
@@ -142,7 +88,6 @@ class GoodputLedger:
             b = dict(self._seconds)
             phases = {k: [round(v[0], 6), v[1]]
                       for k, v in self._phases.items()}
-            flops = self._flops
             compiles = self._compiles
         compile_s = b.get("compile", 0.0)
         overlap = min(compile_s, b.get("step", 0.0))
@@ -152,7 +97,7 @@ class GoodputLedger:
                    if k != "other"}
         classified = sum(buckets.values())
         buckets["other"] = round(max(wall_s - classified, 0.0), 6)
-        out = {
+        return {
             "wall_s": round(wall_s, 6),
             "buckets": buckets,
             "goodput_fraction": round(buckets["step"] / wall_s, 4)
@@ -160,18 +105,6 @@ class GoodputLedger:
             "compiles": compiles,
             "phases": phases,
         }
-        peak = peak_tflops()
-        achieved = (flops / wall_s / 1e12) if wall_s > 0 and flops > 0 \
-            else None
-        # significant digits, not fixed decimals: CPU-scale TFLOP/s (and
-        # the MFU they imply) are legitimately tiny and must not round
-        # to a meaningless 0.0
-        out["achieved_tflops"] = (float(f"{achieved:.6g}")
-                                  if achieved is not None else None)
-        out["peak_tflops"] = peak
-        out["mfu"] = (float(f"{achieved / peak:.6g}")
-                      if achieved is not None and peak else None)
-        return out
 
 
 _lock = threading.Lock()
@@ -215,15 +148,6 @@ def note_phase(path: str, seconds: float) -> bool:
     return True
 
 
-def note_flops(flops: float) -> None:
-    led = _current
-    if led is not None:
-        try:
-            led.add_flops(flops)
-        except Exception:
-            pass
-
-
 def end_epoch(epoch: int, wall_s: float) -> Optional[dict]:
     """Close the active ledger: journal the `goodput` event, feed the
     registry, return the record (None when no ledger is open)."""
@@ -239,7 +163,7 @@ def end_epoch(epoch: int, wall_s: float) -> Optional[dict]:
         rec["epoch"] = int(epoch)
         sec = metrics_mod.counter(
             "goodput_bucket_seconds_total",
-            "epoch wall seconds by goodput bucket (docs/PERF.md)")
+            "epoch wall seconds by goodput bucket")
         for bucket, s in rec["buckets"].items():
             sec.inc(s, bucket=bucket)
         if rec["goodput_fraction"] is not None:
@@ -247,9 +171,6 @@ def end_epoch(epoch: int, wall_s: float) -> Optional[dict]:
                 "goodput_fraction",
                 "last epoch's device-step fraction of wall time",
             ).set(rec["goodput_fraction"])
-        if rec["mfu"] is not None:
-            metrics_mod.gauge(
-                "mfu", "last epoch's model FLOP utilization").set(rec["mfu"])
         _sinks.event("goodput", **rec)
         return rec
     except Exception:

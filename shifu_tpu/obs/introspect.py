@@ -18,11 +18,6 @@ instead of bare `jax.jit`.  The wrapper is transparent at call time (one
   bucket (obs/goodput.py), so a recompile-heavy epoch shows up as lost
   goodput, not as a mysteriously slow "step".
 
-Per-dispatch FLOPs (the MFU numerator) accumulate onto the ledger via
-`goodput.note_flops` on EVERY call whose signature has a captured cost —
-a lax.scan epoch program's cost_analysis covers all its batches, so one
-dispatch credits the whole chunk.
-
 Cost capture itself runs the AOT path (`fn.lower(avals).compile()`),
 which pays a SECOND compile of the program.  That is nearly free on CPU
 (tier-1, tests) but real seconds on a TPU, so capture defaults to
@@ -104,15 +99,6 @@ def _aval(x):
         except TypeError:
             pass
     return jax.ShapeDtypeStruct(shape, dtype)
-
-
-def _signature(args, kwargs) -> tuple:
-    import jax
-
-    leaves, treedef = jax.tree_util.tree_flatten((args, kwargs))
-    return (treedef,
-            tuple((getattr(l, "shape", None), str(getattr(l, "dtype", type(l))))
-                  for l in leaves))
 
 
 def _normalize_cost(ca) -> dict:
@@ -223,7 +209,6 @@ class InstrumentedJit:
         # per-batch dispatch path (the flag is process-stable in practice;
         # flipping SHIFU_TPU_XLA_COST applies to fns built after the flip)
         self._capture = capture_enabled()
-        self._flops_by_sig: dict[tuple, float] = {}
 
     def _note_dispatch(self) -> None:
         # per-name dispatch tally: a plain dict bump (GIL-atomic enough —
@@ -233,16 +218,6 @@ class InstrumentedJit:
         st = _stats.get(self.name)
         if st is not None:
             st["dispatches"] = st.get("dispatches", 0) + 1
-
-    def _sig_of(self, args, kwargs):
-        # AFTER the call is safe: donation deletes buffer *data*, but the
-        # shape/dtype metadata _signature reads stays accessible — so the
-        # steady-state path pays the pytree flatten only once a capture
-        # has actually produced a FLOPs number to look up
-        try:
-            return _signature(args, kwargs)
-        except Exception:
-            return None
 
     def __call__(self, *args, **kwargs):
         fn = self._fn
@@ -259,21 +234,8 @@ class InstrumentedJit:
             except Exception:
                 compiled = False
             if compiled:
-                analysis = _record_compile(self.name, fn, args, kwargs,
-                                           wall, capture=self._capture)
-                if "flops" in analysis:
-                    sig = self._sig_of(args, kwargs)
-                    if sig is not None:
-                        self._flops_by_sig[sig] = analysis["flops"]
-                        from . import goodput
-                        goodput.note_flops(analysis["flops"])
-                    self._note_dispatch()
-                    return out
-        if self._flops_by_sig:  # MFU numerator: credit per dispatch
-            flops = self._flops_by_sig.get(self._sig_of(args, kwargs))
-            if flops:
-                from . import goodput
-                goodput.note_flops(flops)
+                _record_compile(self.name, fn, args, kwargs, wall,
+                                capture=self._capture)
         self._note_dispatch()
         return out
 

@@ -195,7 +195,7 @@ class Histogram:
         owning bucket, Prometheus histogram_quantile semantics).  None for
         an empty series; values beyond the last finite bound clamp to it.
         An ESTIMATE bounded by bucket resolution — exact percentiles need
-        the raw samples (tools/loadtest.py keeps them)."""
+        the raw samples (runtime/loadtest.py keeps them)."""
         snap = self.counts(**labels)
         if snap is None or snap[2] == 0:
             return None

@@ -256,7 +256,8 @@ def profile_summary(path: str) -> Optional[dict]:
     goodput bucket records, compiled functions aggregated by cost, and
     the recovery tax (restore / fallback / preemption-grace seconds) —
     assembled purely from `goodput` / `xla_compile` / checkpoint journal
-    events (docs/PERF.md "Goodput & MFU").  None when no journal."""
+    events (docs/OBSERVABILITY.md "Goodput ledger").  None when no
+    journal."""
     jpath = find_journal(path)
     if jpath is None:
         return None
@@ -292,7 +293,6 @@ def profile_summary(path: str) -> Optional[dict]:
         if kind == "goodput":
             epochs.append({k: rec.get(k) for k in
                            ("epoch", "wall_s", "buckets", "goodput_fraction",
-                            "mfu", "achieved_tflops", "peak_tflops",
                             "compiles")})
         elif kind == "overlap_report":
             overlap_epochs.append({k: rec.get(k) for k in
@@ -384,16 +384,14 @@ def profile_summary(path: str) -> Optional[dict]:
                 pass
 
     totals: dict[str, float] = {}
-    fracs, mfus = [], []
+    fracs = []
     for e in epochs:
         for b, s in (e.get("buckets") or {}).items():
             if isinstance(s, (int, float)):
                 totals[b] = round(totals.get(b, 0.0) + s, 6)
         if isinstance(e.get("goodput_fraction"), (int, float)):
             fracs.append(e["goodput_fraction"])
-        if isinstance(e.get("mfu"), (int, float)):
-            mfus.append(e["mfu"])
-    # overlap engine rollup (docs/PERF.md "Overlap engine"): how much of
+    # overlap engine rollup (docs/DATA.md "Overlap engine"): how much of
     # the epochs' host input work ran behind device compute
     hidden = sum(e["input_hidden_s"] for e in overlap_epochs
                  if isinstance(e.get("input_hidden_s"), (int, float)))
@@ -414,7 +412,6 @@ def profile_summary(path: str) -> Optional[dict]:
         "bucket_totals_s": totals,
         "goodput_fraction_mean": (round(sum(fracs) / len(fracs), 4)
                                   if fracs else None),
-        "mfu_max": (round(max(mfus), 6) if mfus else None),
         "overlap": overlap,
         "ingest": ingests or None,
         # by cost: captured FLOPs first (the honest "expensive" ranking),
@@ -425,8 +422,8 @@ def profile_summary(path: str) -> Optional[dict]:
                             -kv[1]["compile_s"]))),
         "recovery": recovery,
     }
-    # device flight recorder rollup (docs/PERF.md "Where the step time
-    # goes"): the last device profile's top kernels next to the goodput
+    # device flight recorder rollup (docs/OBSERVABILITY.md "Device flight
+    # recorder"): the last device profile's top kernels next to the goodput
     # buckets they decompose, plus the HBM high water and anomaly count
     device: dict = {}
     if profiles:
@@ -518,7 +515,7 @@ def render_profile_text(summary: dict) -> str:
     else:
         hdr = (f"{'epoch':>5} {'wall_s':>8} {'compile':>8} {'input':>8} "
                f"{'step':>8} {'ckpt':>8} {'restore':>8} {'eval':>8} "
-               f"{'other':>8} {'goodput':>8} {'mfu':>8}")
+               f"{'other':>8} {'goodput':>8}")
         lines.append(hdr)
 
         def f(v, spec="0.3f"):
@@ -532,15 +529,11 @@ def render_profile_text(summary: dict) -> str:
                 f"{f(b.get('step')):>8} {f(b.get('checkpoint')):>8} "
                 f"{f(b.get('restore')):>8} {f(b.get('eval')):>8} "
                 f"{f(b.get('other')):>8} "
-                f"{f(e.get('goodput_fraction'), '.1%'):>8} "
-                f"{f(e.get('mfu'), '.4f'):>8}")
+                f"{f(e.get('goodput_fraction'), '.1%'):>8}")
         mean_frac = summary.get("goodput_fraction_mean")
-        mfu_max = summary.get("mfu_max")
-        tail = [f"goodput mean {mean_frac:.1%}"
-                if isinstance(mean_frac, (int, float)) else "goodput mean -"]
-        if isinstance(mfu_max, (int, float)):
-            tail.append(f"mfu max {mfu_max:.4f}")
-        lines.append("  ".join(tail))
+        lines.append(f"goodput mean {mean_frac:.1%}"
+                     if isinstance(mean_frac, (int, float))
+                     else "goodput mean -")
     overlap = summary.get("overlap")
     if overlap:
         eff = overlap.get("efficiency")
@@ -903,8 +896,8 @@ def top_summary(path: str,
     per-stage lifecycle breakdown (always-on `serve_stage_seconds`
     histograms in the scrape file), active SLO alerts (firing `slo_alert`
     events not yet resolved), and sampled `request_trace` / one-shot
-    `device_profile` counts.  Train dirs render epoch progress, goodput /
-    MFU, and the last event — ONE command tops both planes.  None when no
+    `device_profile` counts.  Train dirs render epoch progress, goodput,
+    and the last event — ONE command tops both planes.  None when no
     journal is found.
 
     Staleness: a dir whose freshest signal (fleet lease beat or last
@@ -1101,7 +1094,7 @@ def top_summary(path: str,
                              "valid_auc", "epoch_time")}
         if goodput is not None:
             out["goodput"] = {k: goodput.get(k) for k in
-                              ("epoch", "goodput_fraction", "mfu")}
+                              ("epoch", "goodput_fraction")}
         # sparse embedding engine: the live tier/dedup story from the
         # journal tail (docs/EMBEDDING.md)
         embed: dict = {}
@@ -1298,13 +1291,10 @@ def render_top_text(summary: dict) -> str:
     gp = summary.get("goodput")
     if gp:
         frac = gp.get("goodput_fraction")
-        mfu = gp.get("mfu")
         lines.append(
             "goodput "
             + (format(frac, ".1%") if isinstance(frac, (int, float))
-               else "-")
-            + ("  mfu " + format(mfu, ".4f")
-               if isinstance(mfu, (int, float)) else ""))
+               else "-"))
     em = summary.get("embed")
     if em:
         hr = em.get("hit_rate")

@@ -65,10 +65,7 @@ def _annotate(path: str):
 
 def emit(path: str, dur_s: float, journal: bool = True, **fields) -> None:
     """Record one completed span: `span_seconds` histogram observation +
-    (optionally) a `span` journal event.  The ONE emission contract —
-    shared by the `span()` context manager and external phase trackers
-    (bench._PhaseTrack), so bench phases and real spans can never diverge
-    into split metrics.  Never raises."""
+    (optionally) a `span` journal event.  Never raises."""
     try:
         metrics_mod.histogram(
             "span_seconds",

@@ -187,32 +187,3 @@ def rollup_trace_dir(log_dir: str,
         except (OSError, ValueError):
             continue  # a torn capture must not hide the readable ones
     return kernel_rollup(merged, top_k=top_k)
-
-
-def diff_rollups(a: dict, b: dict) -> list[dict]:
-    """Per-kernel device-time deltas between two rollups (A = before,
-    B = after) — the regression-attribution table tools/trace_diff.py
-    prints.  Kernels are matched by (name, module); one-sided kernels
-    show with the missing side at 0."""
-    def index(r: dict) -> dict[tuple, dict]:
-        return {(k["name"], k.get("module")): k
-                for k in r.get("kernels") or []}
-
-    ia, ib = index(a), index(b)
-    out = []
-    for key in sorted(set(ia) | set(ib)):
-        ka, kb = ia.get(key), ib.get(key)
-        ua = float(ka["device_us"]) if ka else 0.0
-        ub = float(kb["device_us"]) if kb else 0.0
-        out.append({
-            "name": key[0],
-            "module": key[1],
-            "a_us": round(ua, 3),
-            "b_us": round(ub, 3),
-            "delta_us": round(ub - ua, 3),
-            "ratio": round(ub / ua, 4) if ua > 0 else None,
-            "a_calls": ka["calls"] if ka else 0,
-            "b_calls": kb["calls"] if kb else 0,
-        })
-    out.sort(key=lambda d: -abs(d["delta_us"]))
-    return out

@@ -1,9 +1,7 @@
 """Fused FT-Transformer block: attention + FFN in one Pallas pass.
 
-BENCH_r05 pins FT-Transformer at MFU 0.058 — the worst number on the
-ladder — and the flight recorder's rollup blames the unfused hot loop:
-each TransformerBlock dispatches LayerNorm, qkv, attention, proj, LN,
-mlp_in, gelu, mlp_out as separate HLO regions whose (B, S, D)
+Unfused, each TransformerBlock dispatches LayerNorm, qkv, attention,
+proj, LN, mlp_in, gelu, mlp_out as separate HLO regions whose (B, S, D)
 intermediates round-trip HBM eight times per block.  Feature-token
 attention is tiny (S ~ 31 tokens, head_dim 8); the arithmetic lives in
 the FFN matmuls, so the win is keeping one batch tile's activations in

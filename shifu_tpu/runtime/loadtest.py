@@ -15,8 +15,7 @@ Two modes:
   through the daemon's `on_batch` hook (scores + scheduled arrivals +
   done-stamp per dispatched batch), so the measured path is admission ->
   micro-batch -> score -> completion with no per-request Future overhead.
-  This is the capacity-measurement mode (`serving_scores_per_sec` in
-  bench.py / tools/perf_gate.py).
+  This is the capacity-measurement mode.
 - **socket** (`connect=`): each sender owns a ServeClient connection and
   round-trips single-row frames against a live `shifu-tpu serve` daemon —
   the end-to-end-wire mode (rates bounded by the per-connection RTT;
@@ -617,7 +616,7 @@ def find_capacity(export_dir: Optional[str] = None, *,
 
 def render_report(report: dict) -> str:
     """Human text for a loadtest / capacity report — the ONE renderer
-    `shifu-tpu loadtest` and tools/loadtest.py both print."""
+    `shifu-tpu loadtest` prints."""
     lines = []
     if "ramp" in report:
         for step in report["ramp"]:

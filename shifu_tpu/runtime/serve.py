@@ -365,7 +365,7 @@ class ScoringDaemon:
     """The persistent scorer: admission queue, micro-batch dispatch,
     hot-swappable model registry, lifecycle, telemetry.
 
-    In-process API (the wire server and tools/loadtest.py sit on top):
+    In-process API (the wire server and runtime/loadtest.py sit on top):
 
     - `submit(row)` -> Future resolving to that row's (H,) score vector
       (`need_future=False` skips the Future for fire-and-forget callers
@@ -974,7 +974,7 @@ class ScoringDaemon:
     def stage_counts(self) -> dict:
         """Per-stage snapshots of the process-global `serve_stage_seconds`
         histogram: {stage: (counts, sum, n) | None} — callers window a
-        run (tools/loadtest.py) or the daemon lifetime (stats()) by
+        run (runtime/loadtest.py) or the daemon lifetime (stats()) by
         differencing two snapshots."""
         from .. import obs
         from ..export.scorer import SCORE_LATENCY_BUCKETS

@@ -361,7 +361,7 @@ class ServeServer:
 
 
 class ServeClient:
-    """Blocking client for the wire protocol (tools/loadtest.py socket
+    """Blocking client for the wire protocol (runtime/loadtest.py socket
     mode, tests, and a reference for JVM/other-language bindings)."""
 
     def __init__(self, host: str = "127.0.0.1", port: int = 8571,

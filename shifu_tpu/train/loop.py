@@ -594,7 +594,7 @@ def train(job: JobConfig,
             # blocking ingest (hot cache / loaded tiers / out-of-core):
             # credited to the FIRST epoch's goodput input bucket below —
             # the cold-start tax must show up in the ledger, not vanish
-            # into unaccounted pre-epoch wall (docs/PERF.md "Data plane")
+            # into unaccounted pre-epoch wall (docs/DATA.md "Columnar cache")
             t_ingest = time.perf_counter()
             train_ds, valid_ds = pipe.load_datasets(
                 job.schema, job.data, host, nhosts,
@@ -955,7 +955,6 @@ def train(job: JobConfig,
 
     from . import profiler as prof_lib
 
-    profile_dir = os.environ.get("SHIFU_TPU_PROFILE_DIR")
     timing_on = bool(os.environ.get("SHIFU_TPU_TIMING")) or job.train.log_every_steps > 0
 
     # device flight recorder (obs/devprof.py): scheduled jax.profiler
@@ -1107,22 +1106,17 @@ def train(job: JobConfig,
             pending_loader = None
             _prepare_tiers()
         # loss accumulates on device; host sync happens once per epoch so
-        # async dispatch keeps the chips busy (bench.py measures the same way)
+        # async dispatch keeps the chips busy
         loss_acc = None
         loss_n = 0
         host_input_times.clear()
         timer = prof_lib.StepTimer(on_chunk=devprof.chunk_hook(epoch))
         timer.start()
-        # trace seam: the legacy SHIFU_TPU_PROFILE_DIR first-epoch dump
-        # keeps its raw TensorBoard semantics; otherwise the flight
-        # recorder's schedule decides (obs.trace_epochs — a scheduled
-        # epoch's capture closes into a `device_profile` journal event)
-        if profile_dir and epoch == start_epoch:
-            devprof.note_superseded(epoch)  # schedule collision: say so
-            trace_ctx = prof_lib.trace(profile_dir)
-        else:
-            trace_ctx = devprof.epoch_capture(epoch)
-        with trace_ctx, obs.span("epoch/train", epoch=epoch):
+        # trace seam: the flight recorder's schedule decides
+        # (obs.trace_epochs — a scheduled epoch's capture closes into a
+        # `device_profile` journal event)
+        with devprof.epoch_capture(epoch), \
+                obs.span("epoch/train", epoch=epoch):
             streamed_this_epoch = False
             if stream_loader is not None and epoch == start_epoch:
                 # streamed first epoch: train on stacked blocks as files

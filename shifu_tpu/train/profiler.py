@@ -8,18 +8,17 @@ reference: resources/ssgd_monitor.py:270-293, appmaster/TensorflowSession.java:
 - `StepTimer`: cheap per-step wall timing with percentile summaries — the
   straggler view's SPMD successor (under SPMD the interesting skew is
   host-side input time vs device step time, both captured here).
-- `trace`: context manager around `jax.profiler` emitting a TensorBoard-
-  loadable trace directory (the real version of the reference's dead
-  start_tensorboard).
-- `profile_epoch` hook for the train loop via SHIFU_TPU_PROFILE_DIR.
+- `straggler_line`: the cross-host per-epoch timing table.
+
+The `jax.profiler` seam itself (the real version of the reference's dead
+start_tensorboard) is obs/devprof.py's `epoch_capture`, on the
+`obs.trace_epochs` schedule.
 """
 
 from __future__ import annotations
 
-import contextlib
-import os
 import time
-from typing import Callable, Iterator, Optional
+from typing import Callable, Optional
 
 import numpy as np
 
@@ -156,23 +155,3 @@ def straggler_line(epoch: int, epoch_time: float, valid_time: float,
 
     obs.aggregate.epoch_skew(epoch, input_seconds, epoch_time, valid_time,
                              console=console, extra=extra)
-
-
-@contextlib.contextmanager
-def trace(log_dir: str) -> Iterator[None]:
-    """jax.profiler trace (TensorBoard `Profile` plugin format)."""
-    import jax
-
-    os.makedirs(log_dir, exist_ok=True)
-    jax.profiler.start_trace(log_dir)
-    try:
-        yield
-    finally:
-        jax.profiler.stop_trace()
-
-
-def maybe_trace(log_dir: Optional[str]):
-    """trace() if a directory is given, else a no-op context."""
-    if log_dir:
-        return trace(log_dir)
-    return contextlib.nullcontext()

@@ -55,7 +55,7 @@ _EPS = 1e-8
 # "auto" engages only where the FUSED rows-update kernel can serve the
 # scatter (and the vocab is big enough that dense optimizer traffic
 # dominates).  The measured negative result for the XLA-scatter path
-# stands (docs/PERF.md "DeepFM rung"): the dense fused adadelta
+# stands (r05 rig, not re-measured since): the dense fused adadelta
 # elementwise runs at ~760M table-rows/s on a v5e while XLA:TPU scatters
 # run at ~30M rows/s AND degrade with table height, so the scatter-based
 # sparse path measured 0.2x dense at V=100k/B=32k and still 0.71x at

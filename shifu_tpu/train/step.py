@@ -218,7 +218,7 @@ def _input_donate_argnums(donate: bool, donate_batch: bool) -> tuple:
     as the scan consumes them instead of when the Python reference dies —
     steady-state H2D then cycles through a fixed set of buffers rather than
     growing a fresh allocation per chunk.  Callers that REUSE a batch
-    across calls (bench one_step loops, the device-resident tier's blocks)
+    across calls (the device-resident tier's blocks)
     must keep donate_batch=False: a donated buffer is deleted after its
     first use."""
     out = (0,) if donate else ()

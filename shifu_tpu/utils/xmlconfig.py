@@ -107,9 +107,6 @@ KEY_TRAIN_SPARSE_EMBED = "shifu.train.sparse-embedding-update"
 # pod data plane: host shard-assignment mode auto / static / rotate
 # (DataConfig.host_shard, data/pipeline.host_shard_assignment)
 KEY_DATA_HOST_SHARD = "shifu.data.host-shard"
-# minimum train_scaling_efficiency accepted by the pod scaling sweep
-# (TrainConfig.scaling_gate; 0 disables)
-KEY_TRAIN_SCALING_GATE = "shifu.train.scaling-gate"
 # device flight recorder (ObsConfig — obs/devprof.py, docs/OBSERVABILITY.md
 # "Device flight recorder"): trace-window schedule
 # (off/first/every:N/comma-list), capture dir, rollup size, HBM watermark
@@ -509,10 +506,6 @@ def apply_to_job(job: Any, conf: Mapping[str, str]) -> Any:
         import dataclasses
         data = dataclasses.replace(
             data, host_shard=conf[KEY_DATA_HOST_SHARD].strip().lower())
-    if KEY_TRAIN_SCALING_GATE in conf:
-        import dataclasses
-        train = dataclasses.replace(
-            train, scaling_gate=float(conf[KEY_TRAIN_SCALING_GATE]))
 
     import dataclasses
     obs_kw: dict[str, Any] = {}

@@ -52,6 +52,24 @@ def _clean_chaos_and_obs():
     obs.reset_for_tests()
 
 
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_compile_cache():
+    """This file's packs are made and loaded with the persistent compile
+    cache off.  A test earlier on the same worker may have left it on (a
+    benchmark driver run in process does), and on the CPU an executable
+    that was loaded from a warm cache does not serialize whole: the pack
+    made from it fails at its first call."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
 @pytest.fixture(scope="module")
 def packed(tmp_path_factory):
     """Two AOT-packed artifacts of the same schema with different

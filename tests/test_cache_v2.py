@@ -701,7 +701,7 @@ def test_ingest_report_schema_and_tiers(tmp_path):
                     "write_s"} <= set(pf)
     assert cold["tiers"] == {"parse": 3}
     assert warm["tiers"] == {"cache": 3}
-    # cold-ingest phase counters feed bench.py's e2e_cold_ingest fields
+    # the cold-ingest phase counters
     reg = obs.default_registry()
     assert reg.counter("ingest_seconds_total").value(phase="parse") > 0
     assert reg.counter("ingest_seconds_total").value(

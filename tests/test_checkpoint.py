@@ -405,14 +405,24 @@ def test_time_based_checkpoint_cadence(tmp_path, small_job, small_data):
     assert len(steps) > 1, steps
 
 
+def _sigterm_after_epoch(epoch):
+    """An `epoch_callback` that sends this process SIGTERM once `epoch` has
+    closed: train()'s handler is installed by then and epochs remain, which
+    a wall-clock timer promises of neither (a SIGTERM during init takes the
+    default terminate action, by design)."""
+    import os
+    import signal
+
+    def callback(m):
+        if m.epoch == epoch:
+            os.kill(os.getpid(), signal.SIGTERM)
+
+    return callback
+
+
 def test_sigterm_saves_and_exits_75(tmp_path, small_job, small_data):
     """SIGTERM mid-training checkpoints the current state and exits with
     code 75 so the supervisor restarts the job (preemption awareness)."""
-    import dataclasses
-    import os
-    import signal
-    import threading
-
     train_ds, valid_ds = small_data
     d = str(tmp_path / "ckpt")
     job = small_job.replace(
@@ -420,18 +430,10 @@ def test_sigterm_saves_and_exits_75(tmp_path, small_job, small_data):
                                         optimizer=small_job.train.optimizer),
         runtime=RuntimeConfig(checkpoint=CheckpointConfig(directory=d)))
 
-    # prewarm jit caches so the handler is installed before the timer fires
-    warm = small_job.replace(train=small_job.train.__class__(
-        epochs=1, optimizer=small_job.train.optimizer))
-    train(warm, train_ds, valid_ds, console=lambda s: None)
     lines = []
-    killer = threading.Timer(1.5, lambda: os.kill(os.getpid(), signal.SIGTERM))
-    killer.start()
-    try:
-        with pytest.raises(SystemExit) as exc:
-            train(job, train_ds, valid_ds, console=lines.append)
-    finally:
-        killer.cancel()
+    with pytest.raises(SystemExit) as exc:
+        train(job, train_ds, valid_ds, console=lines.append,
+              epoch_callback=_sigterm_after_epoch(1))
     assert exc.value.code == 75
     assert any("SIGTERM" in l for l in lines)
     from shifu_tpu.train import checkpoint as ckpt_lib
@@ -448,26 +450,12 @@ def test_sigterm_saves_and_exits_75(tmp_path, small_job, small_data):
 def test_sigterm_without_checkpoint_dir_still_exits(small_job, small_data):
     """SIGTERM must terminate the run even when no checkpoint manager is
     configured (the drain point fires without a save)."""
-    import os
-    import signal
-    import threading
-
     train_ds, valid_ds = small_data
     job = small_job.replace(train=small_job.train.__class__(
         epochs=200, optimizer=small_job.train.optimizer))
-    # prewarm the jit caches so train() reaches its handler install well
-    # before the timer fires (a SIGTERM during init takes the default
-    # terminate action, by design)
-    warm = small_job.replace(train=small_job.train.__class__(
-        epochs=1, optimizer=small_job.train.optimizer))
-    train(warm, train_ds, valid_ds, console=lambda s: None)
     lines = []
-    killer = threading.Timer(1.0, lambda: os.kill(os.getpid(), signal.SIGTERM))
-    killer.start()
-    try:
-        with pytest.raises(SystemExit) as exc:
-            train(job, train_ds, valid_ds, console=lines.append)
-    finally:
-        killer.cancel()
+    with pytest.raises(SystemExit) as exc:
+        train(job, train_ds, valid_ds, console=lines.append,
+              epoch_callback=_sigterm_after_epoch(1))
     assert exc.value.code == 75
     assert any("no checkpoint directory" in l for l in lines)

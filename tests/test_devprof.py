@@ -3,12 +3,11 @@ arithmetic, the trace-epoch schedule grammar, the anomaly detector's
 quiet/spike contract, the CPU trace-capture train smoke the acceptance
 criteria pin (>=1 `device_profile` with a non-empty kernel rollup whose
 fractions sum to <= 1, >=1 `hbm_watermark`), the chaos `obs.trace`
-fallback, `shifu-tpu trace` rendering, and tools/trace_diff.py.
+fallback, and `shifu-tpu trace` rendering.
 """
 
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -17,8 +16,6 @@ from shifu_tpu import chaos, obs
 from shifu_tpu.config import ObsConfig
 from shifu_tpu.config.schema import ConfigError
 from shifu_tpu.obs import devprof, render as obs_render, tracefmt
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(autouse=True)
@@ -96,18 +93,6 @@ def test_kernel_rollup_empty_and_dir_roundtrip(tmp_path):
     r = tracefmt.rollup_trace_dir(str(tmp_path))
     assert r and r["kernels"][0]["name"] == "dot.9"
     assert tracefmt.rollup_trace_dir(str(tmp_path / "nope")) is None
-
-
-def test_diff_rollups_matches_by_kernel():
-    a = tracefmt.kernel_rollup([_dev("dot.1", 0, 10), _dev("gone.1", 20, 5)])
-    b = tracefmt.kernel_rollup([_dev("dot.1", 0, 30), _dev("new.1", 40, 5)])
-    rows = tracefmt.diff_rollups(a, b)
-    by = {r["name"]: r for r in rows}
-    assert by["dot.1"]["delta_us"] == pytest.approx(20.0)
-    assert by["dot.1"]["ratio"] == pytest.approx(3.0)
-    assert by["gone.1"]["b_us"] == 0.0
-    assert by["new.1"]["a_us"] == 0.0 and by["new.1"]["ratio"] is None
-    assert rows[0]["name"] == "dot.1"  # largest |delta| first
 
 
 # ----------------------------------------------------- schedule + config
@@ -270,27 +255,6 @@ def test_fresh_capture_dir_never_merges_stale_runs(tmp_path):
     assert dp._fresh_capture_dir(base) == base + "-r2"
 
 
-def test_legacy_profile_dir_collision_is_journaled(tmp_path):
-    """SHIFU_TPU_PROFILE_DIR owning a scheduled epoch must leave a
-    journaled explanation, not silently zero device_profile events."""
-    obs.configure(str(tmp_path))
-    cfg = ObsConfig(trace_epochs="first", trace_dir=str(tmp_path / "tr"))
-    dp = devprof.DeviceProfiler(cfg)
-    dp.note_superseded(0)   # scheduled epoch: journals
-    dp.note_superseded(1)   # unscheduled: silent
-    obs.flush()
-    recs = [r for r in obs.read_journal(str(tmp_path / "journal.jsonl"))
-            if r["kind"] == "trace_fallback"]
-    assert len(recs) == 1
-    assert recs[0]["epoch"] == 0 and recs[0]["stage"] == "superseded"
-    # tracing off: never journals
-    dp_off = devprof.DeviceProfiler(ObsConfig())
-    dp_off.note_superseded(0)
-    obs.flush()
-    assert len([r for r in obs.read_journal(str(tmp_path / "journal.jsonl"))
-                if r["kind"] == "trace_fallback"]) == 1
-
-
 def test_chaos_obs_trace_degrades_to_fallback(tmp_path):
     """An injected `obs.trace` fault must not fail the epoch: the capture
     degrades to a journaled `trace_fallback` and the body still runs."""
@@ -373,7 +337,7 @@ def test_train_smoke_journals_device_profile_and_watermarks(
               if k.get("intensity_flops_per_byte")]
     assert joined
     assert all(k.get("window_dispatches", 0) >= 1 for k in joined)
-    # pre-truncation per-module totals ride for trace_diff / rooflines
+    # pre-truncation per-module totals ride for the rooflines
     assert p.get("modules")
     # epoch 1 is unscheduled ("first"): exactly one scheduled capture
     assert all(r["epoch"] == 0 for r in profiles
@@ -441,72 +405,14 @@ def test_trace_off_by_default_still_watermarks(tmp_path, monkeypatch):
     assert [r for r in recs if r["kind"] == "hbm_watermark"]
 
 
-# ---------------------------------------------------------------- tooling
-
-
-def test_trace_diff_tool(tmp_path, monkeypatch, capsys):
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    import trace_diff
-
-    a = {"device_us_total": 100.0, "epoch": 0,
-         "kernels": [{"name": "dot.1", "module": "jit_step", "calls": 3,
-                      "device_us": 80.0},
-                     {"name": "fusion.1", "module": "jit_step", "calls": 3,
-                      "device_us": 20.0}]}
-    b = json.loads(json.dumps(a))
-    b["device_us_total"] = 250.0
-    b["kernels"][0]["device_us"] = 230.0
-    pa, pb = tmp_path / "a.json", tmp_path / "b.json"
-    pa.write_text(json.dumps(a))
-    pb.write_text(json.dumps(b))
-
-    assert trace_diff.main([str(pa), str(pb), "--json"]) == 0
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["verdict"] == "PASS"
-    assert doc["kernels"][0]["name"] == "dot.1"
-    assert doc["kernels"][0]["delta_us"] == pytest.approx(150.0)
-    assert doc["total_ratio"] == pytest.approx(2.5)
-
-    # --fail-above blames the kernel that grew
-    assert trace_diff.main([str(pa), str(pb), "--fail-above", "50",
-                            "--json"]) == 1
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["verdict"] == "REGRESSION"
-    assert "dot.1" in doc["blamed"]
-    # ... and the reverse direction passes (improvements never fail)
-    assert trace_diff.main([str(pb), str(pa), "--fail-above", "50"]) == 0
-    capsys.readouterr()
-
-    # missing rollup: usage error with the fix spelled out, no traceback
-    assert trace_diff.main([str(tmp_path / "nope.json"), str(pb)]) == 2
-
-
-def test_trace_diff_reads_journals(tmp_path, monkeypatch, capsys):
-    """The default spelling: two job dirs, last device_profile each."""
-    sys.path.insert(0, os.path.join(REPO, "tools"))
-    import trace_diff
-
-    for sub, us in (("ja", 10.0), ("jb", 40.0)):
-        obs.reset_for_tests()
-        d = tmp_path / sub / "telemetry"
-        obs.configure(str(d))
-        obs.event("device_profile", epoch=0, trigger="schedule",
-                  device_us_total=us,
-                  kernels=[{"name": "dot.1", "module": None, "calls": 1,
-                            "device_us": us}])
-        obs.flush()
-        obs.shutdown()
-    assert trace_diff.main([str(tmp_path / "ja"), str(tmp_path / "jb"),
-                            "--json"]) == 0
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["total_delta_us"] == pytest.approx(30.0)
+# --------------------------------------------------------------- roofline
 
 
 def test_roofline_join_classifies_with_peaks(monkeypatch):
     """With platform peaks pinned, a high-intensity module classifies
     compute-bound and a low-intensity one HBM-bound."""
-    monkeypatch.setenv("SHIFU_TPU_PEAK_TFLOPS", "100.0")
-    monkeypatch.setenv(devprof.ENV_PEAK_HBM_GBPS, "1000.0")
+    monkeypatch.setattr(devprof, "peaks",
+                        lambda kind=None: (100.0, 1000.0))
     # balance = 100e12 / 1000e9 = 100 flops/byte
     rollup = {"kernels": [
         {"name": "dot.1", "module": "jit_compute", "device_us": 1000.0,
@@ -530,45 +436,40 @@ def test_roofline_join_classifies_with_peaks(monkeypatch):
     assert by["dot.1"]["flops_frac"] == pytest.approx(10.0)
 
 
-def test_roofline_join_scales_by_window_dispatches():
+def test_roofline_join_scales_by_window_dispatches(monkeypatch):
     """cost_analysis FLOPs are PER DISPATCH: a window holding N
     dispatches must multiply by N, or a busy program reads as N-x
     under-utilized (and the module denominator must come from the
     pre-truncation `modules` totals, not just the kept kernels)."""
-    import os
-    os.environ["SHIFU_TPU_PEAK_TFLOPS"] = "100.0"
-    os.environ[devprof.ENV_PEAK_HBM_GBPS] = "1000.0"
-    try:
-        rollup = {
-            "kernels": [{"name": "dot.1", "module": "jit_step",
-                         "device_us": 600.0, "calls": 10}],
-            # the module really spent 1000us (400 folded into other_us)
-            "modules": {"jit_step": 1000.0},
-        }
-        stats = {"train_step": {"flops": 1e10, "bytes_accessed": 1e9}}
-        devprof.roofline_join(rollup, stats=stats,
-                              dispatches={"train_step": 10})
-        k = rollup["kernels"][0]
-        assert k["window_dispatches"] == 10
-        # 1e10 flops x 10 dispatches over 1000us (the module total, NOT
-        # the kept kernel's 600us) = 100 TFLOP/s -> exactly the peak
-        assert k["flops_frac"] == pytest.approx(1.0)
-        # bytes: 1e9 x 10 over 1ms = 10 TB/s -> 10x the 1000 GB/s peak
-        assert k["hbm_frac"] == pytest.approx(10.0)
-        assert k["bound"] == "hbm"
-        # a matched module whose fn never dispatched in the window gets
-        # no fractions (honest null), intensity still rides
-        rollup2 = {"kernels": [{"name": "dot.1", "module": "jit_step",
-                                "device_us": 600.0, "calls": 1}],
-                   "modules": {"jit_step": 600.0}}
-        devprof.roofline_join(rollup2, stats=stats,
-                              dispatches={"other_fn": 5})
-        k2 = rollup2["kernels"][0]
-        assert "flops_frac" not in k2 and k2["bound"] is None
-        assert k2["intensity_flops_per_byte"] == pytest.approx(10.0)
-    finally:
-        os.environ.pop("SHIFU_TPU_PEAK_TFLOPS", None)
-        os.environ.pop(devprof.ENV_PEAK_HBM_GBPS, None)
+    monkeypatch.setattr(devprof, "peaks",
+                        lambda kind=None: (100.0, 1000.0))
+    rollup = {
+        "kernels": [{"name": "dot.1", "module": "jit_step",
+                     "device_us": 600.0, "calls": 10}],
+        # the module really spent 1000us (400 folded into other_us)
+        "modules": {"jit_step": 1000.0},
+    }
+    stats = {"train_step": {"flops": 1e10, "bytes_accessed": 1e9}}
+    devprof.roofline_join(rollup, stats=stats,
+                          dispatches={"train_step": 10})
+    k = rollup["kernels"][0]
+    assert k["window_dispatches"] == 10
+    # 1e10 flops x 10 dispatches over 1000us (the module total, NOT
+    # the kept kernel's 600us) = 100 TFLOP/s -> exactly the peak
+    assert k["flops_frac"] == pytest.approx(1.0)
+    # bytes: 1e9 x 10 over 1ms = 10 TB/s -> 10x the 1000 GB/s peak
+    assert k["hbm_frac"] == pytest.approx(10.0)
+    assert k["bound"] == "hbm"
+    # a matched module whose fn never dispatched in the window gets
+    # no fractions (honest null), intensity still rides
+    rollup2 = {"kernels": [{"name": "dot.1", "module": "jit_step",
+                            "device_us": 600.0, "calls": 1}],
+               "modules": {"jit_step": 600.0}}
+    devprof.roofline_join(rollup2, stats=stats,
+                          dispatches={"other_fn": 5})
+    k2 = rollup2["kernels"][0]
+    assert "flops_frac" not in k2 and k2["bound"] is None
+    assert k2["intensity_flops_per_byte"] == pytest.approx(10.0)
 
 
 def test_introspect_counts_dispatches():

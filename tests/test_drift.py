@@ -573,7 +573,7 @@ def test_e2e_drift_drill(drill_artifact, tmp_path):
     cfg = ServingConfig(
         engine="numpy", report_every_s=0.3, latency_budget_ms=1.0,
         drift=DriftConfig(fast_window_s=0.5, slow_window_s=1.0,
-                          min_rows=100, psi_threshold=0.2,
+                          min_rows=300, psi_threshold=0.2,
                           # the drill shifts INPUTS; a score-KL alert
                           # would break the exactly-ONE contract
                           score_kl_threshold=100.0))

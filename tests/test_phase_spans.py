@@ -58,12 +58,16 @@ def datasets(small_job):
 def two_epochs(small_job, datasets):
     """(the `goodput` events of a two-epoch `train()`, gc.callbacks' length
     before it, after it, and the span path after it).  The job's batch is
-    the eval batch: `evaluate()` takes the larger of it and 4,096."""
+    the eval batch: `evaluate()` takes the larger of it and 4,096.  The
+    model is 2x256 wide so that an eval pass is ~0.4 s here: under six
+    xdist workers a thread descheduled between two spans has cost 14 ms,
+    a sixth of the ~85 ms pass of the 2x16 model and a thirtieth of this."""
     obs.reset_for_tests()
     journal = obs.RunJournal(None)
     obs.set_journal(journal)
     job = small_job.replace(
         data=dataclasses.replace(small_job.data, batch_size=EVAL_BATCH),
+        model=dataclasses.replace(small_job.model, hidden_nodes=(256, 256)),
         train=dataclasses.replace(small_job.train, epochs=2))
     hooks = len(gc.callbacks)
     try:

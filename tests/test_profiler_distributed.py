@@ -11,7 +11,7 @@ import jax
 from shifu_tpu.data.pipeline import TabularDataset, batch_iterator, prefetch_to_device
 from shifu_tpu.parallel import data_parallel_mesh
 from shifu_tpu.parallel import distributed as dist
-from shifu_tpu.train.profiler import StepTimer, maybe_trace
+from shifu_tpu.train.profiler import StepTimer
 
 
 def _ds(n=100, f=4):
@@ -68,16 +68,19 @@ def test_step_timer_summary():
     assert "input fraction" in t.console_line()
 
 
-def test_maybe_trace_noop():
-    with maybe_trace(None):
-        pass
-
-
 def test_trace_writes_profile(tmp_path):
+    """The one trace seam: `obs.trace_epochs=first` leaves the first
+    epoch's raw profiler files under `obs.trace_dir`, and an unscheduled
+    epoch writes nothing."""
     import jax.numpy as jnp
-    from shifu_tpu.train.profiler import trace
+    from shifu_tpu.config import ObsConfig
+    from shifu_tpu.obs import devprof
     d = str(tmp_path / "prof")
-    with trace(d):
+    dp = devprof.DeviceProfiler(ObsConfig(trace_epochs="first", trace_dir=d))
+    with dp.epoch_capture(1):
+        pass
+    assert not os.path.exists(d)
+    with dp.epoch_capture(0):
         jnp.ones((8, 8)).sum().block_until_ready()
     found = []
     for root, _, files in os.walk(d):

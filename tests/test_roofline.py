@@ -16,10 +16,7 @@ Two Pallas kernels move the two worst roofline rows:
 
 Both kernels gate on availability (`fused_available` /
 `ft_block_applicable` + kill-switch envs) and fall back to the existing
-XLA paths; the fallback-both-ways tests hold that contract.  The
-`perf`-marked smoke at the bottom wires tools/trace_diff.py
---fail-above over fused-run rollups so a silently-disengaged fusion
-fails loudly (satellite of ISSUE 11).
+XLA paths; the fallback-both-ways tests hold that contract.
 """
 
 import dataclasses
@@ -528,8 +525,8 @@ def test_roofline_join_classifies_new_kernels(monkeypatch):
     attribution via the epoch_step alias, obs/devprof.py)."""
     from shifu_tpu.obs import devprof
 
-    monkeypatch.setenv("SHIFU_TPU_PEAK_TFLOPS", "100.0")
-    monkeypatch.setenv(devprof.ENV_PEAK_HBM_GBPS, "1000.0")
+    monkeypatch.setattr(devprof, "peaks",
+                        lambda kind=None: (100.0, 1000.0))
     rollup = {"kernels": [
         {"name": "int8_matmul_dequant", "module": "jit_epoch_step",
          "device_us": 500.0, "calls": 10},
@@ -540,86 +537,3 @@ def test_roofline_join_classifies_new_kernels(monkeypatch):
     devprof.roofline_join(rollup, stats=stats)
     for k in rollup["kernels"]:
         assert k["bound"] in ("compute", "hbm"), k
-
-
-@pytest.mark.perf
-def test_trace_diff_fused_rollup_smoke(tmp_path, capsys):
-    """Satellite: tools/trace_diff.py --fail-above wired over fused-run
-    rollups on CPU interpret.  The fused kernel must actually be IN the
-    traced program (a silently-disengaged fusion fails here loudly), two
-    healthy fused windows (equal, fixed `device_us`) diff clean, and a
-    doctored 10x growth exits 1."""
-    import jax
-    import jax.numpy as jnp
-
-    import sys as _sys
-    import os as _os
-    _sys.path.insert(0, _os.path.join(_os.path.dirname(
-        _os.path.dirname(_os.path.abspath(__file__))), "tools"))
-    import trace_diff
-
-    spec_on = _ft_spec(fused_block="on")
-    spec_off = _ft_spec(fused_block="off")
-    p = {k: jnp.asarray(v) for k, v in _ft_params(spec_on).items()}
-    x = jnp.asarray(np.random.default_rng(0).standard_normal(
-        (8, 9, spec_on.token_dim)), jnp.float32)
-
-    from shifu_tpu.models.ft_transformer import _block_forward
-
-    def rollup_of(spec, tag):
-        fn = jax.jit(lambda p_, x_: _block_forward(p_, x_, spec))
-        # engagement check: the fused pallas call must be in the program
-        jaxpr = str(jax.make_jaxpr(
-            lambda p_, x_: _block_forward(p_, x_, spec))(p, x))
-        engaged = "ft_fused_block" in jaxpr
-        calls = 3
-        for _ in range(calls):
-            fn(p, x).block_until_ready()  # the real program runs
-        # a fixed reading, not this machine's clock: the test checks the
-        # diff tool, and a wall-clock pair taken under six xdist workers
-        # has read more than the 500 % apart that the PASS case allows
-        us = 1000.0 * calls
-        name = "ft_fused_block" if engaged else "transformer_block_unfused"
-        roll = {"window_us": round(us, 3),
-                "device_us_total": round(us, 3),
-                "kernels": [{"name": name, "module": "jit_epoch_step",
-                             "calls": calls, "device_us": round(us, 3),
-                             "fraction": 1.0}]}
-        path = tmp_path / f"rollup_{tag}.json"
-        path.write_text(json.dumps(roll))
-        return roll, str(path), engaged
-
-    roll_a, path_a, engaged_a = rollup_of(spec_on, "fused_a")
-    roll_b, path_b, engaged_b = rollup_of(spec_on, "fused_b")
-    _, path_off, engaged_off = rollup_of(spec_off, "unfused")
-    # the loud part: fused config MUST put the kernel in the program
-    assert engaged_a and engaged_b
-    assert not engaged_off
-
-    # two healthy fused windows: same kernel on both sides, wide limit
-    assert trace_diff.main([path_a, path_b, "--fail-above", "500",
-                            "--json"]) == 0
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["verdict"] == "PASS"
-    assert any(k["name"] == "ft_fused_block" for k in doc["kernels"])
-
-    # fused vs unfused: the kernel goes one-sided in the diff — the
-    # attribution trail a disengagement leaves
-    assert trace_diff.main([path_a, path_off, "--json"]) == 0
-    doc = json.loads(capsys.readouterr().out)
-    sides = {k["name"]: k for k in doc["kernels"]}
-    assert sides["ft_fused_block"]["b_us"] == 0
-
-    # doctored 10x growth on the fresh side: --fail-above trips
-    doctored = dict(roll_b)
-    doctored["device_us_total"] = roll_b["device_us_total"] * 10
-    doctored["kernels"] = [dict(roll_b["kernels"][0],
-                                device_us=roll_b["kernels"][0]["device_us"]
-                                * 10)]
-    path_x = tmp_path / "rollup_doctored.json"
-    path_x.write_text(json.dumps(doctored))
-    assert trace_diff.main([path_a, str(path_x), "--fail-above", "500",
-                            "--json"]) == 1
-    doc = json.loads(capsys.readouterr().out)
-    assert doc["verdict"] == "REGRESSION"
-    assert "ft_fused_block" in doc["blamed"]

@@ -493,6 +493,12 @@ def test_socket_server_end_to_end(artifacts):
             np.testing.assert_allclose(one, want[:1], atol=1e-6)
             stats = client.stats()
             assert stats["num_features"] == 12
+            # the daemon counts a batch after it has resolved the batch's
+            # futures, so a reply can reach the client before the count
+            deadline = time.monotonic() + 5.0
+            while stats["requests"] < 1 and time.monotonic() < deadline:
+                time.sleep(0.01)
+                stats = client.stats()
             assert stats["requests"] >= 1
             with pytest.raises(wire_mod.WireError,
                                match="expected 12 features"):

@@ -11,8 +11,7 @@ deliberately skewed journals flips FAIL -> PASS with the correction),
 happens-before nudging, incident reconstruction (failover chain
 lease_expiry -> failover -> promotion -> recovery, SLO episodes,
 degraded swaps, chaos root-cause hints), loadtest p99 trace exemplars,
-`tools/trace_diff.py --serving` SKIP/REGRESSION semantics, the tracing
-overhead guard (sample=0 journals NOTHING and costs ~nothing), and the
+the tracing overhead guard (sample=0 journals NOTHING and costs ~nothing), and the
 acceptance drill: a `local:2` fleet under open-loop load with a chaos
 `delay` inducing a hedged retry, rendered by `shifu-tpu timeline
 --json` in a subprocess with jax MASKED — the hedged trace shows both
@@ -330,69 +329,6 @@ def test_loadtest_inproc_reports_trace_exemplars(tmp_path):
     finally:
         d2.stop()
     assert "trace_exemplars" not in r2
-
-
-# ------------------------------------------------ trace_diff --serving
-
-
-def _load_trace_diff():
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "trace_diff", os.path.join(REPO, "tools", "trace_diff.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_trace_diff_serving_mode_skip_and_regression(tmp_path, capsys):
-    td = _load_trace_diff()
-    a, b = tmp_path / "a", tmp_path / "b"
-    _write_journal(str(a / "journal.jsonl"), [
-        {"ts": 1.0, "seq": 1, "kind": "loadtest_report", "p50_ms": 1.0,
-         "p99_ms": 3.0, "achieved_scores_per_sec": 1000.0,
-         "stages": {"queue": {"mean_ms": 0.5}}},
-        {"ts": 2.0, "seq": 2, "kind": "route_trace", "trace_id": "a" * 16,
-         "hops": [{"ms": 1.0, "outcome": "ok"}], "hedged": False,
-         "queue_ms": 0.2, "e2e_ms": 1.2, "outcome": "ok"},
-        {"ts": 3.0, "seq": 3, "kind": "cold_start", "engine": "aot",
-         "spawn_ms": 40.0, "promote_ms": 25.0, "live_compiles": 0},
-        # an engine leg the B side never drilled: must SKIP, not fail
-        {"ts": 3.5, "seq": 4, "kind": "cold_start", "engine": "jax",
-         "spawn_ms": 900.0, "promote_ms": 30.0, "live_compiles": 5},
-    ])
-    _write_journal(str(b / "journal.jsonl"), [
-        {"ts": 1.0, "seq": 1, "kind": "loadtest_report", "p50_ms": 2.0,
-         "p99_ms": 3.1, "achieved_scores_per_sec": 990.0,
-         # a stage the A side never measured: must SKIP, not fail
-         "stages": {"queue": {"mean_ms": 0.5},
-                    "device": {"mean_ms": 0.4}}},
-        {"ts": 2.0, "seq": 2, "kind": "cold_start", "engine": "aot",
-         "spawn_ms": 44.0, "promote_ms": 26.0, "live_compiles": 0},
-    ])
-    rc = td.main([str(a), str(b), "--serving", "--json",
-                  "--fail-above", "50"])
-    report = json.loads(capsys.readouterr().out)
-    assert rc == td.EXIT_REGRESSION
-    rows = {r["axis"]: r for r in report["axes"]}
-    assert rows["p50_ms"]["status"] == "REGRESSION"      # 2x growth
-    assert rows["p99_ms"]["status"] == "OK"              # within 50%
-    assert rows["stage.device.mean_ms"]["status"] == "SKIP"
-    assert rows["route.hop_ms_mean"]["status"] == "SKIP"  # B has none
-    # the cold-start drill's per-engine legs (ISSUE 19): aot on both
-    # sides diffs (10% growth, within the gate); jax only on A SKIPs
-    assert rows["cold_start.aot.spawn_ms"]["status"] == "OK"
-    assert rows["cold_start.aot.promote_ms"]["status"] == "OK"
-    assert rows["cold_start.jax.spawn_ms"]["status"] == "SKIP"
-    assert report["blamed"] == ["p50_ms"]
-    # without the gate the same diff PASSES (axes informational)
-    assert td.main([str(a), str(b), "--serving"]) == td.EXIT_PASS
-    capsys.readouterr()
-    # usage error on a journal with neither loadtest nor traces
-    _write_journal(str(tmp_path / "c" / "journal.jsonl"),
-                   [{"ts": 1.0, "seq": 1, "kind": "serve_start"}])
-    assert td.main([str(a), str(tmp_path / "c"), "--serving"]) == \
-        td.EXIT_USAGE
 
 
 # -------------------------------------------------- wire v2 + daemon hop
