@@ -127,7 +127,12 @@ class DataConfig:
     block_batches: int = 32
     # device-resident tier: when the training partition fits in this many
     # bytes of HBM, transfer it once and reorder batches on device each epoch
-    # (zero steady-state H2D).  0 disables.
+    # (zero steady-state H2D).  0 disables.  The budget covers the valid
+    # rows too: where their feature blocks fit what the train rows leave of
+    # it (single process), they are placed once as well and every epoch's
+    # eval is one dispatch over them (the journal's `eval_tier`:
+    # "resident"); where they do not fit, the train rows stay resident and
+    # eval streams its batches from the host every epoch ("streamed").
     device_resident_bytes: int = 2 << 30
     # parse-once columnar cache directory (data/cache.py); None defers to the
     # SHIFU_TPU_DATA_CACHE env var, empty-or-unset means no cache.

@@ -30,8 +30,8 @@ from ..parallel import mesh as mesh_lib
 from ..parallel import sharding as shard_lib
 from . import checkpoint as ckpt_lib
 from .optimizers import build_optimizer
-from .step import (make_epoch_scan_step, make_eval_step, make_train_step,
-                   split_readback)
+from .step import (make_epoch_scan_step, make_eval_step,
+                   make_resident_eval_step, make_train_step, split_readback)
 from .train_state import TrainState
 
 Console = Callable[[str], None]
@@ -355,28 +355,10 @@ def _accumulate_streaming(triples, score_sink=None) -> tuple[float, float]:
         return sm.weighted_error(), sm.auc()
 
 
-def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
-             eval_step, mesh: Optional[Mesh] = None,
-             batch_size: Optional[int] = None,
-             score_sink=None) -> tuple[float, float]:
-    """(weighted_error, auc) over the full dataset — every row counted, the
-    tail padded with zero-weight rows (reference evaluates the full valid set
-    per epoch, ssgd_monitor.py:281-284).
-
-    Multi-host: `ds` is this host's shard; every process contributes its
-    rows to global eval batches, runs the same number of collective steps
-    (shorter hosts feed zero-weight padding), and the gathered scores give
-    identical global metrics on every host.
-
-    Four hot spans split the pass on every topology — `prep` (slice, pad,
-    wire cast), `dispatch` (batch placement and the `eval_step` call: the
-    host's re-tiling, the H2D enqueue and the dispatch), `fetch` (the wait
-    for the oldest in-flight scores and their D2H) and `accumulate` —
-    nested under the caller's span (`epoch/eval/...` from `train`), each
-    opened and closed within one resumption of `triples`."""
-    multihost = jax.process_count() > 1 and mesh is not None
-    if not multihost and ds.num_rows == 0:
-        return float("nan"), float("nan")
+def _eval_batch_size(job: JobConfig, ds: pipe.TabularDataset,
+                     mesh: Optional[Mesh], multihost: bool,
+                     batch_size: Optional[int] = None) -> int:
+    """Rows of one eval batch (static shapes), on either eval tier."""
     # an eval batch holds at least 4,096 rows, which amortizes a tabular
     # batch's dispatch; a row that is itself that wide is a sequence of
     # thousands of positions, a batch's worth of work alone, and there the
@@ -399,6 +381,115 @@ def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
         n_micro = job.model.pipeline_microbatches or job.model.pipeline_stages
         quantum = n_micro * (mesh.size if mesh is not None else 1)
         bs = -(-bs // quantum) * quantum
+    return bs
+
+
+@dataclasses.dataclass
+class ResidentEval:
+    """The resident eval tier: the valid set's features where the train rows
+    are, placed once by `train()` as `(nvb, eval batch, F)` blocks in the
+    wire format (the tail padded with zero rows), and the one program that
+    scores them (`make_resident_eval_step`).  Targets and weights stay on
+    the host: `evaluate` reads them as views of the dataset's columns."""
+    features: jax.Array
+    step: Callable
+
+
+def place_resident_eval(ds: Optional[pipe.TabularDataset], job: JobConfig,
+                        mesh: Optional[Mesh],
+                        budget_bytes: int) -> Optional[ResidentEval]:
+    """The resident eval tier for `ds`, or None where its blocks do not
+    fit `budget_bytes` (what `data.device_resident_bytes` leaves beside the
+    train blocks): `evaluate` then streams the batches from the host."""
+    if ds is None or ds.num_rows == 0:
+        return None
+    bs = _eval_batch_size(job, ds, mesh, multihost=False)
+    nvb = pipe.num_batches(ds, bs, drop_remainder=False)
+    # the wire cast evaluate()'s streamed batches get, features only
+    wcast = pipe.wire_cast_fn(job.schema, job.data, job.model.compute_dtype)
+
+    def to_wire(f: np.ndarray) -> np.ndarray:
+        return f if wcast is None else wcast({"features": f})["features"]
+
+    wire_dtype = to_wire(ds.features[:0]).dtype
+    if nvb * bs * ds.num_features * wire_dtype.itemsize > budget_bytes:
+        return None
+    blocks = np.empty((nvb * bs, ds.num_features), wire_dtype)
+    blocks[:ds.num_rows] = to_wire(ds.features)
+    blocks[ds.num_rows:] = 0    # the tail's padding: rows no metric reads
+    blocks = blocks.reshape(nvb, bs, ds.num_features)
+    placed = (shard_lib.shard_blocks({"features": blocks}, mesh)["features"]
+              if mesh is not None else jax.device_put(blocks))
+    return ResidentEval(placed, make_resident_eval_step(job))
+
+
+def _resident_triples(state: TrainState, ds: pipe.TabularDataset,
+                      resident: ResidentEval):
+    """(scores, labels, weights) chunks of the resident eval pass, one a
+    block: the host's part of a pass is views of its own columns, one
+    dispatch, and the fetch of a few dense slices of scores, each
+    accumulated while the next is in flight."""
+    bs = resident.features.shape[1]
+    with obs.span("prep", journal=False):
+        # no gather, no copy, no padding: the padded tail's scores are
+        # dropped by row count below
+        tgt, wgt = ds.target[:, 0], ds.weight[:, 0]
+        views = [(tgt[lo:lo + bs], wgt[lo:lo + bs])
+                 for lo in range(0, ds.num_rows, bs)]
+    with obs.span("dispatch", journal=False):
+        slices = list(resident.step(state, resident.features))
+    obs.counter("eval_resident_passes_total",
+                "eval passes scored from the resident eval tier").inc()
+    chunks = iter(views)
+    for i in range(len(slices)):
+        with obs.span("fetch", journal=False):
+            # the wait for the device and the D2H: every slice's transfer
+            # is asked for at once, so that the later ones land while the
+            # earlier are accumulated; a slice's device buffer is released
+            # inside the phase
+            if i == 0:
+                for s in slices:
+                    s.copy_to_host_async()
+            host = np.asarray(slices[i])
+            slices[i] = None
+        for row, (t, w) in zip(host, chunks):
+            yield row[:t.shape[0]], t, w
+
+
+def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
+             eval_step, mesh: Optional[Mesh] = None,
+             batch_size: Optional[int] = None,
+             score_sink=None,
+             resident: Optional[ResidentEval] = None) -> tuple[float, float]:
+    """(weighted_error, auc) over the full dataset — every row counted, the
+    tail padded with zero-weight rows (reference evaluates the full valid set
+    per epoch, ssgd_monitor.py:281-284).
+
+    Multi-host: `ds` is this host's shard; every process contributes its
+    rows to global eval batches, runs the same number of collective steps
+    (shorter hosts feed zero-weight padding), and the gathered scores give
+    identical global metrics on every host.
+
+    Four hot spans split the pass on every topology — `prep` (slice, pad,
+    wire cast), `dispatch` (batch placement and the `eval_step` call: the
+    host's re-tiling, the H2D enqueue and the dispatch), `fetch` (the wait
+    for the oldest in-flight scores and their D2H) and `accumulate` —
+    nested under the caller's span (`epoch/eval/...` from `train`), each
+    opened and closed within one resumption of `triples`.
+
+    With `resident` (single process; `ds` is the set it was placed from)
+    the batches come from the device and not from the host: the same
+    forward, the same chunks into the same accumulation, and the same four
+    spans, which then time the views of the host's label and weight
+    columns, the pass's one dispatch, the wait for the device with the
+    D2H of the scores, and the accumulation."""
+    multihost = jax.process_count() > 1 and mesh is not None
+    if not multihost and ds.num_rows == 0:
+        return float("nan"), float("nan")
+    if resident is not None and not multihost:
+        return _accumulate_streaming(_resident_triples(state, ds, resident),
+                                     score_sink)
+    bs = _eval_batch_size(job, ds, mesh, multihost, batch_size)
     # same wire cast as training (model casts inputs to compute_dtype first,
     # so scores are bit-identical; H2D bytes halve)
     wcast = pipe.wire_cast_fn(job.schema, job.data, job.model.compute_dtype)
@@ -698,6 +789,7 @@ def train(job: JobConfig,
     steps_per_epoch = None
     use_resident = use_staged = False
     resident_blocks = None
+    resident_eval: Optional[ResidentEval] = None
     device_epoch_step = None
     train_step = None
     staged_put_fn = None
@@ -782,8 +874,8 @@ def train(job: JobConfig,
         # deciding from its local row count alone would diverge on shapes
         # and deadlock the collectives.
         nonlocal min_host_rows, bs, local_bs, steps_per_epoch, use_resident, \
-            use_staged, resident_blocks, device_epoch_step, train_step, \
-            staged_put_fn, staged_source, wcast
+            use_staged, resident_blocks, resident_eval, device_epoch_step, \
+            train_step, staged_put_fn, staged_source, wcast
         # dataset-wide compact-wire flags: u8 label / elided weight apply to
         # the loaded tiers only when EVERY row qualifies — and in multihost,
         # only when every HOST's shard qualifies (block formats are part of
@@ -881,7 +973,7 @@ def train(job: JobConfig,
                         and rows_for_blocks // local_bs > 0)
         use_staged = (job.data.staged and job.data.drop_remainder
                       and not use_resident)
-        resident_blocks = None
+        resident_blocks = resident_eval = None
         if local_sgd and not (use_resident or use_staged):
             raise ValueError(
                 "local_sgd_window (SAGN mode) needs the staged or "
@@ -889,6 +981,14 @@ def train(job: JobConfig,
                 "data.drop_remainder=True (local replicas are synchronized "
                 "by epoch scans, not per-batch dispatches)")
         if use_resident:
+            if jax.process_count() == 1:
+                # the resident eval tier: the same budget covers the valid
+                # rows, placed once beside the train blocks where both
+                # fit; where they do not, every epoch's evaluate() streams
+                # them
+                resident_eval = place_resident_eval(
+                    valid_ds, job, mesh,
+                    job.data.device_resident_bytes - ds_bytes)
             from .step import make_device_epoch_step, make_local_sgd_epoch_step
             device_epoch_step = (
                 make_local_sgd_epoch_step(job, mesh, with_order=True)
@@ -1363,12 +1463,14 @@ def train(job: JobConfig,
         epoch_time = time.perf_counter() - t0
 
         tv0 = time.perf_counter()
+        eval_tier = None  # which source of batches this epoch's eval read
         if epoch % job.train.eval_every_epochs == 0 or epoch == job.train.epochs - 1:
             score_sketch = obs.sketch.ScoreSketch()
+            eval_tier = "streamed" if resident_eval is None else "resident"
             with obs.span("epoch/eval", epoch=epoch):
                 valid_error, valid_auc = evaluate(
                     state, valid_ds, job, eval_step, mesh,
-                    score_sink=score_sketch.update)
+                    score_sink=score_sketch.update, resident=resident_eval)
         else:
             score_sketch = None
             valid_error, valid_auc = float("nan"), float("nan")
@@ -1586,7 +1688,7 @@ def train(job: JobConfig,
         eff = (hidden_s / (hidden_s + exposed_s)
                if hidden_s + exposed_s > 0 else None)
         obs.event("overlap_report", epoch=epoch, tier=tier,
-                  overlap=feeder is not None,
+                  eval_tier=eval_tier, overlap=feeder is not None,
                   prefetch_depth=(feeder.depth if feeder is not None
                                   else job.data.prefetch),
                   input_exposed_s=round(exposed_s, 6),

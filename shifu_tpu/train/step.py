@@ -485,21 +485,55 @@ def make_local_sgd_epoch_step(job: JobConfig, mesh: Optional[Mesh] = None,
     return call
 
 
-def make_eval_step(job: JobConfig) -> Callable[[TrainState, Batch], jax.Array]:
-    """Scores (sigmoid probabilities) for a batch — the eval forward pass.
-    Accepts int8 wire batches (same decode as training, so eval sees the
-    exact features the train step saw)."""
+def _make_forward(job: JobConfig) -> Callable[[TrainState, jax.Array], jax.Array]:
+    """The eval forward pass over one batch's features, wire decode
+    included: what both eval programs below compile."""
     decode = make_wire_decode(job)
 
-    def score(state: TrainState, batch: Batch) -> jax.Array:
-        feats = batch["features"]
+    def forward(state: TrainState, feats: jax.Array) -> jax.Array:
         if decode is not None:
             feats = decode(feats)
         logits = state.apply_fn({"params": state.params}, feats)
         return jax.nn.sigmoid(logits)
 
+    return forward
+
+
+def make_eval_step(job: JobConfig) -> Callable[[TrainState, Batch], jax.Array]:
+    """Scores (sigmoid probabilities) for a batch — the eval forward pass.
+    Accepts int8 wire batches (same decode as training, so eval sees the
+    exact features the train step saw)."""
+    forward = _make_forward(job)
+
+    def score(state: TrainState, batch: Batch) -> jax.Array:
+        return forward(state, batch["features"])
+
     from ..obs.introspect import instrument_jit
     return instrument_jit(score, "eval_step")
+
+
+#: the resident eval program hands its scores back in slices of about this
+#: many bytes, so that the host accumulates one while the next is in flight
+EVAL_SLICE_BYTES = 4 << 20
+
+
+def make_resident_eval_step(job: JobConfig):
+    """The eval forward pass over a valid set that lives on the device as
+    `(nvb, B, F)` feature blocks (train/loop.py's resident eval tier): ONE
+    program maps `make_eval_step`'s forward over the leading axis and hands
+    back head 0's scores dense, `(nvb, B)` cut along the leading axis into
+    a tuple of slices of about EVAL_SLICE_BYTES — one dispatch and a few
+    dense D2H transfers an epoch, where the streamed path pays a batch's
+    preparation, H2D and a `(B, 1)` fetch nvb times."""
+    forward = _make_forward(job)
+
+    def score_blocks(state: TrainState, features: jax.Array) -> tuple:
+        out = jax.lax.map(lambda f: forward(state, f)[:, 0], features)
+        per = max(1, EVAL_SLICE_BYTES // (out.shape[1] * out.dtype.itemsize))
+        return tuple(out[i:i + per] for i in range(0, out.shape[0], per))
+
+    from ..obs.introspect import instrument_jit
+    return instrument_jit(score_blocks, "resident_eval_step")
 
 
 def make_forward_fn(job: JobConfig,

@@ -54,19 +54,28 @@ def datasets(small_job):
             full.take(np.arange(512, at)))
 
 
-@pytest.fixture(scope="module")
-def two_epochs(small_job, datasets):
+@pytest.fixture(scope="module", params=["resident", "streamed"])
+def two_epochs(request, small_job, datasets):
     """(the `goodput` events of a two-epoch `train()`, gc.callbacks' length
-    before it, after it, and the span path after it).  The job's batch is
-    the eval batch: `evaluate()` takes the larger of it and 4,096.  The
-    model is 2x256 wide so that an eval pass is ~0.4 s here: under six
-    xdist workers a thread descheduled between two spans has cost 14 ms,
-    a sixth of the ~85 ms pass of the 2x16 model and a thirtieth of this."""
+    before it, after it, the span path after it, and the eval tier asked
+    for).  The job's batch is the eval batch: `evaluate()` takes the larger
+    of it and 4,096.  The model is 2x256 wide so that an eval pass is
+    ~0.4 s here: under six xdist workers a thread descheduled between two
+    spans has cost 14 ms, a sixth of the ~85 ms pass of the 2x16 model and
+    a thirtieth of this.  The train rows are resident in both cases;
+    "resident" leaves the budget at its default, beside which the valid
+    rows fit too (ISSUE 30), "streamed" states a budget that the train
+    rows alone fill, so that `evaluate()` streams the valid rows."""
     obs.reset_for_tests()
     journal = obs.RunJournal(None)
     obs.set_journal(journal)
+    data = dataclasses.replace(small_job.data, batch_size=EVAL_BATCH)
+    if request.param == "streamed":
+        data = dataclasses.replace(data, device_resident_bytes=sum(
+            a.nbytes for a in (datasets[2].features, datasets[2].target,
+                               datasets[2].weight)))
     job = small_job.replace(
-        data=dataclasses.replace(small_job.data, batch_size=EVAL_BATCH),
+        data=data,
         model=dataclasses.replace(small_job.model, hidden_nodes=(256, 256)),
         train=dataclasses.replace(small_job.train, epochs=2))
     hooks = len(gc.callbacks)
@@ -75,23 +84,31 @@ def two_epochs(small_job, datasets):
     finally:
         obs.set_journal(None)
     good = [r for r in journal.records if r["kind"] == "goodput"]
-    return good, hooks, len(gc.callbacks), obs.current_path()
+    tiers = [(r["tier"], r["eval_tier"]) for r in journal.records
+             if r["kind"] == "overlap_report"]
+    assert tiers == [("resident", request.param)] * 2
+    return good, hooks, len(gc.callbacks), obs.current_path(), request.param
 
 
 def test_every_goodput_event_carries_the_phases(two_epochs):
-    good = two_epochs[0]
+    good, eval_tier = two_epochs[0], two_epochs[4]
     assert [r["epoch"] for r in good] == [0, 1]
     for r in good:
         assert set(EVAL_PHASES) | {WAIT} <= set(r["phases"])
         for path, (seconds, count) in r["phases"].items():
             assert seconds >= 0 and count >= 1, path
         assert r["phases"][WAIT][1] == 1
-        # twenty batches: one more `prep` finds the set exhausted, one more
-        # `accumulate` is the final reduction
-        assert r["phases"]["epoch/eval/dispatch"][1] == 20
-        assert r["phases"]["epoch/eval/fetch"][1] == 20
-        assert r["phases"]["epoch/eval/prep"][1] == 21
-        assert r["phases"]["epoch/eval/accumulate"][1] == 21
+        counts = {p.rsplit("/", 1)[1]: r["phases"][p][1] for p in EVAL_PHASES}
+        if eval_tier == "streamed":
+            # twenty batches: one more `prep` finds the set exhausted, one
+            # more `accumulate` is the final reduction
+            assert counts == {"prep": 21, "dispatch": 20, "fetch": 20,
+                              "accumulate": 21}
+        else:
+            # one pass: the views, the one call, one slice of scores (twenty
+            # blocks of 64 KB), and the same twenty chunks accumulated
+            assert counts == {"prep": 1, "dispatch": 1, "fetch": 1,
+                              "accumulate": 21}
 
 
 def test_eval_phases_fill_the_eval_bucket(two_epochs):
@@ -119,7 +136,7 @@ def test_step_bucket_holds_the_device_wait(two_epochs):
 
 
 def test_train_leaves_no_hook_and_no_open_span(two_epochs):
-    _, before, after, path = two_epochs
+    _, before, after, path, _ = two_epochs
     assert after == before
     assert path == ""
 

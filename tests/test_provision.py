@@ -564,6 +564,15 @@ def test_kill_refuses_live_foreground_provision(fake_gcloud, tmp_path):
         env={**os.environ, "PYTHONPATH":
              REPO + os.pathsep + os.environ.get("PYTHONPATH", "")})
     try:
+        # Popen returns once the child's execve has closed the error pipe,
+        # which is before the kernel has set the new image's arguments:
+        # under load /proc/<pid>/cmdline still reads empty then, and the
+        # stand-in would not look like a dispatcher yet
+        import time
+        deadline = time.monotonic() + 30
+        while (not detach._is_our_job(live.pid, None)
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
         prov.write_marker(spec, str(out))
         # overwrite the recorded pid with the live stand-in's
         marker = prov.read_marker(str(out))

@@ -81,23 +81,16 @@ def _table_sized_in_loops(hlo: str, elements: int):
     return out
 
 
-def test_criteo_size_deepfm_epoch_fits_the_chip(one_chip, no_compile_cache,
-                                                monkeypatch):
-    """The epoch program of the benchmark's `deepfm_criteo.train_resident`
-    cell (13 numeric + 26 categorical fields of 1,300,000 buckets, latent
-    dim 10, 400x400x400, batch 8,192, 128 steps resident, Adadelta over
-    float32 tables: 4.5 GB of state) compiles for one v5e chip - it fits its
-    16 GB beside the copy of the state in the loop's own layout, which
-    leaves a few hundred MB - and its loop makes nothing table-sized by
-    casting, slicing, padding, reshaping or re-tiling a table."""
+def _criteo_deepfm():
+    """(job, fields, rows of its tables, batch) of the benchmark's
+    `deepfm_criteo.train_resident` cell: 13 numeric + 26 categorical fields
+    of 1,300,000 buckets, latent dim 10, 400x400x400, batch 8,192, Adadelta
+    over float32 tables (4.5 GB of state)."""
     from shifu_tpu.config import (DataConfig, JobConfig, ModelSpec,
                                   OptimizerConfig, TrainConfig)
     from shifu_tpu.data import synthetic
-    from shifu_tpu.ops import pallas_common
-    from shifu_tpu.train.loop import init_state
-    from shifu_tpu.train.step import make_device_epoch_step
 
-    n_num, n_cat, vocab, batch, steps = 13, 26, 1_300_000, 8192, 128
+    n_num, n_cat, vocab, batch = 13, 26, 1_300_000, 8192
     schema = synthetic.make_schema(num_features=n_num + n_cat,
                                    num_categorical=n_cat, vocab_size=vocab)
     job = JobConfig(
@@ -109,14 +102,32 @@ def test_criteo_size_deepfm_epoch_fits_the_chip(one_chip, no_compile_cache,
                           optimizer=OptimizerConfig(name="adadelta",
                                                     learning_rate=1.0)),
     ).validate()
+    return job, n_num + n_cat, n_cat * vocab, batch
+
+
+def test_criteo_size_deepfm_epoch_fits_the_chip(one_chip, no_compile_cache,
+                                                monkeypatch):
+    """The epoch program of the benchmark's `deepfm_criteo.train_resident`
+    cell (13 numeric + 26 categorical fields of 1,300,000 buckets, latent
+    dim 10, 400x400x400, batch 8,192, 128 steps resident, Adadelta over
+    float32 tables: 4.5 GB of state) compiles for one v5e chip - it fits its
+    16 GB beside the copy of the state in the loop's own layout, which
+    leaves a few hundred MB - and its loop makes nothing table-sized by
+    casting, slicing, padding, reshaping or re-tiling a table."""
+    from shifu_tpu.ops import pallas_common
+    from shifu_tpu.train.loop import init_state
+    from shifu_tpu.train.step import make_device_epoch_step
+
+    job, fields, table_elements, batch = _criteo_deepfm()
+    steps = 128
 
     def on_chip(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
 
     state = jax.tree_util.tree_map(
-        on_chip, jax.eval_shape(lambda: init_state(job, n_num + n_cat)))
+        on_chip, jax.eval_shape(lambda: init_state(job, fields)))
     blocks = {k: on_chip(jax.ShapeDtypeStruct((steps, batch, w), jnp.float32))
-              for k, w in (("features", n_num + n_cat), ("target", 1),
+              for k, w in (("features", fields), ("target", 1),
                            ("weight", 1))}
     order = on_chip(jax.ShapeDtypeStruct((steps,), jnp.int32))
     # the code asks `jax.default_backend()`, which is the CPU here: steer it
@@ -128,10 +139,44 @@ def test_criteo_size_deepfm_epoch_fits_the_chip(one_chip, no_compile_cache,
     monkeypatch.undo()
     hlo = lowered.compile().as_text()       # raises what the chip would
 
-    made = _table_sized_in_loops(hlo, n_cat * vocab)
+    made = _table_sized_in_loops(hlo, table_elements)
     assert made, "the reader found no table-sized instruction at all"
     bad = [(name, op) for name, op in made
            if op in ("convert", "slice", "reshape", "pad", "concatenate",
                      "transpose", "dynamic-slice")
            or name.startswith(("slice", "pad", "convert", "dynamic-slice"))]
     assert not bad, bad
+
+
+def test_criteo_size_deepfm_resident_eval_fits_the_chip(one_chip,
+                                                        no_compile_cache,
+                                                        monkeypatch):
+    """The resident eval program of the same cell (ISSUE 30: the forward
+    mapped over the 15 blocks of 8,192 that hold its 116,508 valid rows,
+    beside the 4.5 GB of state) compiles for one v5e chip.  It runs between
+    two epoch programs, so its temporaries do not add to theirs; and what
+    it makes table-sized - the copy of the k-dim table into the layout its
+    gather wants, once a batch on the streamed path - it makes outside the
+    loop over the blocks, once a pass."""
+    from shifu_tpu.ops import pallas_common
+    from shifu_tpu.train.loop import init_state
+    from shifu_tpu.train.step import make_resident_eval_step
+
+    job, fields, table_elements, batch = _criteo_deepfm()
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        on_chip, jax.eval_shape(lambda: init_state(job, fields)))
+    blocks = on_chip(jax.ShapeDtypeStruct((15, batch, fields), jnp.float32))
+    monkeypatch.setattr(pallas_common.jax, "default_backend", lambda: "tpu")
+    step = make_resident_eval_step(job)
+    lowered = step._fn.trace(state, blocks).lower(lowering_platforms=("tpu",))
+    monkeypatch.undo()
+    compiled = lowered.compile()            # raises what the chip would
+    hlo = compiled.as_text()
+    assert " while(" in hlo, "the blocks are no longer walked by a loop"
+    assert _table_sized_in_loops(hlo, table_elements) == []
+    (scores,) = jax.eval_shape(step._fn, state, blocks)
+    assert scores.shape == (15, batch) and scores.dtype == jnp.float32
