@@ -269,6 +269,9 @@ VALID_ACTIVATIONS = ("sigmoid", "tanh", "relu", "leakyrelu")
 #: model types the training path runs and nothing downstream of it does
 TRAINING_ONLY_MODEL_TYPES = ("block_stack",)
 
+#: the letters of `BlockStackSpec.pattern`, one a kind of block
+BLOCK_KINDS = "M*ELAF"
+
 
 def refuse_training_only(model_type: Any, what: str) -> None:
     """Export and serving refuse a training-only model by name, with what
@@ -287,13 +290,24 @@ class BlockStackSpec:
     scorer over fixed-width rows of token ids (every selected column a
     categorical column of one vocabulary, one column a position).
 
-    `pattern` has one letter a block: `M` a Mamba-2 mixer, `*` causal
-    grouped-query attention (no positional term), `E` a routed-expert
-    layer with one shared expert.  Each block is
-    `x <- x + mixer(RMSNorm(x))`; a final RMSNorm and the last position's
-    vector feed the shared `shifu_output_0` head.  The keys are the ones a
-    published `config.json` of the hybrid families carries, so a
-    configuration is copied, not translated.
+    `pattern` has one letter a block, and a letter names a kind of block:
+
+    - `M` a Mamba-2 mixer;
+    - `*` causal grouped-query attention with no positional term;
+    - `E` sigmoid-routed relu^2 experts beside one shared expert;
+    - `L` a gated-DeltaNet linear-attention mixer (the gated delta rule);
+    - `A` gated causal grouped-query attention: a per-head RMSNorm on q and
+      k, a rotary term on the first `partial_rotary_factor` of a head's
+      dims, a sigmoid output gate;
+    - `F` softmax-routed gated experts (`silu(W_g x) * W_u x`) beside one
+      shared expert behind a sigmoid gate.
+
+    Each block is `x <- x + mixer(RMSNorm(x))`; a final RMSNorm and the
+    last position's vector feed the shared `shifu_output_0` head.  The
+    norms of `L`, `A` and `F` blocks are zero-centred (`x_hat * (1 + w)`,
+    `w` from zero), and the final norm is of the last block's kind.  The
+    keys are the ones a published `config.json` of the hybrid families
+    carries, so a configuration is copied, not translated.
 
     `experts_held` / `first_expert_held` say which routed experts this
     program holds (expert parallelism's share): the router scores all
@@ -310,16 +324,27 @@ class BlockStackSpec:
     n_groups: int = 1
     ssm_state_size: int = 0
     conv_kernel: int = 4
-    # *: causal grouped-query attention
+    # * and A: causal grouped-query attention
     num_attention_heads: int = 0
     num_key_value_heads: int = 0
     head_dim: int = 0
-    # E: routed experts (relu^2, no gate) beside one shared expert
+    # A: the rotary term, on the first partial_rotary_factor of a head
+    partial_rotary_factor: float = 1.0
+    rope_theta: float = 10000.0
+    # L: the gated delta rule (value head h reads key head
+    # h // (linear_num_value_heads // linear_num_key_heads))
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4
+    # E and F: routed experts beside one shared expert
     n_routed_experts: int = 0
     num_experts_per_tok: int = 0
     moe_intermediate_size: int = 0
-    moe_shared_expert_intermediate_size: int = 0
-    routed_scaling_factor: float = 1.0
+    moe_shared_expert_intermediate_size: int = 0     # E
+    routed_scaling_factor: float = 1.0               # E
+    shared_expert_intermediate_size: int = 0         # F
     experts_held: int = 0           # 0 = all of them
     first_expert_held: int = 0
 
@@ -327,40 +352,57 @@ class BlockStackSpec:
     def held(self) -> int:
         return self.experts_held or self.n_routed_experts
 
+    @property
+    def rotary_dim(self) -> int:
+        return int(self.head_dim * self.partial_rotary_factor)
+
+    def _need(self, kind: str, *names: str) -> None:
+        if min(getattr(self, n) for n in names) <= 0:
+            raise ConfigError(f"block_stack: {kind} block needs "
+                              + ", ".join(names))
+
     def validate(self) -> None:
-        if not self.pattern or set(self.pattern) - set("ME*"):
+        if not self.pattern or set(self.pattern) - set(BLOCK_KINDS):
             raise ConfigError(
-                f"block_stack.pattern must be letters of 'M', 'E', '*': "
+                f"block_stack.pattern must be letters of {BLOCK_KINDS!r}: "
                 f"{self.pattern!r}")
         if self.hidden_size < 1:
             raise ConfigError("block_stack.hidden_size must be positive")
         if "M" in self.pattern:
-            if min(self.mamba_num_heads, self.mamba_head_dim,
-                   self.ssm_state_size, self.n_groups,
-                   self.conv_kernel) < 1:
-                raise ConfigError("block_stack: an 'M' block needs "
-                                  "mamba_num_heads, mamba_head_dim, n_groups, "
-                                  "ssm_state_size, conv_kernel")
+            self._need("an 'M'", "mamba_num_heads", "mamba_head_dim",
+                       "n_groups", "ssm_state_size", "conv_kernel")
             if self.mamba_num_heads % self.n_groups:
                 raise ConfigError("block_stack.mamba_num_heads must be a "
                                   "multiple of n_groups")
-        if "*" in self.pattern:
-            if min(self.num_attention_heads, self.num_key_value_heads,
-                   self.head_dim) < 1:
-                raise ConfigError("block_stack: a '*' block needs "
-                                  "num_attention_heads, num_key_value_heads, "
-                                  "head_dim")
+        if "*" in self.pattern or "A" in self.pattern:
+            self._need("a '*' or 'A'", "num_attention_heads",
+                       "num_key_value_heads", "head_dim")
             if self.num_attention_heads % self.num_key_value_heads:
                 raise ConfigError("block_stack.num_attention_heads must be "
                                   "a multiple of num_key_value_heads")
+        if "A" in self.pattern:
+            self._need("an 'A'", "partial_rotary_factor", "rope_theta")
+            if (self.partial_rotary_factor > 1.0 or self.rotary_dim < 2
+                    or self.rotary_dim % 2):
+                raise ConfigError(
+                    "block_stack: head_dim * partial_rotary_factor must be "
+                    f"an even count of a head's dims: {self.rotary_dim}")
+        if "L" in self.pattern:
+            self._need("an 'L'", "linear_num_key_heads",
+                       "linear_num_value_heads", "linear_key_head_dim",
+                       "linear_value_head_dim", "linear_conv_kernel_dim")
+            if self.linear_num_value_heads % self.linear_num_key_heads:
+                raise ConfigError("block_stack.linear_num_value_heads must "
+                                  "be a multiple of linear_num_key_heads")
         if "E" in self.pattern:
-            if min(self.n_routed_experts, self.num_experts_per_tok,
-                   self.moe_intermediate_size,
-                   self.moe_shared_expert_intermediate_size) < 1:
-                raise ConfigError("block_stack: an 'E' block needs "
-                                  "n_routed_experts, num_experts_per_tok, "
-                                  "moe_intermediate_size, "
-                                  "moe_shared_expert_intermediate_size")
+            self._need("an 'E'", "n_routed_experts", "num_experts_per_tok",
+                       "moe_intermediate_size",
+                       "moe_shared_expert_intermediate_size")
+        if "F" in self.pattern:
+            self._need("an 'F'", "n_routed_experts", "num_experts_per_tok",
+                       "moe_intermediate_size",
+                       "shared_expert_intermediate_size")
+        if "E" in self.pattern or "F" in self.pattern:
             if self.num_experts_per_tok > self.n_routed_experts:
                 raise ConfigError("block_stack.num_experts_per_tok exceeds "
                                   "n_routed_experts")

@@ -1,8 +1,10 @@
 """A routed expert layer told which experts it holds (expert parallelism's
-share of a layer): sigmoid-score top-k routing over all the experts, and the
-held experts' part of the result, with no token dropped whatever the load.
+share of a layer): top-k routing over all the experts, and the held experts'
+part of the result, with no token dropped whatever the load.
 
-`route_topk` scores every expert and picks k a token.  `plan_dispatch` lays
+`route_topk` (sigmoid scores) and `route_softmax_topk` (softmax
+probabilities, renormalised over the chosen) score every expert and pick k a
+token.  `plan_dispatch` lays
 the (token, slot) choices that fell on held experts out in rows grouped by
 expert, each group padded to whole blocks of `block_rows`; it is sized for
 the worst case (every choice on a held expert), so nothing is ever dropped.
@@ -11,7 +13,9 @@ count is the load, not the worst case - gathers a block's tokens, runs them
 through that block's expert (`W2 relu(W1 x)^2`) and scatter-adds the weighted
 result to the tokens' rows.  Its backward pass walks the same blocks again
 (recomputing the hidden activation) and accumulates the experts' weight
-gradients in float32.  Plain XLA: no kernel, no capacity factor.
+gradients in float32.  `routed_gated_mlp` is the same walk through gated
+experts (`W_down (silu(W_gate x) * W_up x)`, three matrices an expert).
+Plain XLA: no kernel, no capacity factor.
 """
 
 from __future__ import annotations
@@ -29,6 +33,17 @@ def route_topk(logits: jax.Array, k: int, scale: float):
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     chosen, experts = jax.lax.top_k(s, k)
     weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return experts.astype(jnp.int32), weights
+
+
+def route_softmax_topk(logits: jax.Array, k: int):
+    """Softmax routing with the chosen weights renormalised
+    (`norm_topk_prob`): logits (T, E) float32 -> (experts (T, k) int32,
+    weights (T, k) float32, `p / sum(p)` over the k largest of
+    `p = softmax(logits)`)."""
+    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    chosen, experts = jax.lax.top_k(p, k)
+    weights = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
     return experts.astype(jnp.int32), weights
 
 
@@ -158,3 +173,86 @@ def _routed_bwd(block_rows, res, g):
 
 
 routed_relu2_mlp.defvjp(_routed_fwd, _routed_bwd)
+
+
+def _gated_hidden(xb, wg_e, wu_e):
+    hg = jnp.dot(xb, wg_e, preferred_element_type=jnp.float32)
+    hu = jnp.dot(xb, wu_e, preferred_element_type=jnp.float32)
+    return hg, hu, (jax.nn.silu(hg) * hu).astype(xb.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8,))
+def routed_gated_mlp(x, w_gate, w_up, w_down, row_weight, row_token,
+                     block_expert, live_blocks, block_rows: int):
+    """sum over a token's choices on held experts of `w * W_down_e
+    (silu(W_gate_e x) * W_up_e x)`.  x (T, H) in the compute dtype; w_gate,
+    w_up (E, H, F), w_down (E, F, H); the rest as `routed_relu2_mlp` takes
+    it.  Returns (T, H) float32."""
+    return _gated_fwd(x, w_gate, w_up, w_down, row_weight, row_token,
+                      block_expert, live_blocks, block_rows)[0]
+
+
+def _gated_fwd(x, w_gate, w_up, w_down, row_weight, row_token, block_expert,
+               live_blocks, block_rows):
+    cdt = x.dtype
+    wgc, wuc, wdc = (w.astype(cdt) for w in (w_gate, w_up, w_down))
+
+    def body(i, out):
+        tok, rw, e = _block(i, block_rows, row_token, row_weight,
+                            block_expert)
+        xb = x.at[tok].get(mode="fill", fill_value=0)
+        _, _, a = _gated_hidden(xb, wgc[e], wuc[e])
+        y = jnp.dot(a, wdc[e], preferred_element_type=jnp.float32)
+        return out.at[tok].add(rw[:, None] * y, mode="drop")
+
+    out = jax.lax.fori_loop(0, live_blocks, body,
+                            jnp.zeros(x.shape, jnp.float32))
+    return out, (x, w_gate, w_up, w_down, row_weight, row_token,
+                 block_expert, live_blocks)
+
+
+def _gated_bwd(block_rows, res, g):
+    (x, w_gate, w_up, w_down, row_weight, row_token, block_expert,
+     live_blocks) = res
+    cdt = x.dtype
+    wgc, wuc, wdc = (w.astype(cdt) for w in (w_gate, w_up, w_down))
+    g = g.astype(cdt)
+
+    def body(i, carry):
+        dx, dwg, dwu, dwd, drw = carry
+        tok, rw, e = _block(i, block_rows, row_token, row_weight,
+                            block_expert)
+        xb = x.at[tok].get(mode="fill", fill_value=0)
+        gb = g.at[tok].get(mode="fill", fill_value=0)
+        hg, hu, a = _gated_hidden(xb, wgc[e], wuc[e])
+        y = jnp.dot(a, wdc[e], preferred_element_type=jnp.float32)
+        drw = jax.lax.dynamic_update_slice(
+            drw, jnp.sum(gb.astype(jnp.float32) * y, axis=-1),
+            (i * block_rows,))
+        gy = (rw[:, None] * gb).astype(cdt)
+        da = jnp.dot(gy, wdc[e].T, preferred_element_type=jnp.float32)
+        sig = jax.nn.sigmoid(hg)
+        dhu = (da * hg * sig).astype(cdt)
+        dhg = (da * hu * sig * (1.0 + hg * (1.0 - sig))).astype(cdt)
+        dxb = (jnp.dot(dhg, wgc[e].T, preferred_element_type=jnp.float32)
+               + jnp.dot(dhu, wuc[e].T, preferred_element_type=jnp.float32))
+        dwd = dwd.at[e].add(jnp.dot(a.T, gy,
+                                    preferred_element_type=jnp.float32))
+        dwg = dwg.at[e].add(jnp.dot(xb.T, dhg,
+                                    preferred_element_type=jnp.float32))
+        dwu = dwu.at[e].add(jnp.dot(xb.T, dhu,
+                                    preferred_element_type=jnp.float32))
+        return dx.at[tok].add(dxb, mode="drop"), dwg, dwu, dwd, drw
+
+    dx, dwg, dwu, dwd, drw = jax.lax.fori_loop(
+        0, live_blocks, body,
+        (jnp.zeros(x.shape, jnp.float32),
+         jnp.zeros(w_gate.shape, jnp.float32),
+         jnp.zeros(w_up.shape, jnp.float32),
+         jnp.zeros(w_down.shape, jnp.float32),
+         jnp.zeros(row_weight.shape, jnp.float32)))
+    return (dx.astype(cdt), dwg.astype(w_gate.dtype), dwu.astype(w_up.dtype),
+            dwd.astype(w_down.dtype), drw, None, None, None)
+
+
+routed_gated_mlp.defvjp(_gated_fwd, _gated_bwd)

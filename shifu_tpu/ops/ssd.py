@@ -20,6 +20,8 @@ row's inputs, not its (heads, chunks, Q, Q) decay matrices.
 
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -28,16 +30,18 @@ import jax.numpy as jnp
 CHUNK = 128
 
 
-def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
+def causal_conv1d(x: jax.Array, w: jax.Array,
+                  b: Optional[jax.Array] = None) -> jax.Array:
     """Depthwise causal convolution along axis 1: `y_t = b + sum_j w[j]
     x_{t-(K-1)+j}`, positions before the row's start zero.  x (B, T, C),
-    w (K, C), b (C,)."""
+    w (K, C), b (C,) or None where the convolution has no bias."""
     k = w.shape[0]
     t = x.shape[1]
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
-    y = b.astype(x.dtype)
+    y = None if b is None else b.astype(x.dtype)
     for j in range(k):
-        y = y + xp[:, j:j + t, :] * w[j].astype(x.dtype)
+        term = xp[:, j:j + t, :] * w[j].astype(x.dtype)
+        y = term if y is None else y + term
     return y
 
 

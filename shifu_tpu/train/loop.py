@@ -318,12 +318,19 @@ def _tree_sum(acc, new):
 
 def _moe_layers(counters: dict) -> list[dict]:
     """The `moe` journal event's layers from the model's summed counters
-    (models/block_stack.ExpertsBlock), a leading axis an E layer."""
+    (models/block_stack._dispatch_counters), a leading axis an expert
+    layer.  `block_rows`, the rows a block of the layer's dispatch holds,
+    is the same every step and is carried, not summed: the summed
+    `live_rows` over the summed `live_blocks`."""
     per = np.asarray(counters["tokens_per_expert"])
-    return [{"tokens_per_expert": [int(v) for v in per[i]],
-             **{k: int(np.asarray(counters[k])[i]) for k in
-                ("routed_slots", "held_slots", "tokens_dropped")}}
-            for i in range(per.shape[0])]
+    layers = []
+    for i in range(per.shape[0]):
+        n = {k: int(np.asarray(counters[k])[i]) for k in
+             ("routed_slots", "held_slots", "tokens_dropped", "live_blocks",
+              "live_rows")}
+        n["block_rows"] = n.pop("live_rows") // max(n["live_blocks"], 1)
+        layers.append({"tokens_per_expert": [int(v) for v in per[i]], **n})
+    return layers
 
 
 def _accumulate_streaming(triples, score_sink=None) -> tuple[float, float]:
@@ -1657,7 +1664,7 @@ def train(job: JobConfig,
                 epoch, time.perf_counter() - t0 + ingest_wall_s)
         if "moe" in step_counters:
             # where the routed expert layers' tokens went this epoch, one
-            # entry an E layer: summed on the device, read with the loss
+            # entry an expert layer: summed on the device, read with the loss
             obs.event("moe", epoch=epoch, layers=_moe_layers(
                 step_counters["moe"]))
 

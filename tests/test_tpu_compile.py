@@ -180,3 +180,45 @@ def test_criteo_size_deepfm_resident_eval_fits_the_chip(one_chip,
     assert _table_sized_in_loops(hlo, table_elements) == []
     (scores,) = jax.eval_shape(step._fn, state, blocks)
     assert scores.shape == (15, batch) and scores.dtype == jnp.float32
+
+
+def test_qwen3_next_ep16_epoch_fits_the_chip(one_chip, no_compile_cache,
+                                             monkeypatch):
+    """The epoch program of the benchmark's `qwen3_next_ep16.train_sequences`
+    cell (the blocks `LFLFLFAF` at Qwen3-Next-80B-A3B's published widths, 32
+    of 512 experts held, 586.8 M parameters with both Adadelta slots: 7.04 GB
+    of arguments; 8 steps of 8 rows of 4,096 positions, a block
+    rematerialized at a time) compiles for one v5e chip, the delta rule's
+    triangular solve and the routed experts' walk among it: its arguments
+    are carried in place and they and its temporaries fit the chip's 16 GiB
+    together."""
+    from benchmarks import harness
+    from shifu_tpu.ops import pallas_common
+    from shifu_tpu.train.loop import init_state
+    from shifu_tpu.train.step import make_device_epoch_step
+
+    _, _, config, _, params, driver = harness.load_cell(
+        "qwen3_next_ep16.train_sequences")
+    job = driver.build_job(config, params, 1, 1)
+    batch, width = config["batch_size"], config["num_categorical"]
+    steps = params["train_rows"] // batch
+    assert (batch, width, steps) == (8, 4096, 8)
+
+    def on_chip(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+
+    state = jax.tree_util.tree_map(
+        on_chip, jax.eval_shape(lambda: init_state(job, width)))
+    blocks = {k: on_chip(jax.ShapeDtypeStruct((steps, batch, w), jnp.float32))
+              for k, w in (("features", width), ("target", 1), ("weight", 1))}
+    order = on_chip(jax.ShapeDtypeStruct((steps,), jnp.int32))
+    monkeypatch.setattr(pallas_common.jax, "default_backend", lambda: "tpu")
+    step = make_device_epoch_step(job, None)
+    lowered = step._fn.trace(state, blocks, order).lower(
+        lowering_platforms=("tpu",))
+    monkeypatch.undo()
+    memory = lowered.compile().memory_analysis()   # raises what the chip would
+    arguments = memory.argument_size_in_bytes
+    assert 7.0e9 < arguments < 7.1e9
+    assert memory.alias_size_in_bytes > 0.999 * memory.output_size_in_bytes
+    assert arguments + memory.temp_size_in_bytes < 16 * 2 ** 30
