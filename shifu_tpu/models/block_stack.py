@@ -18,9 +18,9 @@ beside one shared expert behind a sigmoid gate.  The norms of `L`, `A` and
 `F` blocks are zero-centred, `x_hat * (1 + w)` with `w` from zero; the final
 norm is of the last block's kind.  The residual stream stays in the compute
 dtype; router logits, norms' statistics, the scans' decays, the delta rule's
-triangular solve, the rotary term and the softmax are float32.  A row is a
-fixed-width sequence: every selected column is one position's token id, all
-of one vocabulary.
+chunk systems and their inverse, the rotary term and the softmax are
+float32.  A row is a fixed-width sequence: every selected column is one
+position's token id, all of one vocabulary.
 
 Only the last position reaches the head, and an expert block mixes nothing
 along the sequence: the blocks that follow the last sequence mixer run on

@@ -3,13 +3,14 @@ ops/gated_delta.py, ops/routed_experts.py) against the plain reference
 (benchmarks/reference/qwen3_next.py), at a small size on the CPU.
 
 Tolerances, and why each: the program in float32 and the reference compute
-the same sums in another order (the delta rule by chunks through a
-triangular solve, attention by query blocks, the experts by dispatched
-blocks), so they differ by float32 rounding: 2e-4 relative on scores, loss
-and three optimizer steps, 2e-3 of a leaf's norm on gradients (a sum over 80
-positions and the solve amplify the last bits).  The program in bfloat16,
-the precision below, misses the scores' tolerance at least five times over,
-which is what makes it a test of the precision the configuration states.
+the same sums in another order (the delta rule by chunks through the
+inverse of a triangular system, attention by query blocks, the experts by
+dispatched blocks), so they differ by float32 rounding: 2e-4 relative on
+scores, loss and three optimizer steps, 2e-3 of a leaf's norm on gradients
+(a sum over 80 positions and the inverse amplify the last bits).  The
+program in bfloat16, the precision below, misses the scores' tolerance at
+least five times over, which is what makes it a test of the precision the
+configuration states.
 """
 
 import functools
