@@ -270,7 +270,7 @@ VALID_ACTIVATIONS = ("sigmoid", "tanh", "relu", "leakyrelu")
 TRAINING_ONLY_MODEL_TYPES = ("block_stack",)
 
 #: the letters of `BlockStackSpec.pattern`, one a kind of block
-BLOCK_KINDS = "M*ELAF"
+BLOCK_KINDS = "M*ELAFCDG"
 
 
 def refuse_training_only(model_type: Any, what: str) -> None:
@@ -300,7 +300,15 @@ class BlockStackSpec:
       k, a rotary term on the first `partial_rotary_factor` of a head's
       dims, a sigmoid output gate;
     - `F` softmax-routed gated experts (`silu(W_g x) * W_u x`) beside one
-      shared expert behind a sigmoid gate.
+      shared expert behind a sigmoid gate;
+    - `C` causal multi-head latent attention: q and the keys and values
+      through low-rank projections with an RMSNorm inside each, a head's
+      key of `qk_nope_head_dim` dims of its own and `qk_rope_head_dim`
+      rotary dims (pairs of neighbours turn together) that all heads
+      share, values of `v_head_dim`;
+    - `D` a dense gated MLP at `intermediate_size`;
+    - `G` sigmoid-routed gated experts (`E`'s router, `F`'s experts) beside
+      `n_shared_experts` shared experts' width with no gate.
 
     Each block is `x <- x + mixer(RMSNorm(x))`; a final RMSNorm and the
     last position's vector feed the shared `shifu_output_0` head.  The
@@ -330,7 +338,15 @@ class BlockStackSpec:
     head_dim: int = 0
     # A: the rotary term, on the first partial_rotary_factor of a head
     partial_rotary_factor: float = 1.0
-    rope_theta: float = 10000.0
+    rope_theta: float = 10000.0                      # A and C
+    # C: latent attention over num_attention_heads heads
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # D: the dense gated MLP
+    intermediate_size: int = 0
     # L: the gated delta rule (value head h reads key head
     # h // (linear_num_value_heads // linear_num_key_heads))
     linear_num_key_heads: int = 0
@@ -338,13 +354,14 @@ class BlockStackSpec:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4
-    # E and F: routed experts beside one shared expert
+    # E, F and G: routed experts beside one shared expert
     n_routed_experts: int = 0
     num_experts_per_tok: int = 0
     moe_intermediate_size: int = 0
     moe_shared_expert_intermediate_size: int = 0     # E
-    routed_scaling_factor: float = 1.0               # E
+    routed_scaling_factor: float = 1.0               # E and G
     shared_expert_intermediate_size: int = 0         # F
+    n_shared_experts: int = 0       # G: of moe_intermediate_size each
     experts_held: int = 0           # 0 = all of them
     first_expert_held: int = 0
 
@@ -402,7 +419,19 @@ class BlockStackSpec:
             self._need("an 'F'", "n_routed_experts", "num_experts_per_tok",
                        "moe_intermediate_size",
                        "shared_expert_intermediate_size")
-        if "E" in self.pattern or "F" in self.pattern:
+        if "C" in self.pattern:
+            self._need("a 'C'", "num_attention_heads", "q_lora_rank",
+                       "kv_lora_rank", "qk_nope_head_dim",
+                       "qk_rope_head_dim", "v_head_dim", "rope_theta")
+            if self.qk_rope_head_dim % 2:
+                raise ConfigError("block_stack.qk_rope_head_dim must be "
+                                  "even: its dims turn in pairs")
+        if "D" in self.pattern:
+            self._need("a 'D'", "intermediate_size")
+        if "G" in self.pattern:
+            self._need("a 'G'", "n_routed_experts", "num_experts_per_tok",
+                       "moe_intermediate_size", "n_shared_experts")
+        if set(self.pattern) & set("EFG"):
             if self.num_experts_per_tok > self.n_routed_experts:
                 raise ConfigError("block_stack.num_experts_per_tok exceeds "
                                   "n_routed_experts")

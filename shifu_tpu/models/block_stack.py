@@ -1,5 +1,5 @@
 """A causal sequence scorer built from a layer-pattern string: pre-norm
-residual blocks of six kinds over one token table, the last position's
+residual blocks of nine kinds over one token table, the last position's
 vector into the `shifu_output_0` head every model shares.
 
     x_0 = table[ids]                                   (B, T, hidden)
@@ -12,11 +12,16 @@ mixer (ops/ssd.py), `*` causal grouped-query attention with no positional
 term (ops/attention.causal_gqa), `L` a gated-DeltaNet linear-attention mixer
 (ops/gated_delta.py), `A` gated causal grouped-query attention (a per-head
 RMSNorm on q and k, a partial rotary term, a sigmoid output gate, then
-`causal_gqa`).  Expert layers (ops/routed_experts.py): `E` sigmoid-routed
-relu^2 experts beside one shared expert, `F` softmax-routed gated experts
-beside one shared expert behind a sigmoid gate.  The norms of `L`, `A` and
-`F` blocks are zero-centred, `x_hat * (1 + w)` with `w` from zero; the final
-norm is of the last block's kind.  The residual stream stays in the compute
+`causal_gqa`), `C` causal multi-head latent attention (q, and the keys and
+values, through low-rank projections with an RMSNorm inside each; a head's
+key is dims of its own plus rotary dims all heads share, its values of
+another width: ops/attention.causal_latent_attention).  Expert layers
+(ops/routed_experts.py): `E` sigmoid-routed relu^2 experts beside one shared
+expert, `F` softmax-routed gated experts beside one shared expert behind a
+sigmoid gate, `G` sigmoid-routed gated experts beside a shared expert with
+no gate.  `D` is a dense gated MLP.  The norms of `L`, `A` and `F` blocks
+are zero-centred, `x_hat * (1 + w)` with `w` from zero; the final norm is of
+the last block's kind.  The residual stream stays in the compute
 dtype; router logits, norms' statistics, the scans' decays, the delta rule's
 chunk systems and their inverse, the rotary term and the softmax are
 float32.  A row is a fixed-width sequence: every selected column is one
@@ -41,7 +46,7 @@ import jax
 import jax.numpy as jnp
 
 from ..config.schema import BlockStackSpec, ModelSpec
-from ..ops.attention import causal_gqa
+from ..ops.attention import causal_gqa, causal_latent_attention
 from ..ops.gated_delta import gated_delta_rule
 from ..ops.routed_experts import (default_block_rows, plan_dispatch,
                                   route_softmax_topk, route_topk,
@@ -53,8 +58,8 @@ INIT_STD = 0.02
 #: of the `M`, `*` and `E` blocks alone: the output projections' initial
 #: scale, `rescale_prenorm_residual` at the depth of the published stack
 #: they come from, and the initial range of the Mamba-2 step, its
-#: `time_step_min` / `_max` / `_floor`.  The `L`, `A` and `F` blocks draw
-#: every projection at INIT_STD, as their family does
+#: `time_step_min` / `_max` / `_floor`.  The other kinds draw every
+#: projection at INIT_STD, as their families do
 RESCALE_LAYERS = 52
 OUT_STD = INIT_STD / RESCALE_LAYERS ** 0.5
 TIME_STEP_MIN, TIME_STEP_MAX, TIME_STEP_FLOOR = 0.001, 0.1, 1e-4
@@ -117,6 +122,23 @@ def rotate(x, theta: float, rotary_dim: int):
                            axis=-1).astype(x.dtype)
 
 
+def rotate_pairs(x, theta: float):
+    """The rotary term on every dim of a head, neighbours together
+    (`rope_interleave`), position = index along axis 1: x (B, T, H, D) ->
+    the same shape and dtype.  Dims 2i and 2i + 1 turn together by
+    `t * theta**(-2i / D)`, in float32."""
+    d = x.shape[-1]
+    dim = jnp.arange(d)
+    inv = theta ** (-(dim // 2).astype(jnp.float32) * 2.0 / d)
+    angle = jnp.arange(x.shape[1], dtype=jnp.float32)[:, None] * inv
+    cos, sin = jnp.cos(angle)[:, None, :], jnp.sin(angle)[:, None, :]
+    xf = x.astype(jnp.float32)
+    # a dim's partner in its pair, signed: -x[2i+1] at 2i, x[2i] at 2i+1
+    partner = jnp.where(dim % 2 == 0, -jnp.roll(xf, -1, axis=-1),
+                        jnp.roll(xf, 1, axis=-1))
+    return (xf * cos + partner * sin).astype(x.dtype)
+
+
 def relu2(x):
     r = jax.nn.relu(x)
     return r * r
@@ -153,6 +175,42 @@ class _Block(nn.Module):
     def dot(self, x, w):
         return jnp.dot(x, w.astype(self.cdt),
                        preferred_element_type=jnp.float32).astype(self.cdt)
+
+    @nn.nowrap
+    def gated_mlp(self, x, w_gate, w_up, w_down):
+        """`(silu(x W_gate) * x W_up) W_down`, the gate in float32."""
+        f32 = jnp.float32
+        hidden = (jax.nn.silu(self.dot(x, w_gate).astype(f32))
+                  * self.dot(x, w_up).astype(f32)).astype(self.cdt)
+        return self.dot(hidden, w_down)
+
+    @nn.nowrap    # no scope of its own: the parts stay `block<i>/moe/...`
+    def experts_layer(self, x, tokens, w_router, route, experts, shared):
+        """What the expert kinds share: (x + the layer's output, what
+        `_dispatch_counters` counts of this call).  `tokens` is the normed
+        x, a row a token; `route(logits)` gives (the chosen experts, their
+        weights); `experts(row_weight, row_token, block_expert, live_blocks,
+        block_rows)` the held experts' part and `shared(tokens)` the shared
+        expert's, both float32."""
+        bs, f32 = self.bs, jnp.float32
+        k = bs.num_experts_per_tok
+        with jax.named_scope("moe"):
+            with jax.named_scope("router"):
+                logits = jnp.dot(tokens.astype(f32), w_router.astype(f32),
+                                 precision=jax.lax.Precision.HIGHEST)
+                chosen, weights = route(logits)
+            with jax.named_scope("dispatch"):
+                plan, rows, row_weight, row_token = _dispatch(bs, chosen,
+                                                              weights)
+            with jax.named_scope("experts"):
+                routed = experts(row_weight, row_token, plan["block_expert"],
+                                 plan["live_blocks"], rows)
+            with jax.named_scope("shared"):
+                beside = shared(tokens)
+            with jax.named_scope("combine"):
+                y = (beside + routed).astype(self.cdt)
+        counters = _dispatch_counters(plan, tokens, k, rows)
+        return x + y.reshape(x.shape), counters
 
 
 class MambaBlock(_Block):
@@ -232,7 +290,7 @@ class ExpertsBlock(_Block):
 
     @nn.compact
     def __call__(self, x):
-        bs, cdt = self.bs, self.cdt
+        bs = self.bs
         hidden, f, fs, held = (bs.hidden_size, bs.moe_intermediate_size,
                                bs.moe_shared_expert_intermediate_size,
                                bs.held)
@@ -245,27 +303,13 @@ class ExpertsBlock(_Block):
         s1 = self.weight("shared/w1", (hidden, fs), init)
         s2 = self.weight("shared/w2", (fs, hidden), out_init)
         tokens = h.reshape(-1, hidden)
-        k = bs.num_experts_per_tok
-        with jax.named_scope("moe"):
-            with jax.named_scope("router"):
-                logits = jnp.dot(tokens.astype(jnp.float32),
-                                 w_r.astype(jnp.float32),
-                                 precision=jax.lax.Precision.HIGHEST)
-                experts, weights = route_topk(logits, k,
-                                              bs.routed_scaling_factor)
-            with jax.named_scope("dispatch"):
-                plan, rows, row_weight, row_token = _dispatch(bs, experts,
-                                                              weights)
-            with jax.named_scope("experts"):
-                routed = routed_relu2_mlp(
-                    tokens, w1, w2, row_weight, row_token,
-                    plan["block_expert"], plan["live_blocks"], rows)
-            with jax.named_scope("shared"):
-                shared = self.dot(relu2(self.dot(tokens, s1)), s2)
-            with jax.named_scope("combine"):
-                y = (shared.astype(jnp.float32) + routed).astype(cdt)
-        counters = _dispatch_counters(plan, tokens, k, rows)
-        return x + y.reshape(x.shape), counters
+        return self.experts_layer(
+            x, tokens, w_r,
+            lambda logits: route_topk(logits, bs.num_experts_per_tok,
+                                      bs.routed_scaling_factor),
+            lambda *plan: routed_relu2_mlp(tokens, w1, w2, *plan),
+            lambda t: self.dot(relu2(self.dot(t, s1)), s2)
+            .astype(jnp.float32))
 
 
 def _dispatch(bs: BlockStackSpec, experts, weights):
@@ -393,7 +437,7 @@ class GatedExpertsBlock(_Block):
 
     @nn.compact
     def __call__(self, x):
-        bs, cdt = self.bs, self.cdt
+        bs = self.bs
         hidden, f, fs, held = (bs.hidden_size, bs.moe_intermediate_size,
                                bs.shared_expert_intermediate_size, bs.held)
         h = self.pre_norm(x)
@@ -407,38 +451,122 @@ class GatedExpertsBlock(_Block):
         s_down = self.weight("shared/w_down", (fs, hidden), init)
         s_open = self.weight("shared/gate", (hidden, 1), init)
         tokens = h.reshape(-1, hidden)
-        k = bs.num_experts_per_tok
         f32 = jnp.float32
-        with jax.named_scope("moe"):
-            with jax.named_scope("router"):
-                logits = jnp.dot(tokens.astype(f32), w_r.astype(f32),
-                                 precision=jax.lax.Precision.HIGHEST)
-                experts, weights = route_softmax_topk(logits, k)
-            with jax.named_scope("dispatch"):
-                plan, rows, row_weight, row_token = _dispatch(bs, experts,
-                                                              weights)
-            with jax.named_scope("experts"):
-                routed = routed_gated_mlp(
-                    tokens, w_gate, w_up, w_down, row_weight, row_token,
-                    plan["block_expert"], plan["live_blocks"], rows)
-            with jax.named_scope("shared"):
-                hidden_act = (jax.nn.silu(self.dot(tokens, s_gate).astype(f32))
-                              * self.dot(tokens, s_up).astype(f32)).astype(cdt)
-                shared = (self.dot(hidden_act, s_down).astype(f32)
-                          * jax.nn.sigmoid(self.dot(tokens, s_open)
-                                           .astype(f32)))
-            with jax.named_scope("combine"):
-                y = (shared + routed).astype(cdt)
-        counters = _dispatch_counters(plan, tokens, k, rows)
-        return x + y.reshape(x.shape), counters
+        return self.experts_layer(
+            x, tokens, w_r,
+            lambda logits: route_softmax_topk(logits,
+                                              bs.num_experts_per_tok),
+            lambda *plan: routed_gated_mlp(tokens, w_gate, w_up, w_down,
+                                           *plan),
+            lambda t: (self.gated_mlp(t, s_gate, s_up, s_down).astype(f32)
+                       * jax.nn.sigmoid(self.dot(t, s_open).astype(f32))))
+
+
+class LatentAttentionBlock(_Block):
+    @nn.compact
+    def __call__(self, x):
+        bs = self.bs
+        hq, dn, dr, dv = (bs.num_attention_heads, bs.qk_nope_head_dim,
+                          bs.qk_rope_head_dim, bs.v_head_dim)
+        h = self.pre_norm(x)
+        init = nn.initializers.normal(INIT_STD)
+        ones = nn.initializers.ones
+        w_qa = self.weight("q_a_proj", (bs.hidden_size, bs.q_lora_rank), init)
+        q_norm = self.weight("q_a_norm", (bs.q_lora_rank,), ones)
+        w_qb = self.weight("q_b_proj", (bs.q_lora_rank, hq * (dn + dr)), init)
+        # the latent's columns, then the one rotary key's
+        w_kva = self.weight("kv_a_proj", (bs.hidden_size,
+                                          bs.kv_lora_rank + dr), init)
+        kv_norm = self.weight("kv_a_norm", (bs.kv_lora_rank,), ones)
+        # a head's columns: its key's dims, then its value's
+        w_kvb = self.weight("kv_b_proj", (bs.kv_lora_rank, hq * (dn + dv)),
+                            init)
+        w_o = self.weight("o_proj", (hq * dv, bs.hidden_size), init)
+        b_, t = x.shape[:2]
+
+        def row(xs):
+            """One row from its latents on: its queries, keys and values are
+            up-projected where they are read, so a batch's never stand in
+            memory together, and the backward holds one row's."""
+            c_q, c_kv, k_pe = (a[None] for a in xs)
+            with jax.named_scope("up_proj"):
+                q_nope, q_pe = jnp.split(
+                    self.dot(c_q, w_qb).reshape(1, t, hq, dn + dr), [dn],
+                    axis=-1)
+                k_nope, v = jnp.split(
+                    self.dot(c_kv, w_kvb).reshape(1, t, hq, dn + dv), [dn],
+                    axis=-1)
+            with jax.named_scope("rope"):
+                q_pe = rotate_pairs(q_pe, bs.rope_theta)
+                k_pe = rotate_pairs(k_pe[:, :, None, :], bs.rope_theta)[:, :, 0]
+            with jax.named_scope("scores"):
+                return causal_latent_attention(q_nope, q_pe, k_nope, k_pe,
+                                               v)[0]
+
+        with jax.named_scope("latent_attention"):
+            with jax.named_scope("q_latent"):
+                c_q = rms_norm(self.dot(h, w_qa), q_norm, bs.norm_eps)
+            with jax.named_scope("kv_latent"):
+                c_kv, k_pe = jnp.split(self.dot(h, w_kva), [bs.kv_lora_rank],
+                                       axis=-1)
+                c_kv = rms_norm(c_kv, kv_norm, bs.norm_eps)
+            o = jax.lax.map(jax.checkpoint(row), (c_q, c_kv, k_pe))
+            with jax.named_scope("o_proj"):
+                return x + self.dot(o.reshape(b_, t, hq * dv), w_o)
+
+
+class DenseMlpBlock(_Block):
+    @nn.compact
+    def __call__(self, x):
+        bs = self.bs
+        h = self.pre_norm(x)
+        init = nn.initializers.normal(INIT_STD)
+        w_gate = self.weight("gate_proj", (bs.hidden_size,
+                                           bs.intermediate_size), init)
+        w_up = self.weight("up_proj", (bs.hidden_size, bs.intermediate_size),
+                           init)
+        w_down = self.weight("down_proj", (bs.intermediate_size,
+                                           bs.hidden_size), init)
+        with jax.named_scope("dense_mlp"):
+            return x + self.gated_mlp(h, w_gate, w_up, w_down)
+
+
+class SigmoidGatedExpertsBlock(_Block):
+    """`ExpertsBlock`'s router over `GatedExpertsBlock`'s experts, the
+    shared expert added with no gate.  Returns (x, counters), as they do."""
+
+    @nn.compact
+    def __call__(self, x):
+        bs = self.bs
+        hidden, f, held = bs.hidden_size, bs.moe_intermediate_size, bs.held
+        fs = f * bs.n_shared_experts
+        h = self.pre_norm(x)
+        init = nn.initializers.normal(INIT_STD)
+        w_r = self.weight("router", (hidden, bs.n_routed_experts), init)
+        w_gate = self.weight("experts/w_gate", (held, hidden, f), init)
+        w_up = self.weight("experts/w_up", (held, hidden, f), init)
+        w_down = self.weight("experts/w_down", (held, f, hidden), init)
+        s_gate = self.weight("shared/w_gate", (hidden, fs), init)
+        s_up = self.weight("shared/w_up", (hidden, fs), init)
+        s_down = self.weight("shared/w_down", (fs, hidden), init)
+        tokens = h.reshape(-1, hidden)
+        return self.experts_layer(
+            x, tokens, w_r,
+            lambda logits: route_topk(logits, bs.num_experts_per_tok,
+                                      bs.routed_scaling_factor),
+            lambda *plan: routed_gated_mlp(tokens, w_gate, w_up, w_down,
+                                           *plan),
+            lambda t: self.gated_mlp(t, s_gate, s_up, s_down)
+            .astype(jnp.float32))
 
 
 _KINDS = {"M": MambaBlock, "*": AttentionBlock, "E": ExpertsBlock,
           "L": GatedDeltaBlock, "A": GatedAttentionBlock,
-          "F": GatedExpertsBlock}
+          "F": GatedExpertsBlock, "C": LatentAttentionBlock,
+          "D": DenseMlpBlock, "G": SigmoidGatedExpertsBlock}
 #: the kinds that mix along the sequence, and the kinds that hand back
 #: counters beside x
-SEQUENCE_MIXERS, EXPERT_KINDS = "M*LA", "EF"
+SEQUENCE_MIXERS, EXPERT_KINDS = "M*LAC", "EFG"
 
 
 class BlockStack(nn.Module):

@@ -162,8 +162,8 @@ def _causal_block(q, k, v, first: int, scale: float):
 
 def causal_gqa(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     """Causal grouped-query attention, no positional term.  q (B, T, Hq, D),
-    k / v (B, T, Hkv, D), Hq a multiple of Hkv (query head h reads key-value
-    head h // (Hq // Hkv)).  Returns (B, T, Hq, D).
+    k (B, T, Hkv, D), v (B, T, Hkv, Dv), Hq a multiple of Hkv (query head h
+    reads key-value head h // (Hq // Hkv)).  Returns (B, T, Hq, Dv).
 
     Plain XLA: a row at a time, a block of queries at a time, each block
     rematerialized so that the backward holds a row's q, k, v and one
@@ -179,6 +179,24 @@ def causal_gqa(q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
         q_r = q_r.reshape(t, g, hq // g, d)
         out = [block(q_r[lo:lo + blk], k_r[:lo + blk], v_r[:lo + blk], lo,
                      scale) for lo in range(0, t, blk)]
-        return jnp.concatenate(out, axis=0).reshape(t, hq, d)
+        return jnp.concatenate(out, axis=0).reshape(t, hq, v.shape[-1])
 
     return jax.lax.map(row, (q, k, v))
+
+
+def causal_latent_attention(q_nope: jax.Array, q_pe: jax.Array,
+                            k_nope: jax.Array, k_pe: jax.Array,
+                            v: jax.Array) -> jax.Array:
+    """Causal multi-head latent attention in its training form: a score is
+    a head's own product plus a product with one rotary key all heads share,
+    `(q_nope . k_nope + q_pe . k_pe) / sqrt(Dn + Dr)`, and the values are of
+    a width of their own.  q_nope, k_nope (B, T, H, Dn), q_pe (B, T, H, Dr),
+    k_pe (B, T, Dr) - one head - v (B, T, H, Dv).  Returns (B, T, H, Dv).
+
+    The shared key is copied a head and joined to the head's own dims, so
+    the score is one product over Dn + Dr dims: `causal_gqa` of the joined
+    queries and keys."""
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :],
+                            (*k_nope.shape[:3], k_pe.shape[-1]))
+    return causal_gqa(jnp.concatenate([q_nope, q_pe], axis=-1),
+                      jnp.concatenate([k_nope, k_pe], axis=-1), v)

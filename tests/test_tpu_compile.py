@@ -182,24 +182,16 @@ def test_criteo_size_deepfm_resident_eval_fits_the_chip(one_chip,
     assert scores.shape == (15, batch) and scores.dtype == jnp.float32
 
 
-def test_qwen3_next_ep16_epoch_fits_the_chip(one_chip, no_compile_cache,
-                                             monkeypatch):
-    """The epoch program of the benchmark's `qwen3_next_ep16.train_sequences`
-    cell (the blocks `LFLFLFAF` at Qwen3-Next-80B-A3B's published widths, 32
-    of 512 experts held, 586.8 M parameters with both Adadelta slots: 7.04 GB
-    of arguments; 8 steps of 8 rows of 4,096 positions, a block
-    rematerialized at a time) compiles for one v5e chip, the delta rule's
-    chunk systems and the routed experts' walk among it: its arguments
-    are carried in place and they and its temporaries fit the chip's 16 GiB
-    together.  The chunk systems are inverted by products: the operation a
-    triangular solve becomes on the chip is not in the program."""
+def _compiled_epoch_program(cell: str, one_chip, monkeypatch):
+    """The epoch program of a sequence cell of the benchmark (8 steps of 8
+    rows of 4,096 positions), compiled for one v5e chip: raises what the
+    chip's compiler would."""
     from benchmarks import harness
     from shifu_tpu.ops import pallas_common
     from shifu_tpu.train.loop import init_state
     from shifu_tpu.train.step import make_device_epoch_step
 
-    _, _, config, _, params, driver = harness.load_cell(
-        "qwen3_next_ep16.train_sequences")
+    _, _, config, _, params, driver = harness.load_cell(cell)
     job = driver.build_job(config, params, 1, 1)
     batch, width = config["batch_size"], config["num_categorical"]
     steps = params["train_rows"] // batch
@@ -218,10 +210,47 @@ def test_qwen3_next_ep16_epoch_fits_the_chip(one_chip, no_compile_cache,
     lowered = step._fn.trace(state, blocks, order).lower(
         lowering_platforms=("tpu",))
     monkeypatch.undo()
-    compiled = lowered.compile()                   # raises what the chip would
-    assert "InvertDiagBlocksLowerTriangular" not in compiled.as_text()
+    return lowered.compile()
+
+
+def _fits_in_place(compiled, low: float, high: float) -> None:
+    """The arguments lie between `low` and `high` bytes, are carried in
+    place, and fit the chip's 16 GiB with the temporaries."""
     memory = compiled.memory_analysis()
     arguments = memory.argument_size_in_bytes
-    assert 7.0e9 < arguments < 7.1e9
+    assert low < arguments < high
     assert memory.alias_size_in_bytes > 0.999 * memory.output_size_in_bytes
     assert arguments + memory.temp_size_in_bytes < 16 * 2 ** 30
+
+
+def test_qwen3_next_ep16_epoch_fits_the_chip(one_chip, no_compile_cache,
+                                             monkeypatch):
+    """The epoch program of the benchmark's `qwen3_next_ep16.train_sequences`
+    cell (the blocks `LFLFLFAF` at Qwen3-Next-80B-A3B's published widths, 32
+    of 512 experts held, 586.8 M parameters with both Adadelta slots: 7.04 GB
+    of arguments; 8 steps of 8 rows of 4,096 positions, a block
+    rematerialized at a time) compiles for one v5e chip, the delta rule's
+    chunk systems and the routed experts' walk among it: its arguments
+    are carried in place and they and its temporaries fit the chip's 16 GiB
+    together.  The chunk systems are inverted by products: the operation a
+    triangular solve becomes on the chip is not in the program."""
+    compiled = _compiled_epoch_program("qwen3_next_ep16.train_sequences",
+                                       one_chip, monkeypatch)
+    assert "InvertDiagBlocksLowerTriangular" not in compiled.as_text()
+    _fits_in_place(compiled, 7.0e9, 7.1e9)
+
+
+def test_joyai_flash_ep16_epoch_fits_the_chip(one_chip, no_compile_cache,
+                                              monkeypatch):
+    """The epoch program of the benchmark's `joyai_flash_ep16.train_sequences`
+    cell (the blocks `CDCGCGCGCGCG` at JoyAI-LLM-Flash's published widths, 16
+    of 256 experts held, 639.0 M parameters with both Adadelta slots: 7.67 GB
+    of arguments; a block rematerialized at a time) compiles for one v5e
+    chip, latent attention's 192-wide keys beside 128-wide values and the
+    routed experts' walk among it.  It fits because a row's queries, keys
+    and values are up-projected from the latents a row at a time: with the
+    batch's up-projected together the compiler refuses the program ("Used
+    17.42G of 15.75G hbm")."""
+    compiled = _compiled_epoch_program("joyai_flash_ep16.train_sequences",
+                                       one_chip, monkeypatch)
+    _fits_in_place(compiled, 7.6e9, 7.75e9)
