@@ -117,8 +117,13 @@ class DataConfig:
     # a persistent feeder shuffles and assembles epoch N+1's batches on host
     # threads while epoch N still executes on device, and next-epoch work
     # overlaps the eval dispatch tail — batch order stays a pure function of
-    # (seed, epoch), byte-identical to the non-overlapped order.  False
-    # restores the per-epoch producer thread (stop-the-world boundaries).
+    # (seed, epoch), byte-identical to the non-overlapped order.  On the
+    # resident tiers (an epoch is one dispatch, the valid rows live on the
+    # device) epoch N+1's scan is dispatched before the host accumulates
+    # epoch N's eval scores, where that accumulation is a sizeable share
+    # of the epoch (docs/DATA.md "Overlap engine").  False
+    # restores the per-epoch producer thread and the sequential order
+    # (stop-the-world boundaries).
     overlap_epochs: bool = True
     # staged epochs: device-put (block_batches, B, F) blocks once and
     # lax.scan the train step on device — one H2D transfer per block instead

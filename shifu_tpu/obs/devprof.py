@@ -518,13 +518,18 @@ class DeviceProfiler:
                 return cand
         return base  # pathological; the merge is the lesser evil
 
+    def will_capture(self, epoch: int) -> bool:
+        """Whether `obs.trace_epochs` schedules a capture of `epoch` (the
+        train loop dispatches no scan ahead of a capture that is to see it
+        whole)."""
+        return (self.enabled and self.tracing_enabled
+                and self._sched(epoch, self.start_epoch))
+
     @contextlib.contextmanager
     def epoch_capture(self, epoch: int) -> Iterator[None]:
         """Trace the whole epoch when `obs.trace_epochs` schedules it;
         a plain no-op context otherwise."""
-        if (not self.enabled or not self.tracing_enabled
-                or self._trace_active
-                or not self._sched(epoch, self.start_epoch)):
+        if self._trace_active or not self.will_capture(epoch):
             yield
             return
         log_dir = self._fresh_capture_dir(

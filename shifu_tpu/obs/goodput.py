@@ -78,6 +78,12 @@ class GoodputLedger:
                 cell[0] += seconds
                 cell[1] += count
 
+    def phase_seconds(self, path: str) -> float:
+        """The seconds credited to phase `path` so far (0.0 if none)."""
+        with self._lock:
+            cell = self._phases.get(path)
+            return cell[0] if cell is not None else 0.0
+
     def summary(self, wall_s: float) -> dict:
         """The goodput record for an epoch of `wall_s` seconds.  Compile
         time happens INSIDE the timed step/eval dispatches (a compiling

@@ -391,6 +391,28 @@ def _eval_batch_size(job: JobConfig, ds: pipe.TabularDataset,
     return bs
 
 
+#: the share of an epoch's wall that the eval's accumulation (the host's own
+#: work, with nothing for the device in it) has to take before the next
+#: epoch's scan is dispatched ahead of it.  Under it there is nothing to
+#: hide that a run could show (every cell's rate spreads by more), and the
+#: sequential order keeps the device idle at every boundary, which is what
+#: a trace taken from `epoch_callback` and a prompt SIGTERM drain want.
+_AHEAD_MIN_HOST_SHARE = 0.05
+
+
+@dataclasses.dataclass
+class _ScanAhead:
+    """An epoch's resident scan dispatched between the two halves of the
+    previous epoch's resident eval (`train()`'s `run_ahead`), until that
+    epoch's iteration takes it over: its loss sum still on the device, its
+    batches, and the StepTimer that timed the dispatch as the epoch's one
+    chunk."""
+    loss_acc: Any
+    loss_n: int
+    timer: Any
+    t_dispatched: float
+
+
 @dataclasses.dataclass
 class ResidentEval:
     """The resident eval tier: the valid set's features where the train rows
@@ -430,33 +452,41 @@ def place_resident_eval(ds: Optional[pipe.TabularDataset], job: JobConfig,
     return ResidentEval(placed, make_resident_eval_step(job))
 
 
-def _resident_triples(state: TrainState, ds: pipe.TabularDataset,
-                      resident: ResidentEval):
-    """(scores, labels, weights) chunks of the resident eval pass, one a
-    block: the host's part of a pass is views of its own columns, one
-    dispatch, and the fetch of a few dense slices of scores, each
-    accumulated while the next is in flight."""
+def _dispatch_resident_eval(state: TrainState, ds: pipe.TabularDataset,
+                            resident: ResidentEval) -> tuple[list, list]:
+    """The dispatching half of a resident eval pass: views of the host's
+    label and weight columns, a block each, and the pass's one dispatch
+    with the D2H of every slice of scores asked for.  Nothing here waits
+    for the device, so what the caller dispatches next (`train()`: the
+    next epoch's scan) queues behind the pass and runs while
+    `_fetch_resident_eval` brings the scores in."""
     bs = resident.features.shape[1]
     with obs.span("prep", journal=False):
         # no gather, no copy, no padding: the padded tail's scores are
-        # dropped by row count below
+        # dropped by row count in the fetching half
         tgt, wgt = ds.target[:, 0], ds.weight[:, 0]
         views = [(tgt[lo:lo + bs], wgt[lo:lo + bs])
                  for lo in range(0, ds.num_rows, bs)]
     with obs.span("dispatch", journal=False):
         slices = list(resident.step(state, resident.features))
+        # every slice's transfer is asked for at once, so that the later
+        # ones land while the earlier are accumulated
+        for s in slices:
+            s.copy_to_host_async()
     obs.counter("eval_resident_passes_total",
                 "eval passes scored from the resident eval tier").inc()
+    return views, slices
+
+
+def _fetch_resident_eval(views: list, slices: list):
+    """The finishing half: (scores, labels, weights) chunks of a dispatched
+    resident eval pass, one a block, each slice accumulated while the next
+    is in flight."""
     chunks = iter(views)
     for i in range(len(slices)):
         with obs.span("fetch", journal=False):
-            # the wait for the device and the D2H: every slice's transfer
-            # is asked for at once, so that the later ones land while the
-            # earlier are accumulated; a slice's device buffer is released
-            # inside the phase
-            if i == 0:
-                for s in slices:
-                    s.copy_to_host_async()
+            # the wait for the device and the D2H; a slice's device buffer
+            # is released inside the phase
             host = np.asarray(slices[i])
             slices[i] = None
         for row, (t, w) in zip(host, chunks):
@@ -494,8 +524,9 @@ def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
     if not multihost and ds.num_rows == 0:
         return float("nan"), float("nan")
     if resident is not None and not multihost:
-        return _accumulate_streaming(_resident_triples(state, ds, resident),
-                                     score_sink)
+        return _accumulate_streaming(
+            _fetch_resident_eval(*_dispatch_resident_eval(state, ds, resident)),
+            score_sink)
     bs = _eval_batch_size(job, ds, mesh, multihost, batch_size)
     # same wire cast as training (model casts inputs to compute_dtype first,
     # so scores are bit-identical; H2D bytes halve)
@@ -1103,6 +1134,13 @@ def train(job: JobConfig,
         # chaos site "train.chunk": the safe-point boundary itself — a
         # crash here models dying between a chunk's compute and its save
         chaos.maybe_fail("train.chunk", echo=console, epoch=epoch)
+        if ahead is not None:
+            # the state in hand is the one after the scan in flight, an
+            # epoch past this label, and that epoch has no record yet: the
+            # drain and the time cadence wait for its boundary, where
+            # `may_run_ahead` dispatches nothing further (what a SIGTERM
+            # during a resident scan has always waited for)
+            return
         if term_flag["hit"]:
             if manager is not None:
                 cur = int(jax.device_get(state.step))
@@ -1153,6 +1191,64 @@ def train(job: JobConfig,
                 host_input_times.append(time.perf_counter() - t0)
                 yield item
         return run()
+
+    def dispatch_resident_epoch(ep: int, timer):
+        """The resident tier's one dispatch of epoch `ep`, timed by `timer`
+        as the epoch's one chunk: (loss sum still on the device, batches).
+        `state` is donated to the scan and rebound to what it leaves."""
+        nonlocal state
+        nb_total = resident_blocks["features"].shape[0]
+        # THE shared per-epoch order stream (pipeline.py): the journaled
+        # order_digest derives from the same function
+        order = pipe.epoch_permutation(
+            nb_total, shuffle=job.data.shuffle,
+            seed=job.data.shuffle_seed, epoch=ep).astype(np.int32)
+        timer.mark_input_ready()
+        state, loss_acc = device_epoch_step(
+            state, resident_blocks, jnp.asarray(order))
+        timer.mark_step_done()
+        return loss_acc, nb_total
+
+    # the resident tiers pipelined one epoch deep (docs/DATA.md "Overlap
+    # engine"): `ahead` is epoch e+1's scan, dispatched once epoch e's eval
+    # pass is queued and before the host fetches and accumulates its
+    # scores, so that the device trains while the host counts.  The device
+    # runs its queue in order: the eval pass reads the state after epoch e,
+    # and the scan that takes that state by donation runs behind it.
+    ahead: Optional[_ScanAhead] = None
+    # what the look-ahead can hide, as last observed: the accumulation's
+    # share of the wall of the newest epoch that evaluated
+    host_share: Optional[float] = None
+
+    def may_run_ahead(epoch: int) -> bool:
+        """Whether epoch `epoch`+1's scan may be dispatched at the close of
+        `epoch`: both tiers resident in one process, an accumulation that
+        is worth hiding, and nothing at this boundary that reads `state` on
+        the host or may end the run."""
+        nxt = epoch + 1
+        ck = job.runtime.checkpoint
+        return (use_overlap and use_resident and resident_eval is not None
+                and pending_loader is None
+                and host_share is not None
+                and host_share >= _AHEAD_MIN_HOST_SHARE
+                and job.train.early_stop_patience == 0
+                and nxt < job.train.epochs
+                and (manager is None or not (
+                    nxt % ck.save_every_epochs == 0
+                    or (save_secs > 0
+                        and time.monotonic() - last_save >= save_secs)))
+                and not term_flag["hit"]
+                # a scheduled device capture is to see its scan whole
+                and not devprof.will_capture(nxt))
+
+    def run_ahead(ep: int) -> None:
+        """Dispatch epoch `ep`'s scan from inside epoch `ep` - 1's eval."""
+        nonlocal ahead
+        timer = prof_lib.StepTimer(on_chunk=devprof.chunk_hook(ep))
+        timer.start()
+        with obs.span("scan_ahead", journal=False):
+            loss_acc, nb = dispatch_resident_epoch(ep, timer)
+        ahead = _ScanAhead(loss_acc, nb, timer, time.perf_counter())
 
     history: list[EpochMetrics] = []
     # drift baseline (obs/sketch.py): the training-feature sketch is
@@ -1217,8 +1313,14 @@ def train(job: JobConfig,
         loss_acc = None
         loss_n = 0
         host_input_times.clear()
-        timer = prof_lib.StepTimer(on_chunk=devprof.chunk_hook(epoch))
-        timer.start()
+        ran_ahead, ahead = ahead, None
+        if ran_ahead is not None:
+            # this epoch's scan has been in flight since the previous
+            # epoch's eval, where its dispatch was timed
+            timer = ran_ahead.timer
+        else:
+            timer = prof_lib.StepTimer(on_chunk=devprof.chunk_hook(epoch))
+            timer.start()
         # trace seam: the flight recorder's schedule decides
         # (obs.trace_epochs — a scheduled epoch's capture closes into a
         # `device_profile` journal event)
@@ -1365,18 +1467,11 @@ def train(job: JobConfig,
                             f"batch {bs}")
             if streamed_this_epoch:
                 pass
+            elif ran_ahead is not None:
+                # what is left of it is the wait below
+                loss_acc, loss_n = ran_ahead.loss_acc, ran_ahead.loss_n
             elif use_resident:
-                nb_total = resident_blocks["features"].shape[0]
-                # THE shared per-epoch order stream (pipeline.py): the
-                # journaled order_digest derives from the same function
-                order = pipe.epoch_permutation(
-                    nb_total, shuffle=job.data.shuffle,
-                    seed=job.data.shuffle_seed, epoch=epoch).astype(np.int32)
-                timer.mark_input_ready()
-                state, loss_acc = device_epoch_step(
-                    state, resident_blocks, jnp.asarray(order))
-                loss_n = nb_total
-                timer.mark_step_done()
+                loss_acc, loss_n = dispatch_resident_epoch(epoch, timer)
             elif use_staged:
                 # multihost: every host streams blocks of its OWN shard's
                 # epoch subset (exactly min_host_rows rows), so the
@@ -1471,13 +1566,31 @@ def train(job: JobConfig,
 
         tv0 = time.perf_counter()
         eval_tier = None  # which source of batches this epoch's eval read
+        eval_beside_scan_s = 0.0  # the eval's host half, next scan queued
         if epoch % job.train.eval_every_epochs == 0 or epoch == job.train.epochs - 1:
             score_sketch = obs.sketch.ScoreSketch()
             eval_tier = "streamed" if resident_eval is None else "resident"
             with obs.span("epoch/eval", epoch=epoch):
-                valid_error, valid_auc = evaluate(
-                    state, valid_ds, job, eval_step, mesh,
-                    score_sink=score_sketch.update, resident=resident_eval)
+                if may_run_ahead(epoch):
+                    # evaluate()'s resident branch with the next epoch's
+                    # scan queued between its two halves
+                    dispatched = _dispatch_resident_eval(
+                        state, valid_ds, resident_eval)
+                    run_ahead(epoch + 1)
+                    valid_error, valid_auc = _accumulate_streaming(
+                        _fetch_resident_eval(*dispatched),
+                        score_sketch.update)
+                else:
+                    valid_error, valid_auc = evaluate(
+                        state, valid_ds, job, eval_step, mesh,
+                        score_sink=score_sketch.update,
+                        resident=resident_eval)
+            if ahead is not None:
+                eval_beside_scan_s = time.perf_counter() - ahead.t_dispatched
+                obs.counter(
+                    "eval_overlapped_epochs_total",
+                    "epochs whose eval's host half (fetch, accumulate) ran "
+                    "beside the next epoch's scan").inc()
         else:
             score_sketch = None
             valid_error, valid_auc = float("nan"), float("nan")
@@ -1656,12 +1769,20 @@ def train(job: JobConfig,
         # buckets always sum to the wall
         led = obs.goodput.current()
         if led is not None:
-            led.add("input", sum(timer.input_times))
-            led.add("step", sum(timer.step_times) + device_wait.seconds)
+            # a scan dispatched ahead was timed inside the previous epoch's
+            # wall (its eval bucket, phase `epoch/eval/scan_ahead`): what
+            # this epoch's wall holds of it is the device_wait
+            in_wall = ran_ahead is None
+            led.add("input", sum(timer.input_times) if in_wall else 0.0)
+            led.add("step", (sum(timer.step_times) if in_wall else 0.0)
+                    + device_wait.seconds)
             led.add("eval", valid_time)
             gc_phases.fold(led)
-            obs.goodput.end_epoch(
-                epoch, time.perf_counter() - t0 + ingest_wall_s)
+            wall_s = time.perf_counter() - t0
+            if eval_tier is not None and wall_s > 0:
+                host_share = led.phase_seconds(
+                    "epoch/eval/accumulate") / wall_s
+            obs.goodput.end_epoch(epoch, wall_s + ingest_wall_s)
         if "moe" in step_counters:
             # where the routed expert layers' tokens went this epoch, one
             # entry an expert layer: summed on the device, read with the loss
@@ -1702,6 +1823,8 @@ def train(job: JobConfig,
                   input_production_s=round(prod_s, 6),
                   input_hidden_s=round(hidden_s, 6),
                   eval_s=round(valid_time, 6),
+                  eval_overlapped=ahead is not None,
+                  eval_beside_scan_s=round(eval_beside_scan_s, 6),
                   prefetched_chunks=(feeder.ready_ahead()
                                      if feeder is not None else 0),
                   overlap_efficiency=(round(eff, 4) if eff is not None
@@ -1749,7 +1872,11 @@ def train(job: JobConfig,
                             "critical path (device idle)")
         hid_c.inc(hidden_s, kind="input")
         exp_c.inc(exposed_s, kind="input")
-        exp_c.inc(valid_time, kind="eval")
+        if ahead is None:
+            # an eval with the next scan queued behind it is in neither
+            # counter: the host cannot see when that scan ended (the next
+            # epoch's device_wait and a device trace can)
+            exp_c.inc(valid_time, kind="eval")
         if eff is not None:
             obs.gauge("overlap_efficiency",
                       "last epoch's hidden / (hidden + exposed) input "
