@@ -2272,8 +2272,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         # paths drop the persistence floor to 0: padded-bucket scorer
         # programs compile in tens of ms — below the 0.5s train-path
         # floor, which would silently skip exactly the compiles a member
-        # restart pays again (hit/miss verdicts ride every xla_compile
-        # event through the observe_compile seam)
+        # restart pays again (JAX's hit/miss verdict rides every
+        # xla_compile event, obs/introspect.py)
         from ..utils.compilecache import enable_persistent_cache
         serving_cmd = args.command in ("serve", "loadtest", "fleet")
         enable_persistent_cache(

@@ -27,6 +27,14 @@ alone.  This module is that accounting for shifu_tpu:
   under it.  Phases are raw host seconds: a compile or a collection
   (`gc/gen<N>`) that ran inside a phase is in that phase's seconds too.
 
+The interval before the first epoch has a ledger of its own:
+`begin_startup()` opens one at `train()`'s entry and the first
+`begin_epoch()` takes its place, so the same hot spans (`startup/ingest`,
+`startup/init_state`, `startup/tiers/...`) and the same compile notes make
+startup's phases.  The loop keeps that ledger and writes it into its one
+`startup` event (train/loop.py); it feeds no counter and journals no
+`goodput` event.
+
 Every epoch journals ONE `goodput` event and feeds the
 `goodput_bucket_seconds_total{bucket=...}` counter plus the
 `goodput_fraction` gauge, so `shifu-tpu profile` and `shifu-tpu status`
@@ -125,6 +133,12 @@ def begin_epoch() -> GoodputLedger:
         return _current
 
 
+def begin_startup() -> GoodputLedger:
+    """Open a ledger for the interval before the first epoch: active until
+    the first `begin_epoch()` replaces it, and the caller's to read."""
+    return begin_epoch()
+
+
 def current() -> Optional[GoodputLedger]:
     return _current
 
@@ -143,7 +157,8 @@ def note(bucket: str, seconds: float) -> None:
 
 def note_phase(path: str, seconds: float) -> bool:
     """Credit one closed interval of phase `path` to the active ledger.
-    False between epochs, where there is none to take it.  Never raises."""
+    False where none is open to take it (between epochs, outside a
+    `train()` call).  Never raises."""
     led = _current
     if led is None:
         return False

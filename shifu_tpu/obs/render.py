@@ -268,6 +268,7 @@ def profile_summary(path: str) -> Optional[dict]:
     overlap_epochs: list[dict] = []
     ingests: list[dict] = []
     profiles: list[dict] = []
+    startup: Optional[dict] = None
     hbm_peak = 0
     hbm_last: Optional[dict] = None
     anomalies = 0
@@ -302,6 +303,11 @@ def profile_summary(path: str) -> Optional[dict]:
                                     "eval_s", "prefetched_chunks",
                                     "overlap_efficiency", "order_digest",
                                     "resident_format")})
+        elif kind == "startup":
+            # one a train() call; a restarted job's last call is shown
+            startup = {k: rec.get(k) for k in
+                       ("epoch", "wall_s", "phases", "first_epoch",
+                        "compiles")}
         elif kind == "xla_compile":
             fn = str(rec.get("fn", "?"))
             c = compiles.setdefault(fn, {"compiles": 0, "compile_s": 0.0,
@@ -413,6 +419,7 @@ def profile_summary(path: str) -> Optional[dict]:
         "goodput_fraction_mean": (round(sum(fracs) / len(fracs), 4)
                                   if fracs else None),
         "overlap": overlap,
+        "startup": startup,
         "ingest": ingests or None,
         # by cost: captured FLOPs first (the honest "expensive" ranking),
         # compile seconds as the tiebreak/no-capture fallback
@@ -504,6 +511,57 @@ def profile_summary(path: str) -> Optional[dict]:
     return out
 
 
+_COMPILE_STAGES = (("trace+lower", ("trace_s", "lower_s")),
+                   ("compiled", ("backend_compile_s",)),
+                   ("cache-loaded", ("cache_retrieval_s",)))
+
+
+def _startup_lines(st: Optional[dict]) -> list[str]:
+    """The `startup` event (train/loop.py) in a few lines: the call's wall
+    to its first epoch's boundary, by phase, then JAX's own seconds a
+    program compiled before it."""
+    if not st:
+        return []
+
+    def num(v) -> float:
+        return float(v) if isinstance(v, (int, float)) else 0.0
+
+    phases = st.get("phases") or {}
+
+    def phase(path: str) -> float:
+        return num((phases.get(path) or (0.0, 0))[0])
+
+    def stages(compiles: list) -> str:
+        return " ".join(
+            f"{label} {sum(num(c.get(k)) for c in compiles for k in keys):.3f}s"
+            for label, keys in _COMPILE_STAGES)
+
+    first = st.get("first_epoch") or {}
+    wall = num(st.get("wall_s"))
+    top = [p for p in phases if p.rsplit("/", 1)[0] not in phases]
+    left = wall - sum(phase(p) for p in top) - num(first.get("wall_s"))
+    tiers = " ".join(f"{c} {phase('startup/tiers/' + c):.3f}s"
+                     for c in ("flags", "blocks", "h2d", "eval_tier"))
+    compiles = [c for c in st.get("compiles") or [] if isinstance(c, dict)]
+    lines = [
+        f"startup (to the boundary of epoch {st.get('epoch')}): train call "
+        f"{wall:.3f}s = ingest {phase('startup/ingest'):.3f}s + restore "
+        f"{phase('startup/restore'):.3f}s + init "
+        f"{phase('startup/init_state'):.3f}s + tiers "
+        f"{phase('startup/tiers'):.3f}s ({tiers}) + first epoch "
+        f"{num(first.get('wall_s')):.3f}s (compile "
+        f"{num((first.get('buckets') or {}).get('compile')):.3f}s) + "
+        f"{left:.3f}s elsewhere",
+        f"  programs before that boundary: {len(compiles)}, "
+        + stages(compiles)]
+    for c in compiles:
+        lines.append(f"  {c.get('fn')}"
+                     + (f" [{c['span']}]" if c.get("span")
+                        and c.get("span") != c.get("fn") else "")
+                     + f": {stages([c])} ({c.get('cache')})")
+    return lines
+
+
 def render_profile_text(summary: dict) -> str:
     """Human rendering of `profile_summary`'s dict: the per-epoch bucket
     table, top compiled functions, and the recovery tax."""
@@ -557,6 +615,7 @@ def render_profile_text(summary: dict) -> str:
                 f"prefetched_next={e.get('prefetched_chunks')}"
                 + (f" eff={eeff:.1%}"
                    if isinstance(eeff, (int, float)) else ""))
+    lines.extend(_startup_lines(summary.get("startup")))
     for ing in summary.get("ingest") or []:
         tiers = ing.get("tiers") or {}
         tier_s = " ".join(f"{k}={v}" for k, v in sorted(tiers.items()))

@@ -634,6 +634,13 @@ def train(job: JobConfig,
     Datasets may be passed directly (tests, bench) or loaded from
     job.data.paths with per-host file sharding.
     """
+    # startup, opened (docs/OBSERVABILITY.md "Startup"): from here to the
+    # first epoch's ledger, hot spans under `startup/...` and compile notes
+    # go to a ledger of their own, and the first trained epoch's boundary
+    # journals them, with every compile so far, as one `startup` event
+    t_entry = time.perf_counter()
+    startup_led = obs.goodput.begin_startup()
+    compile_mark = obs.introspect.compile_mark()
     job = job.validate()
     console = console or (lambda s: print(s, flush=True))
 
@@ -724,11 +731,11 @@ def train(job: JobConfig,
             # credited to the FIRST epoch's goodput input bucket below —
             # the cold-start tax must show up in the ledger, not vanish
             # into unaccounted pre-epoch wall (docs/DATA.md "Columnar cache")
-            t_ingest = time.perf_counter()
-            train_ds, valid_ds = pipe.load_datasets(
-                job.schema, job.data, host, nhosts,
-                feature_dtype=feature_dtype)
-            pending_ingest_s = time.perf_counter() - t_ingest
+            with obs.span("startup/ingest", journal=False) as ingest:
+                train_ds, valid_ds = pipe.load_datasets(
+                    job.schema, job.data, host, nhosts,
+                    feature_dtype=feature_dtype)
+            pending_ingest_s = ingest.seconds
     assert valid_ds is not None or stream_loader is not None
 
     # Shifu train.baggingSampleRate: deterministic per-run subsample of the
@@ -748,7 +755,9 @@ def train(job: JobConfig,
 
     num_features = (train_ds.num_features if train_ds is not None else 0) \
         or job.schema.feature_count
-    state = init_state(job, num_features, mesh)
+    with obs.span("startup/init_state", journal=False):
+        # with the wait: the parameters' initialisation is a device program
+        state = jax.block_until_ready(init_state(job, num_features, mesh))
 
     # auto-resume (successor of MonitoredTrainingSession restore-on-start)
     start_epoch = 0
@@ -757,7 +766,9 @@ def train(job: JobConfig,
         manager = ckpt_lib.make_manager(job.runtime.checkpoint.directory,
                                         job.runtime.checkpoint.max_to_keep)
         if job.runtime.checkpoint.resume:
-            restored = restore_latest_any_layout(manager, state, job, console)
+            with obs.span("startup/restore", journal=False):
+                restored = restore_latest_any_layout(manager, state, job,
+                                                     console)
             if restored is not None:
                 r_state, extra, step = restored
                 fresh_opt = state.opt_state  # before the restore discards it
@@ -920,10 +931,11 @@ def train(job: JobConfig,
         # the collective program signature; the flags ride the same
         # allgather round as min_host_rows).  One full pass over the
         # target/weight columns, at memory bandwidth, once per job.
-        label_ok = (job.data.wire_label_dtype in ("auto", "uint8")
-                    and pipe.target_u8_exact(train_ds.target))
-        weight_ok = (job.data.wire_weight_mode in ("auto", "elide")
-                     and pipe.weight_all_ones(train_ds.weight))
+        with obs.span("flags", journal=False):
+            label_ok = (job.data.wire_label_dtype in ("auto", "uint8")
+                        and pipe.target_u8_exact(train_ds.target))
+            weight_ok = (job.data.wire_weight_mode in ("auto", "elide")
+                         and pipe.weight_all_ones(train_ds.weight))
         if multihost:
             from jax.experimental import multihost_utils
             agreed = np.min(multihost_utils.process_allgather(np.asarray(
@@ -1024,9 +1036,10 @@ def train(job: JobConfig,
                 # rows, placed once beside the train blocks where both
                 # fit; where they do not, every epoch's evaluate() streams
                 # them
-                resident_eval = place_resident_eval(
-                    valid_ds, job, mesh,
-                    job.data.device_resident_bytes - ds_bytes)
+                with obs.span("eval_tier", journal=False):
+                    resident_eval = place_resident_eval(
+                        valid_ds, job, mesh,
+                        job.data.device_resident_bytes - ds_bytes)
             from .step import make_device_epoch_step, make_local_sgd_epoch_step
             device_epoch_step = (
                 make_local_sgd_epoch_step(job, mesh, with_order=True)
@@ -1036,31 +1049,37 @@ def train(job: JobConfig,
             def stack(arr):
                 return arr[:nb_total * local_bs].reshape(
                     nb_total, local_bs, *arr.shape[1:])
-            host_blocks = {"features": stack(train_ds.features),
-                           "target": stack(train_ds.target),
-                           "weight": stack(train_ds.weight)}
-            raw_features = host_blocks["features"]
-            if wcast is not None:
-                host_blocks = wcast(host_blocks)
-            if (rfmt == "int8"
-                    and host_blocks["features"].dtype != np.int8):
-                # forced int8 residency under a wider wire: quantize the
-                # stacked blocks once to the same static grid the int8
-                # wire uses — from the RAW features, not the wire-cast
-                # ones (a bf16 wire cast first would shift values across
-                # int8 buckets and break parity with the int8-wire run)
-                scale, offset = pipe.wire_params(job.schema, job.data)
-                host_blocks = dict(host_blocks)
-                host_blocks["features"] = pipe.wire_quantize(
-                    raw_features, scale, offset)
-            if multihost:
-                resident_blocks = shard_lib.shard_blocks_process_local(
-                    host_blocks, mesh)
-            elif mesh is not None:
-                resident_blocks = shard_lib.shard_blocks(host_blocks, mesh)
-            else:
-                resident_blocks = {k: jax.device_put(v)
-                                   for k, v in host_blocks.items()}
+            with obs.span("blocks", journal=False):
+                host_blocks = {"features": stack(train_ds.features),
+                               "target": stack(train_ds.target),
+                               "weight": stack(train_ds.weight)}
+                raw_features = host_blocks["features"]
+                if wcast is not None:
+                    host_blocks = wcast(host_blocks)
+                if (rfmt == "int8"
+                        and host_blocks["features"].dtype != np.int8):
+                    # forced int8 residency under a wider wire: quantize
+                    # the stacked blocks once to the same static grid the
+                    # int8 wire uses — from the RAW features, not the
+                    # wire-cast ones (a bf16 wire cast first would shift
+                    # values across int8 buckets and break parity with the
+                    # int8-wire run)
+                    scale, offset = pipe.wire_params(job.schema, job.data)
+                    host_blocks = dict(host_blocks)
+                    host_blocks["features"] = pipe.wire_quantize(
+                        raw_features, scale, offset)
+            # no barrier: where a put returns before the bytes have landed,
+            # the first epoch's `epoch/train/device_wait` holds the rest
+            with obs.span("h2d", journal=False):
+                if multihost:
+                    resident_blocks = shard_lib.shard_blocks_process_local(
+                        host_blocks, mesh)
+                elif mesh is not None:
+                    resident_blocks = shard_lib.shard_blocks(host_blocks,
+                                                             mesh)
+                else:
+                    resident_blocks = {k: jax.device_put(v)
+                                       for k, v in host_blocks.items()}
         if use_staged:
             # loop-invariant staged-tier plumbing (the per-epoch subset
             # below still varies when shards are imbalanced)
@@ -1088,7 +1107,8 @@ def train(job: JobConfig,
             train_step = make_train_step(job, mesh, donate_batch=True)
 
     if train_ds is not None:
-        _prepare_tiers()
+        with obs.span("startup/tiers", journal=False):
+            _prepare_tiers()
     eval_step = make_eval_step(job)
 
     from . import profiler as prof_lib
@@ -1273,6 +1293,8 @@ def train(job: JobConfig,
     # (folded in at each epoch's close) as phases of the epoch's ledger;
     # its thresholds stay as they are
     gc_phases = obs.spans.GcPhases()
+    first_epoch: Optional[dict] = None   # its goodput record, for `startup`
+    startup_rec: Optional[dict] = None   # built at the first boundary
     try:
       for epoch in range(start_epoch, job.train.epochs):
         # chaos site "train.epoch_start": the epoch boundary BEFORE any
@@ -1307,7 +1329,8 @@ def train(job: JobConfig,
             else:
                 train_ds = pending_loader.train_dataset()
             pending_loader = None
-            _prepare_tiers()
+            with obs.span("epoch/tiers", journal=False):
+                _prepare_tiers()
         # loss accumulates on device; host sync happens once per epoch so
         # async dispatch keeps the chips busy
         loss_acc = None
@@ -1461,7 +1484,8 @@ def train(job: JobConfig,
                     # _prepare_tiers can clamp or raise its usual errors
                     train_ds = pending_loader.train_dataset()
                     pending_loader = None
-                    _prepare_tiers()
+                    with obs.span("tiers", journal=False):
+                        _prepare_tiers()
                     console(f"streamed first epoch had no full batch of "
                             f"{stream_bs}; re-running epoch {epoch} with "
                             f"batch {bs}")
@@ -1782,7 +1806,17 @@ def train(job: JobConfig,
             if eval_tier is not None and wall_s > 0:
                 host_share = led.phase_seconds(
                     "epoch/eval/accumulate") / wall_s
-            obs.goodput.end_epoch(epoch, wall_s + ingest_wall_s)
+            good = obs.goodput.end_epoch(epoch, wall_s + ingest_wall_s)
+            if startup_led is not None and good is not None:
+                # the first trained epoch, as its goodput event has it, less
+                # the blocking ingest charged to it: `startup/ingest` here
+                first_epoch = {
+                    "wall_s": round(good["wall_s"] - ingest_wall_s, 6),
+                    "buckets": dict(
+                        good["buckets"],
+                        input=round(max(good["buckets"]["input"]
+                                        - ingest_wall_s, 0.0), 6)),
+                    "phases": good["phases"]}
         if "moe" in step_counters:
             # where the routed expert layers' tokens went this epoch, one
             # entry an expert layer: summed on the device, read with the loss
@@ -1889,8 +1923,26 @@ def train(job: JobConfig,
             feeder.set_depth(pipe.next_prefetch_depth(
                 feeder.depth, exposed_s / wall_now))
 
-        if epoch_callback is not None:
-            epoch_callback(m)
+        if startup_led is not None:
+            # stamped here, where a caller's set-up ends (the callback)
+            call_s = time.perf_counter() - t_entry
+            startup_rec = {
+                "wall_s": round(call_s, 6),
+                "phases": startup_led.summary(call_s)["phases"],
+                "first_epoch": first_epoch,
+                "compiles": obs.introspect.compiles_since(compile_mark),
+                "epoch": epoch}
+            startup_led = None
+        try:
+            if epoch_callback is not None:
+                epoch_callback(m)
+        finally:
+            if startup_rec is not None:
+                # after the callback, also where it ends the call: a caller
+                # that marks the journal in its first callback (the
+                # benchmark's window) finds the event in what follows
+                obs.event("startup", **startup_rec)
+                startup_rec = None
 
         if early_stop_now:
             break

@@ -20,7 +20,6 @@ or a time.
 from __future__ import annotations
 
 import os
-import threading
 from typing import Optional
 
 ENV_DISABLE = "SHIFU_TPU_NO_COMPILE_CACHE"
@@ -29,42 +28,15 @@ DEFAULT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__)))), ".jax_cache")
 
-# persistent-cache observation state (obs/introspect.py classifies each
-# XLA compile as hit/miss from the entry-set delta): the directory the
-# cache was enabled at, and the entries seen at the last observation
-_lock = threading.Lock()
+# the directory the cache was enabled at.  Whether it served a compile is
+# JAX's to say (obs/introspect.py listens to its `cache_hits` /
+# `cache_misses` events); nothing here reads the directory
 _active_dir: Optional[str] = None
-_seen_entries: frozenset[str] = frozenset()
 
 
 def active_dir() -> Optional[str]:
     """The persistent-cache directory in use this process, or None."""
     return _active_dir
-
-
-def _list_entries(path: str) -> frozenset[str]:
-    try:
-        return frozenset(os.listdir(path))
-    except OSError:
-        return frozenset()
-
-
-def observe_compile() -> str:
-    """Classify the XLA compile that just finished against the
-    persistent cache: "off" (cache disabled), "miss" (a new cache entry
-    appeared — this compile was real work, now persisted), or "hit"
-    (no new entry: either deserialized from the cache or below the
-    persistence thresholds — small/fast programs are never written, so
-    "hit" is an upper bound; docs/OBSERVABILITY.md).  Updates the seen
-    set so back-to-back compiles classify independently."""
-    global _seen_entries
-    if _active_dir is None:
-        return "off"
-    with _lock:
-        now = _list_entries(_active_dir)
-        fresh = now - _seen_entries
-        _seen_entries = now
-    return "miss" if fresh else "hit"
 
 
 def enable_persistent_cache(min_compile_time_secs: float = 0.5
@@ -91,7 +63,7 @@ def enable_persistent_cache(min_compile_time_secs: float = 0.5
         return None
     import jax
 
-    global _active_dir, _seen_entries
+    global _active_dir
     from_env = os.environ.get(ENV_DIR)
     path = from_env or DEFAULT_DIR
     try:
@@ -106,9 +78,7 @@ def enable_persistent_cache(min_compile_time_secs: float = 0.5
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     jax.config.update("jax_persistent_cache_min_compile_time_secs",
                       float(min_compile_time_secs))
-    with _lock:
-        _active_dir = path
-        _seen_entries = _list_entries(path)
+    _active_dir = path
     from .. import obs
     # registry-only (sinks are usually configured later in run_train):
     # the scrape file records whether repeat compiles could deserialize

@@ -35,7 +35,8 @@ def pytest_addoption(parser):
 # tests/, which is the tier-1 command; the benchmark's other tests run whole
 # cut-down cells for minutes and stay with benchmarks/README.md's command.
 _BENCHMARK_READER_TESTS = ("test_benchmark_json.py", "test_counts.py",
-                           "test_phases.py", "test_tracered.py")
+                           "test_phases.py", "test_tracered.py",
+                           "test_startup.py")
 
 
 def pytest_configure(config):

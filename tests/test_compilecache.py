@@ -60,6 +60,56 @@ def test_default_is_one_fixed_path_inside_the_checkout():
         assert ".jax_cache/" in f.read().split()
 
 
+_VERDICT = """
+import json, os, sys
+import jax, jax.numpy as jnp
+from shifu_tpu import obs
+from shifu_tpu.obs import introspect
+from shifu_tpu.utils import compilecache
+path = compilecache.enable_persistent_cache(min_compile_time_secs=0.0)
+# another writer to the directory, after this process looked at it
+with open(os.path.join(path, "someone-elses-entry"), "w") as f:
+    f.write("x")
+journal = obs.RunJournal(None)
+obs.set_journal(journal)
+fn = introspect.instrument_jit(lambda x: jnp.tanh(x @ x.T).sum(), "probe")
+fn(jnp.ones((24, 24), jnp.float32))
+print(json.dumps([r["cache"] for r in journal.records
+                  if r["kind"] == "xla_compile"]))
+"""
+
+
+def _verdicts(cache_dir, disabled=False) -> list:
+    env = {k: v for k, v in os.environ.items()
+           if k != "SHIFU_TPU_NO_COMPILE_CACHE"}
+    env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
+    env["JAX_COMPILATION_CACHE_DIR"] = cache_dir
+    env["SHIFU_TPU_XLA_COST"] = "0"
+    out = subprocess.run([sys.executable, "-c", _VERDICT], env=env,
+                         capture_output=True, text=True, timeout=180,
+                         check=True).stdout
+    return json.loads(out.strip().splitlines()[-1])
+
+
+def test_the_verdict_is_jaxs_and_another_writer_does_not_flip_it(tmp_path):
+    """`xla_compile.cache` is `miss` where JAX compiled and wrote the
+    program and `hit` where its cache served it, whatever else appears in
+    the directory meanwhile (the verdict was once read off a listing of it,
+    where a new file from anyone read as a miss)."""
+    cache_dir = str(tmp_path / "shared")
+    assert _verdicts(cache_dir) == ["miss"]
+    os.remove(os.path.join(cache_dir, "someone-elses-entry"))
+    assert _verdicts(cache_dir) == ["hit"]
+
+
+def test_the_module_keeps_no_listing_of_the_directory():
+    from shifu_tpu.utils import compilecache
+
+    assert not hasattr(compilecache, "observe_compile")
+    assert not hasattr(compilecache, "_seen_entries")
+    assert not hasattr(compilecache, "_list_entries")
+
+
 def test_chip_smoke_rehearsal_runs_phases_and_never_passes(tmp_path):
     """device -> train (both tiers) -> serve at tiny size on the CPU: the
     plumbing holds, every line says REHEARSAL, and no result line prints."""
