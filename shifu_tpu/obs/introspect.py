@@ -319,7 +319,8 @@ def _observe(name: str, wall_s: float, split: dict, **fields) -> None:
 
 
 def _record_compile(name: str, fn, args, kwargs, wall_s: float,
-                    capture: Optional[bool] = None) -> dict:
+                    capture: Optional[bool] = None,
+                    notes: Optional[dict] = None) -> dict:
     """Journal + registry + goodput for one observed compile; returns
     the captured analysis (possibly empty).  Never raises."""
     try:
@@ -337,10 +338,24 @@ def _record_compile(name: str, fn, args, kwargs, wall_s: float,
     except Exception:
         analysis = {}
     try:
-        _observe(name, wall_s, split, **analysis)
+        _observe(name, wall_s, split, **analysis, **(notes or {}))
     except Exception:
         pass
     return analysis
+
+
+_notes = threading.local()
+
+
+def note(**fields) -> None:
+    """Add trace-time facts (numbers, summed by key) to the `xla_compile`
+    event of the instrumented program being traced on this thread: what a
+    step builder decided while the program was traced (the fused Adadelta
+    apply's leaves and bytes).  Outside such a trace it does nothing."""
+    got = getattr(_notes, "fields", None)
+    if got is not None:
+        for k, v in fields.items():
+            got[k] = got.get(k, 0) + v
 
 
 class InstrumentedJit:
@@ -374,8 +389,12 @@ class InstrumentedJit:
             n0 = fn._cache_size()
         except Exception:
             n0 = None
+        outer, _notes.fields = getattr(_notes, "fields", None), {}
         t0 = time.perf_counter()
-        out = fn(*args, **kwargs)
+        try:
+            out = fn(*args, **kwargs)
+        finally:
+            notes, _notes.fields = _notes.fields, outer
         wall = time.perf_counter() - t0
         if n0 is not None:
             try:
@@ -384,7 +403,7 @@ class InstrumentedJit:
                 compiled = False
             if compiled:
                 _record_compile(self.name, fn, args, kwargs, wall,
-                                capture=self._capture)
+                                capture=self._capture, notes=notes)
         self._note_dispatch()
         return out
 
@@ -423,6 +442,6 @@ def reset_for_tests() -> None:
 
 
 # re-exported through obs/__init__ for call sites
-__all__ = ["instrument_jit", "InstrumentedJit", "compile_span",
+__all__ = ["instrument_jit", "InstrumentedJit", "compile_span", "note",
            "capture_enabled", "stats", "reset_for_tests", "listen",
            "compile_mark", "compiles_since"]

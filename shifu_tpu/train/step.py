@@ -147,14 +147,20 @@ def decode_target_weight(batch: Batch) -> tuple[jax.Array, jax.Array]:
 
 
 def make_apply_gradients(job: JobConfig, mesh: Optional[Mesh] = None):
-    """(state, grads, batch) -> new state: the dense optax apply, or the
-    sparse rows-touched-only table apply when the job's plan engages
+    """(state, grads, batch) -> new state: the dense optax apply; the fused
+    Adadelta apply for the large leaves where it engages (one in-place
+    kernel pass a leaf, train/optimizers.py); or the sparse
+    rows-touched-only table apply when the job's plan engages
     (train/sparse_embed.py — tables masked out of optax, moments on
     TrainState.table_slots, touched rows gathered/updated/scattered)."""
+    from .optimizers import fused_adadelta_engages, make_fused_adadelta_apply
     from .sparse_embed import make_sparse_apply
 
     sparse = make_sparse_apply(job, mesh)
     if sparse is None:
+        if fused_adadelta_engages(job.train.optimizer, mesh):
+            fused = make_fused_adadelta_apply(job.train.optimizer)
+            return lambda st, grads, batch: fused(st, grads)
         return lambda st, grads, batch: st.apply_gradients(grads)
     # the whole batch dict: the sparse apply reads features and, when the
     # feeder attached them, the embed_unique compacted ids (embed/dedup)
