@@ -1,8 +1,9 @@
 """Shared build/caching for the framework's native C++ components.
 
 One g++ invocation per source file, cached in `runtime/_build/` keyed by
-source mtime.  Used by the scoring engine (csrc/shifu_scorer.cc) and the
-data parser (csrc/shifu_parser.cc); both are dependency-free C ABI shared
+source mtime.  Used by the scoring engine (csrc/shifu_scorer.cc), the
+data parser (csrc/shifu_parser.cc) and the eval's accumulation
+(csrc/shifu_evalacc.cc); all are dependency-free C ABI shared
 libraries bindable from Python (ctypes) and the JVM (JNA/JNI) — the authored
 native-code layer replacing the reference's consumed TF C++ runtime
 (shifu-tensorflow-eval/pom.xml:59-73).
@@ -73,10 +74,15 @@ def _compile_cached(
         if (os.path.exists(out_path) and not force
                 and os.path.getmtime(out_path) >= _source_mtime(src)):
             return out_path
+        # written under a name of this process's own and renamed into place:
+        # processes that build the same artifact at once (test workers, a
+        # job's hosts on a shared disk) never load a half-written file
+        tmp = f"{out_path}.{os.getpid()}.tmp"
         for flags in flag_variants:
-            cmd = ["g++", *flags, "-o", out_path, src, *tail]
+            cmd = ["g++", *flags, "-o", tmp, src, *tail]
             proc = subprocess.run(cmd, capture_output=True, text=True)
             if proc.returncode == 0:
+                os.replace(tmp, out_path)
                 return out_path
         raise RuntimeError(
             f"native build failed ({' '.join(cmd)}):\n{proc.stderr}")

@@ -333,29 +333,38 @@ def _moe_layers(counters: dict) -> list[dict]:
     return layers
 
 
-def _accumulate_streaming(triples, score_sink=None) -> tuple[float, float]:
+def _accumulate_streaming(triples, score_sink=None,
+                          sketch=None) -> tuple[float, float]:
     """THE eval accumulation: one StreamingMetrics over (scores, labels,
     weights) chunks, shared by the single-host and multihost branches of
     `evaluate` — the two used to carry their own copies, so eval
     instrumentation (and any accumulator fix) had to land twice.  Binned
-    AUC matches the exact statistic to < 1e-6 at the default 2^20 bins."""
+    AUC matches the exact statistic to < 1e-6 at the default 2^20 bins.
+
+    `sketch` (an obs.sketch.ScoreSketch, the baseline profile's) takes the
+    scores of the rows with weight > 0 in the same pass over a chunk
+    (zero-weight padding would skew the frozen score distribution);
+    `score_sink` is called with them, a copy a chunk."""
     sm = metrics_lib.StreamingMetrics()
     # nonzero-weight rows: the one definition that reads the same on every
     # topology (the multihost branch's gathered global batches keep their
     # zero-weight padding; the single-host branch pre-trims real rows —
     # counting raw lengths would make the counter topology-dependent)
     rows = obs.counter("eval_rows_total", "rows evaluated (nonzero weight)")
+    native = obs.counter(
+        "eval_rows_native_total",
+        "of eval_rows_total, the rows the native one-pass accumulation "
+        "reduced")
     # phase `accumulate` opens per chunk, never across the generator's
     # resumption: `triples` opens its own phases (prep, dispatch, fetch)
     for chunk in triples:
         with obs.span("accumulate", journal=False):
             s, t, w = chunk
-            seen = sm.nonzero_rows
-            counted = sm.update(s, t, w)  # the rows with weight > 0
+            seen, seen_native = sm.nonzero_rows, sm.native_rows
+            counted = sm.update(s, t, w, sketch)  # the rows with weight > 0
             rows.inc(sm.nonzero_rows - seen)
+            native.inc(sm.native_rows - seen_native)
             if score_sink is not None:
-                # baseline score sketch: only rows that counted (zero-weight
-                # padding would skew the frozen score distribution)
                 score_sink(np.asarray(s)[counted])
             del chunk, s, t, w  # the chunk's buffers go inside the phase
     with obs.span("accumulate", journal=False):  # the final reduction
@@ -497,7 +506,8 @@ def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
              eval_step, mesh: Optional[Mesh] = None,
              batch_size: Optional[int] = None,
              score_sink=None,
-             resident: Optional[ResidentEval] = None) -> tuple[float, float]:
+             resident: Optional[ResidentEval] = None,
+             sketch=None) -> tuple[float, float]:
     """(weighted_error, auc) over the full dataset — every row counted, the
     tail padded with zero-weight rows (reference evaluates the full valid set
     per epoch, ssgd_monitor.py:281-284).
@@ -519,14 +529,17 @@ def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
     forward, the same chunks into the same accumulation, and the same four
     spans, which then time the views of the host's label and weight
     columns, the pass's one dispatch, the wait for the device with the
-    D2H of the scores, and the accumulation."""
+    D2H of the scores, and the accumulation.
+
+    `sketch` and `score_sink` take the scores of the rows with weight > 0
+    (`_accumulate_streaming`)."""
     multihost = jax.process_count() > 1 and mesh is not None
     if not multihost and ds.num_rows == 0:
         return float("nan"), float("nan")
     if resident is not None and not multihost:
         return _accumulate_streaming(
             _fetch_resident_eval(*_dispatch_resident_eval(state, ds, resident)),
-            score_sink)
+            score_sink, sketch)
     bs = _eval_batch_size(job, ds, mesh, multihost, batch_size)
     # same wire cast as training (model casts inputs to compute_dtype first,
     # so scores are bit-identical; H2D bytes halve)
@@ -578,7 +591,7 @@ def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
             while pend:
                 yield fetch()
 
-        return _accumulate_streaming(triples(), score_sink)
+        return _accumulate_streaming(triples(), score_sink, sketch)
 
     from jax.experimental import multihost_utils
     from jax.sharding import NamedSharding, PartitionSpec
@@ -620,7 +633,7 @@ def evaluate(state: TrainState, ds: pipe.TabularDataset, job: JobConfig,
                        np.asarray(w.addressable_data(0))[:, 0])
             yield out
 
-    return _accumulate_streaming(triples(), score_sink)
+    return _accumulate_streaming(triples(), score_sink, sketch)
 
 
 def train(job: JobConfig,
@@ -1603,12 +1616,11 @@ def train(job: JobConfig,
                     run_ahead(epoch + 1)
                     valid_error, valid_auc = _accumulate_streaming(
                         _fetch_resident_eval(*dispatched),
-                        score_sketch.update)
+                        sketch=score_sketch)
                 else:
                     valid_error, valid_auc = evaluate(
                         state, valid_ds, job, eval_step, mesh,
-                        score_sink=score_sketch.update,
-                        resident=resident_eval)
+                        resident=resident_eval, sketch=score_sketch)
             if ahead is not None:
                 eval_beside_scan_s = time.perf_counter() - ahead.t_dispatched
                 obs.counter(

@@ -1,7 +1,7 @@
 """Sanitizer self-tests for the native components (ASan + UBSan + TSan).
 
 The reference had no race/memory detection of any kind (SURVEY.md §5.2:
-"None").  Here both authored C++ components carry a -DSHIFU_SELFTEST_MAIN
+"None").  Here the authored C++ components carry a -DSHIFU_SELFTEST_MAIN
 entry that drives their kernels (multithreaded chunked parse; tiled matmul /
 layernorm / softmax incl. remainder paths) under
 -fsanitize=address,undefined — an out-of-bounds read, use-after-free, leak,
@@ -147,3 +147,13 @@ def test_scorer_selftest_asan_ubsan():
     proc = subprocess.run([exe], capture_output=True, text=True, timeout=120)
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "scorer selftest ok" in proc.stdout
+
+
+def test_evalacc_selftest_asan_ubsan():
+    """The eval's one-pass accumulation on its edge chunks (no rows, one
+    row, every weight zero, no weights, uint8 labels, NaN and scores past
+    both ends) under ASan/UBSan."""
+    exe = _build_or_skip("shifu_evalacc.cc", extra_flags=["-ffp-contract=off"])
+    proc = subprocess.run([exe], capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stdout + proc.stderr
+    assert "evalacc selftest ok" in proc.stdout
