@@ -275,7 +275,7 @@ VALID_ACTIVATIONS = ("sigmoid", "tanh", "relu", "leakyrelu")
 TRAINING_ONLY_MODEL_TYPES = ("block_stack",)
 
 #: the letters of `BlockStackSpec.pattern`, one a kind of block
-BLOCK_KINDS = "M*ELAFCDG"
+BLOCK_KINDS = "M*ELAFCDGVQ"
 
 
 def refuse_training_only(model_type: Any, what: str) -> None:
@@ -313,7 +313,15 @@ class BlockStackSpec:
       share, values of `v_head_dim`;
     - `D` a dense gated MLP at `intermediate_size`;
     - `G` sigmoid-routed gated experts (`E`'s router, `F`'s experts) beside
-      `n_shared_experts` shared experts' width with no gate.
+      `n_shared_experts` shared experts' width with no gate; with
+      `n_shared_experts` 0 beside none, and a token whose choices all fall
+      on experts not held here leaves the layer unchanged;
+    - `V` a gated short convolution over `hidden_size` channels:
+      `[B | C | X] = x W_in`, `(C * conv(B * X)) W_out`, the convolution
+      depthwise, causal, of 3 taps and no bias;
+    - `Q` causal grouped-query attention with a plain RMSNorm over each
+      head's dims of q and k and the rotary term on all of a head's dims,
+      no gate.
 
     Each block is `x <- x + mixer(RMSNorm(x))`; a final RMSNorm and the
     last position's vector feed the shared `shifu_output_0` head.  The
@@ -337,13 +345,13 @@ class BlockStackSpec:
     n_groups: int = 1
     ssm_state_size: int = 0
     conv_kernel: int = 4
-    # * and A: causal grouped-query attention
+    # *, A and Q: causal grouped-query attention
     num_attention_heads: int = 0
     num_key_value_heads: int = 0
     head_dim: int = 0
     # A: the rotary term, on the first partial_rotary_factor of a head
     partial_rotary_factor: float = 1.0
-    rope_theta: float = 10000.0                      # A and C
+    rope_theta: float = 10000.0                      # A, C and Q
     # C: latent attention over num_attention_heads heads
     q_lora_rank: int = 0
     kv_lora_rank: int = 0
@@ -366,7 +374,8 @@ class BlockStackSpec:
     moe_shared_expert_intermediate_size: int = 0     # E
     routed_scaling_factor: float = 1.0               # E and G
     shared_expert_intermediate_size: int = 0         # F
-    n_shared_experts: int = 0       # G: of moe_intermediate_size each
+    # G: of moe_intermediate_size each, 0 for none; -1 = not given
+    n_shared_experts: int = -1
     experts_held: int = 0           # 0 = all of them
     first_expert_held: int = 0
 
@@ -396,8 +405,8 @@ class BlockStackSpec:
             if self.mamba_num_heads % self.n_groups:
                 raise ConfigError("block_stack.mamba_num_heads must be a "
                                   "multiple of n_groups")
-        if "*" in self.pattern or "A" in self.pattern:
-            self._need("a '*' or 'A'", "num_attention_heads",
+        if set(self.pattern) & set("*AQ"):
+            self._need("a '*', 'A' or 'Q'", "num_attention_heads",
                        "num_key_value_heads", "head_dim")
             if self.num_attention_heads % self.num_key_value_heads:
                 raise ConfigError("block_stack.num_attention_heads must be "
@@ -409,6 +418,12 @@ class BlockStackSpec:
                 raise ConfigError(
                     "block_stack: head_dim * partial_rotary_factor must be "
                     f"an even count of a head's dims: {self.rotary_dim}")
+        if "Q" in self.pattern:
+            self._need("a 'Q'", "rope_theta")
+            if self.head_dim % 2:
+                raise ConfigError("block_stack: a 'Q' block turns all of a "
+                                  "head's dims in pairs: head_dim must be "
+                                  f"even, not {self.head_dim}")
         if "L" in self.pattern:
             self._need("an 'L'", "linear_num_key_heads",
                        "linear_num_value_heads", "linear_key_head_dim",
@@ -435,7 +450,10 @@ class BlockStackSpec:
             self._need("a 'D'", "intermediate_size")
         if "G" in self.pattern:
             self._need("a 'G'", "n_routed_experts", "num_experts_per_tok",
-                       "moe_intermediate_size", "n_shared_experts")
+                       "moe_intermediate_size")
+            if self.n_shared_experts < 0:
+                raise ConfigError("block_stack: a 'G' block needs "
+                                  "n_shared_experts (0 for none)")
         if set(self.pattern) & set("EFG"):
             if self.num_experts_per_tok > self.n_routed_experts:
                 raise ConfigError("block_stack.num_experts_per_tok exceeds "

@@ -1,5 +1,5 @@
 """A causal sequence scorer built from a layer-pattern string: pre-norm
-residual blocks of nine kinds over one token table, the last position's
+residual blocks of eleven kinds over one token table, the last position's
 vector into the `shifu_output_0` head every model shares.
 
     x_0 = table[ids]                                   (B, T, hidden)
@@ -15,15 +15,20 @@ RMSNorm on q and k, a partial rotary term, a sigmoid output gate, then
 `causal_gqa`), `C` causal multi-head latent attention (q, and the keys and
 values, through low-rank projections with an RMSNorm inside each; a head's
 key is dims of its own plus rotary dims all heads share, its values of
-another width: ops/attention.causal_latent_attention).  Expert layers
+another width: ops/attention.causal_latent_attention), `V` a gated short
+convolution (`[B | C | X] = u W_in`, then `(C * conv(B * X)) W_out`, a
+depthwise causal convolution of SHORT_CONV_TAPS taps with no bias), `Q`
+causal grouped-query attention with a plain per-head RMSNorm on q and k and
+the rotary term on every dim of a head, no gate.  Expert layers
 (ops/routed_experts.py): `E` sigmoid-routed relu^2 experts beside one shared
 expert, `F` softmax-routed gated experts beside one shared expert behind a
-sigmoid gate, `G` sigmoid-routed gated experts beside a shared expert with
-no gate.  `D` is a dense gated MLP.  The norms of `L`, `A` and `F` blocks
-are zero-centred, `x_hat * (1 + w)` with `w` from zero; the final norm is of
-the last block's kind.  The residual stream stays in the compute
-dtype; router logits, norms' statistics, the scans' decays, the delta rule's
-chunk systems and their inverse, the rotary term and the softmax are
+sigmoid gate, `G` sigmoid-routed gated experts beside `n_shared_experts`
+shared experts' width with no gate, or beside none.  `D` is a dense gated
+MLP.  The norms of `L`, `A` and `F` blocks are zero-centred, `x_hat * (1 +
+w)` with `w` from zero; the final norm is of the last block's kind.  The
+residual stream stays in the compute dtype; router logits, norms'
+statistics, the short convolution's gates, the scans' decays, the delta
+rule's chunk systems and their inverse, the rotary term and the softmax are
 float32.  A row is a fixed-width sequence: every selected column is one
 position's token id, all of one vocabulary.
 
@@ -34,7 +39,9 @@ the last position alone, one token a row.
 An expert layer holds `experts_held` of the `n_routed_experts` the router
 scores (expert parallelism's share of the layer): a token's choices that
 fall on other experts add nothing here.  Each expert block counts where its
-tokens went and how many blocks of the dispatch held rows; the model sows
+tokens went and how many blocks of the dispatch held rows (a `G` block with
+no shared expert also how many of its tokens chose no held expert, and so
+left the layer unchanged here); the model sows
 the counts (collection `counters`, name `moe`) and the train step sums them
 over an epoch.
 """
@@ -65,6 +72,8 @@ OUT_STD = INIT_STD / RESCALE_LAYERS ** 0.5
 TIME_STEP_MIN, TIME_STEP_MAX, TIME_STEP_FLOOR = 0.001, 0.1, 1e-4
 #: under the root where an `L` block scales q and k to unit length
 UNIT_LENGTH_EPS = 1e-6
+#: the taps of a `V` block's depthwise convolution (`conv_L_cache`)
+SHORT_CONV_TAPS = 3
 
 
 def uniform_init(lo: float, hi: float):
@@ -191,7 +200,9 @@ class _Block(nn.Module):
         x, a row a token; `route(logits)` gives (the chosen experts, their
         weights); `experts(row_weight, row_token, block_expert, live_blocks,
         block_rows)` the held experts' part and `shared(tokens)` the shared
-        expert's, both float32."""
+        expert's, both float32.  `shared` None: the layer has no shared
+        expert, its output is the held experts' part alone, and the counters
+        say how many tokens chose no held expert."""
         bs, f32 = self.bs, jnp.float32
         k = bs.num_experts_per_tok
         with jax.named_scope("moe"):
@@ -205,11 +216,16 @@ class _Block(nn.Module):
             with jax.named_scope("experts"):
                 routed = experts(row_weight, row_token, plan["block_expert"],
                                  plan["live_blocks"], rows)
-            with jax.named_scope("shared"):
-                beside = shared(tokens)
-            with jax.named_scope("combine"):
-                y = (beside + routed).astype(self.cdt)
-        counters = _dispatch_counters(plan, tokens, k, rows)
+            if shared is None:
+                y = routed.astype(self.cdt)
+                unrouted = _tokens_unrouted(bs, chosen)
+            else:
+                with jax.named_scope("shared"):
+                    beside = shared(tokens)
+                with jax.named_scope("combine"):
+                    y = (beside + routed).astype(self.cdt)
+                unrouted = None
+        counters = _dispatch_counters(plan, tokens, k, rows, unrouted)
         return x + y.reshape(x.shape), counters
 
 
@@ -325,11 +341,22 @@ def _dispatch(bs: BlockStackSpec, experts, weights):
     return plan, rows, row_weight, slot // k
 
 
-def _dispatch_counters(plan: dict, tokens, k: int, block_rows: int) -> dict:
+def _tokens_unrouted(bs: BlockStackSpec, experts):
+    """How many tokens of the (T, k) choices `experts` chose no held
+    expert."""
+    local = experts - bs.first_expert_held
+    held = jnp.any((local >= 0) & (local < bs.held), axis=-1)
+    return jnp.sum(~held).astype(jnp.int32)
+
+
+def _dispatch_counters(plan: dict, tokens, k: int, block_rows: int,
+                       unrouted=None) -> dict:
     """What an expert block counts of one call.  `live_rows` is the rows
     of the dispatch's live blocks, `live_blocks * block_rows`: summed over
-    an epoch beside `live_blocks`, it carries the block size out."""
-    return {
+    an epoch beside `live_blocks`, it carries the block size out.  A layer
+    with no shared expert also counts its `tokens` and, of them, the
+    `tokens_unrouted` (`unrouted`), for which it did no work."""
+    counters = {
         "tokens_per_expert": plan["tokens_per_expert"],
         "routed_slots": jnp.int32(tokens.shape[0] * k),
         "held_slots": plan["held_slots"],
@@ -337,6 +364,10 @@ def _dispatch_counters(plan: dict, tokens, k: int, block_rows: int) -> dict:
         "live_blocks": plan["live_blocks"],
         "live_rows": plan["live_blocks"] * block_rows,
     }
+    if unrouted is not None:
+        counters["tokens"] = jnp.int32(tokens.shape[0])
+        counters["tokens_unrouted"] = unrouted
+    return counters
 
 
 class GatedDeltaBlock(_Block):
@@ -533,7 +564,8 @@ class DenseMlpBlock(_Block):
 
 class SigmoidGatedExpertsBlock(_Block):
     """`ExpertsBlock`'s router over `GatedExpertsBlock`'s experts, the
-    shared expert added with no gate.  Returns (x, counters), as they do."""
+    shared expert added with no gate; with `n_shared_experts` 0 there is
+    none, and no leaf of it.  Returns (x, counters), as they do."""
 
     @nn.compact
     def __call__(self, x):
@@ -546,9 +578,15 @@ class SigmoidGatedExpertsBlock(_Block):
         w_gate = self.weight("experts/w_gate", (held, hidden, f), init)
         w_up = self.weight("experts/w_up", (held, hidden, f), init)
         w_down = self.weight("experts/w_down", (held, f, hidden), init)
-        s_gate = self.weight("shared/w_gate", (hidden, fs), init)
-        s_up = self.weight("shared/w_up", (hidden, fs), init)
-        s_down = self.weight("shared/w_down", (fs, hidden), init)
+        shared = None
+        if fs:
+            s_gate = self.weight("shared/w_gate", (hidden, fs), init)
+            s_up = self.weight("shared/w_up", (hidden, fs), init)
+            s_down = self.weight("shared/w_down", (fs, hidden), init)
+
+            def shared(t):
+                return self.gated_mlp(t, s_gate, s_up, s_down).astype(
+                    jnp.float32)
         tokens = h.reshape(-1, hidden)
         return self.experts_layer(
             x, tokens, w_r,
@@ -556,17 +594,79 @@ class SigmoidGatedExpertsBlock(_Block):
                                       bs.routed_scaling_factor),
             lambda *plan: routed_gated_mlp(tokens, w_gate, w_up, w_down,
                                            *plan),
-            lambda t: self.gated_mlp(t, s_gate, s_up, s_down)
-            .astype(jnp.float32))
+            shared)
+
+
+class ShortConvBlock(_Block):
+    """The gated short convolution: `[B | C | X] = h W_in`, `z = B * X`,
+    `z'_t = sum_j w_j * z_{t-2+j}` per channel (zero before the row), then
+    `(C * z') W_out`; no bias anywhere.  The gates in float32."""
+
+    @nn.compact
+    def __call__(self, x):
+        bs, cdt, f32 = self.bs, self.cdt, jnp.float32
+        hidden = bs.hidden_size
+        h = self.pre_norm(x)
+        init = nn.initializers.normal(INIT_STD)
+        w_in = self.weight("in_proj", (hidden, 3 * hidden), init)
+        conv_w = self.weight("conv_w", (SHORT_CONV_TAPS, hidden), init)
+        w_out = self.weight("out_proj", (hidden, hidden), init)
+        with jax.named_scope("short_conv"):
+            with jax.named_scope("in_proj"):
+                b, c, u = jnp.split(self.dot(h, w_in), 3, axis=-1)
+            with jax.named_scope("conv"):
+                z = causal_conv1d((b.astype(f32) * u.astype(f32)).astype(cdt),
+                                  conv_w)
+                y = (c.astype(f32) * z.astype(f32)).astype(cdt)
+            with jax.named_scope("out_proj"):
+                return x + self.dot(y, w_out)
+
+
+class NormedAttentionBlock(_Block):
+    """Causal grouped-query attention with a plain RMSNorm over each head's
+    dims of q and k (weights from 1) and the rotary term on every dim of a
+    head (dims i and i + head_dim / 2 together), no output gate."""
+
+    @nn.compact
+    def __call__(self, x):
+        bs = self.bs
+        hq, hkv, d = (bs.num_attention_heads, bs.num_key_value_heads,
+                      bs.head_dim)
+        h = self.pre_norm(x)
+        init = nn.initializers.normal(INIT_STD)
+        ones = nn.initializers.ones
+        w_q = self.weight("q_proj", (bs.hidden_size, hq * d), init)
+        w_k = self.weight("k_proj", (bs.hidden_size, hkv * d), init)
+        w_v = self.weight("v_proj", (bs.hidden_size, hkv * d), init)
+        q_norm = self.weight("q_norm", (d,), ones)
+        k_norm = self.weight("k_norm", (d,), ones)
+        w_o = self.weight("o_proj", (hq * d, bs.hidden_size), init)
+        b_, t = x.shape[:2]
+        with jax.named_scope("attention"):
+            with jax.named_scope("qkv"):
+                q = self.dot(h, w_q).reshape(b_, t, hq, d)
+                k = self.dot(h, w_k).reshape(b_, t, hkv, d)
+                v = self.dot(h, w_v).reshape(b_, t, hkv, d)
+            with jax.named_scope("qk_norm"):
+                q = rms_norm(q, q_norm, bs.norm_eps)
+                k = rms_norm(k, k_norm, bs.norm_eps)
+            with jax.named_scope("rope"):
+                q = rotate(q, bs.rope_theta, d)
+                k = rotate(k, bs.rope_theta, d)
+            with jax.named_scope("softmax"):
+                o = causal_gqa(q, k, v)
+            with jax.named_scope("o_proj"):
+                return x + self.dot(o.reshape(b_, t, hq * d), w_o)
 
 
 _KINDS = {"M": MambaBlock, "*": AttentionBlock, "E": ExpertsBlock,
           "L": GatedDeltaBlock, "A": GatedAttentionBlock,
           "F": GatedExpertsBlock, "C": LatentAttentionBlock,
-          "D": DenseMlpBlock, "G": SigmoidGatedExpertsBlock}
+          "D": DenseMlpBlock, "G": SigmoidGatedExpertsBlock,
+          "V": ShortConvBlock, "Q": NormedAttentionBlock}
 #: the kinds that mix along the sequence, and the kinds that hand back
 #: counters beside x
-SEQUENCE_MIXERS, EXPERT_KINDS = "M*LAC", "EFG"
+SEQUENCE_MIXERS, EXPERT_KINDS = "M*LACVQ", "EFG"
 
 
 class BlockStack(nn.Module):

@@ -2,10 +2,10 @@
 share of a layer): top-k routing over all the experts, and the held experts'
 part of the result, with no token dropped whatever the load.
 
-`route_topk` (sigmoid scores) and `route_softmax_topk` (softmax
-probabilities, renormalised over the chosen) score every expert and pick k a
-token.  `plan_dispatch` lays
-the (token, slot) choices that fell on held experts out in rows grouped by
+`route_topk` (sigmoid scores, an optional selection bias) and
+`route_softmax_topk` (softmax probabilities, renormalised over the chosen)
+score every expert and pick k a token.  `plan_dispatch` lays the (token,
+slot) choices that fell on held experts out in rows grouped by
 expert, each group padded to whole blocks of `block_rows`; it is sized for
 the worst case (every choice on a held expert), so nothing is ever dropped.
 `routed_relu2_mlp` then walks the blocks that are live - a `while` whose trip
@@ -26,12 +26,19 @@ import jax
 import jax.numpy as jnp
 
 
-def route_topk(logits: jax.Array, k: int, scale: float):
-    """Sigmoid-score routing (`n_group` 1, the correction bias at zero):
-    logits (T, E) float32 -> (experts (T, k) int32, weights (T, k) float32,
-    `scale * s / sum(s)` over the k chosen scores)."""
+def route_topk(logits: jax.Array, k: int, scale: float, bias=None):
+    """Sigmoid-score routing (`n_group` 1): logits (T, E) float32 ->
+    (experts (T, k) int32, weights (T, k) float32, `scale * s / sum(s)` over
+    the k chosen scores).  `bias` (E,), the selection bias
+    (`e_score_correction_bias`, the expert bias), moves which experts are
+    chosen - the k largest of `s + bias` - and not their weights; None is
+    a bias at zero."""
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
-    chosen, experts = jax.lax.top_k(s, k)
+    if bias is None:
+        chosen, experts = jax.lax.top_k(s, k)
+    else:
+        _, experts = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+        chosen = jnp.take_along_axis(s, experts, axis=-1)
     weights = scale * chosen / jnp.sum(chosen, axis=-1, keepdims=True)
     return experts.astype(jnp.int32), weights
 

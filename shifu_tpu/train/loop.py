@@ -319,15 +319,16 @@ def _tree_sum(acc, new):
 def _moe_layers(counters: dict) -> list[dict]:
     """The `moe` journal event's layers from the model's summed counters
     (models/block_stack._dispatch_counters), a leading axis an expert
-    layer.  `block_rows`, the rows a block of the layer's dispatch holds,
-    is the same every step and is carried, not summed: the summed
+    layer; `tokens` and `tokens_unrouted` where the layers count them (no
+    shared expert).  `block_rows`, the rows a block of the layer's dispatch
+    holds, is the same every step and is carried, not summed: the summed
     `live_rows` over the summed `live_blocks`."""
     per = np.asarray(counters["tokens_per_expert"])
     layers = []
     for i in range(per.shape[0]):
         n = {k: int(np.asarray(counters[k])[i]) for k in
              ("routed_slots", "held_slots", "tokens_dropped", "live_blocks",
-              "live_rows")}
+              "live_rows", "tokens", "tokens_unrouted") if k in counters}
         n["block_rows"] = n.pop("live_rows") // max(n["live_blocks"], 1)
         layers.append({"tokens_per_expert": [int(v) for v in per[i]], **n})
     return layers

@@ -457,6 +457,7 @@ def test_the_new_scopes_are_in_the_compiled_programs_metadata():
 
 
 WIDTHS = model_group(CFG)["block_stack"]
+LEFT_OUT = object()   # a key the group does not give
 
 
 @pytest.mark.parametrize("change, message", [
@@ -466,15 +467,20 @@ WIDTHS = model_group(CFG)["block_stack"]
     ({"v_head_dim": 0}, "'C' block needs"),
     ({"qk_rope_head_dim": 5}, "must be even"),
     ({"intermediate_size": 0}, "'D' block needs"),
-    ({"n_shared_experts": 0}, "'G' block needs"),
+    ({"n_shared_experts": LEFT_OUT}, "'G' block needs"),
     ({"num_experts_per_tok": 17}, "exceeds"),
     ({"first_expert_held": 12}, "within"),
+    ({"moe_intermediate_size": 0}, "'G' block needs"),
 ])
 def test_validate_refuses_a_pattern_whose_letters_lack_their_widths(
         change, message):
+    widths = {k: v for k, v in {**WIDTHS, **change}.items()
+              if v is not LEFT_OUT}
     with pytest.raises(ConfigError, match=message):
-        BlockStackSpec(**{**WIDTHS, **change}).validate()
+        BlockStackSpec(**widths).validate()
     BlockStackSpec(**WIDTHS).validate()
+    # an explicit 0 is a layer with no shared expert, not a missing key
+    BlockStackSpec(**{**WIDTHS, "n_shared_experts": 0}).validate()
 
 
 def test_a_published_key_the_blocks_hold_otherwise_is_refused():

@@ -253,10 +253,10 @@ def test_criteo_size_deepfm_resident_eval_fits_the_chip(one_chip,
     assert scores.shape == (15, batch) and scores.dtype == jnp.float32
 
 
-def _compiled_epoch_program(cell: str, one_chip, monkeypatch):
-    """The epoch program of a sequence cell of the benchmark (8 steps of 8
-    rows of 4,096 positions), compiled for one v5e chip: raises what the
-    chip's compiler would."""
+def _compiled_epoch_program(cell: str, one_chip, monkeypatch, steps=8):
+    """The epoch program of a sequence cell of the benchmark (`steps` steps
+    of 8 rows of 4,096 positions), compiled for one v5e chip: raises what
+    the chip's compiler would."""
     from benchmarks import harness
     from shifu_tpu.ops import pallas_common
     from shifu_tpu.train.loop import init_state
@@ -265,8 +265,7 @@ def _compiled_epoch_program(cell: str, one_chip, monkeypatch):
     _, _, config, _, params, driver = harness.load_cell(cell)
     job = driver.build_job(config, params, 1, 1)
     batch, width = config["batch_size"], config["num_categorical"]
-    steps = params["train_rows"] // batch
-    assert (batch, width, steps) == (8, 4096, 8)
+    assert (batch, width, params["train_rows"] // batch) == (8, 4096, steps)
 
     def on_chip(x):
         return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
@@ -373,3 +372,20 @@ def test_joyai_flash_ep16_epoch_fits_the_chip(one_chip, no_compile_cache,
     compiled = _compiled_epoch_program("joyai_flash_ep16.train_sequences",
                                        one_chip, monkeypatch)
     _fits_in_place(compiled, 7.6e9, 7.75e9)
+
+
+def test_lfm2_8b_a1b_ep4_epoch_fits_the_chip(one_chip, no_compile_cache,
+                                             monkeypatch):
+    """The epoch program of the benchmark's `lfm2_8b_a1b_ep4.train_sequences`
+    cell (the blocks `VDQGVGVGVG` at LFM2-8B-A1B's published widths: four
+    gated short convolutions, one QK-normed attention, the dense MLP and
+    four expert layers of 8 held of 32 experts with no shared expert;
+    507.8 M parameters with both Adadelta slots: 6.10 GB of arguments; 4
+    steps of 8 rows of 4,096 positions, a block rematerialized at a time)
+    compiles for one v5e chip at batch 8: its arguments are carried in
+    place, and with its 2.99 GB of temporaries (the rehearsal when the cell
+    was added) they fill 8.5 of the chip's 16 GiB."""
+    compiled = _compiled_epoch_program("lfm2_8b_a1b_ep4.train_sequences",
+                                       one_chip, monkeypatch, steps=4)
+    _fits_in_place(compiled, 6.05e9, 6.15e9)
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
